@@ -62,6 +62,9 @@ def main() -> None:
                     help="previous BENCH_ftfi_runtime.json to diff fig3 "
                          "rows against")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     backends = tuple(args.backend.split(","))
     baseline_rows = _load_baseline(args.baseline) if args.baseline else None
 

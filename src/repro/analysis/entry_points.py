@@ -58,9 +58,9 @@ def _rng():
 
 
 def _mesh24():
-    import jax
+    from repro.launch.mesh import make_local_mesh
     _require_devices(8)
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_local_mesh(data=2, model=4)
 
 
 # ---------------------------------------------------------------------------

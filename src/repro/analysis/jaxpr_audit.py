@@ -32,7 +32,7 @@ from typing import Any, Callable, Iterator
 import numpy as np
 
 import jax
-import jax.extend.core  # noqa: F401  (makes jax.extend.core resolvable on 0.4.x)
+import jax.extend.core  # noqa: F401  (submodule: not loaded by `import jax`)
 
 # Collective primitive names as they appear in jaxprs.  ``psum_scatter``
 # is spelled ``reduce_scatter`` by the lowering; budgets may use either.

@@ -177,7 +177,7 @@ def check_source(src: str, path: str = "<string>") -> list[LintError]:
                             "jax_enable_x64 flip inside src/ changes global "
                             "precision for every caller; tests only")
 
-        # with jax.experimental.enable_x64(): inside src/
+        # with jax.enable_x64(...): inside src/
         if in_src and isinstance(node, (ast.With, ast.AsyncWith)):
             for item in node.items:
                 ctx = item.context_expr

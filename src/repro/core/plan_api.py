@@ -53,6 +53,12 @@ from repro.core.integrate import (CrossBucket, IntegrationPlan, LeafBucket,
 
 KERNEL_MODES = ("poly", "exp", "expq", "rational")
 
+# Every executor and cross-engine contraction runs at full f32 precision: the
+# TPU's default f32 matmul is one bf16 pass (~1e-3 relative), which breaks the
+# exactness the integrate promises (~1e-6 at mesh scale). The CPU is exact
+# either way.
+EXACT = jax.lax.Precision.HIGHEST
+
 _SAVE_VERSION = 1
 # PlanSpec field-layout generation, mixed into disk-cache keys (NOT the npz
 # version: old artifacts still load — absent fields default to None)
@@ -389,9 +395,9 @@ def chebyshev_batched_matvec(fn_eval, tgt_d, tgt_mask, src_d, src_mask, Xp,
     Bmat = fn_eval(xc[:, :, None] + yc[:, None, :])  # (B, r, r)
     Lx = _lagrange_batched(tgt_d, xc)  # (B, Kx, r)
     Ly = _lagrange_batched(src_d, yc)  # (B, Ky, r)
-    tmp = jnp.einsum("bkr,bkd->brd", Ly, Xp)
-    tmp = jnp.einsum("bqr,brd->bqd", Bmat, tmp)
-    return jnp.einsum("bkq,bqd->bkd", Lx, tmp)
+    tmp = jnp.einsum("bkr,bkd->brd", Ly, Xp, precision=EXACT)
+    tmp = jnp.einsum("bqr,brd->bqd", Bmat, tmp, precision=EXACT)
+    return jnp.einsum("bkq,bqd->bkd", Lx, tmp, precision=EXACT)
 
 
 def _lagrange_batched(pts, nodes):
@@ -415,7 +421,8 @@ def polynomial_batched_matvec(coeffs, tgt_d, tgt_mask, src_d, src_mask, Xp):
     xpow = _powers_b(tgt_d, Bdeg)  # (B, Kt, deg+1)
     ypow = _powers_b(src_d, Bdeg)  # (B, Ks, deg+1)
     ypow = ypow * src_mask[:, :, None]
-    S = jnp.einsum("bku,bkd->bud", ypow, Xp)  # (B, deg+1, d)
+    S = jnp.einsum("bku,bkd->bud", ypow, Xp,
+                   precision=EXACT)  # (B, deg+1, d)
     Wrows = []
     for l in range(Bdeg + 1):
         acc = 0.0
@@ -423,7 +430,7 @@ def polynomial_batched_matvec(coeffs, tgt_d, tgt_mask, src_d, src_mask, Xp):
             acc = acc + coeffs[tt] * math.comb(tt, l) * S[:, tt - l]
         Wrows.append(acc)
     W = jnp.stack(Wrows, axis=1)  # (B, deg+1, d)
-    return jnp.einsum("bkl,bld->bkd", xpow, W)
+    return jnp.einsum("bkl,bld->bkd", xpow, W, precision=EXACT)
 
 
 def _powers_b(x, B):
@@ -439,7 +446,8 @@ def exponential_batched_matvec(lam, scale, tgt_d, tgt_mask, src_d, src_mask,
     Padded source groups carry zero mass in Xp, so no source mask is needed."""
     ly = lam * src_d  # (B, Us)
     m = jnp.max(jnp.where(src_mask, ly, -jnp.inf), axis=1, keepdims=True)
-    t = jnp.einsum("bu,bud->bd", jnp.exp(ly - m) * src_mask, Xp)  # (B, d)
+    t = jnp.einsum("bu,bud->bd", jnp.exp(ly - m) * src_mask, Xp,
+                   precision=EXACT)  # (B, d)
     return scale * jnp.exp(lam * tgt_d + m)[:, :, None] * t[:, None, :]
 
 
@@ -560,7 +568,7 @@ def _execute(spec: PlanSpec, params: PlanParams, fn_eval: Callable,
         M = fn_eval(params.leaf_dists[i])  # (B, K, K)
         pair_mask = mask[:, :, None] & mask[:, None, :]
         M = jnp.where(jnp.asarray(pair_mask), M, 0.0)
-        contrib = jnp.einsum("bij,bjd->bid", M, Xl)
+        contrib = jnp.einsum("bij,bjd->bid", M, Xl, precision=EXACT)
         out = out.at[ids].add(contrib * mask[:, :, None])
 
     if spec.n_src_groups:

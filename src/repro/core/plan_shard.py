@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.lru import BoundedLRU
-from repro.core.plan_api import PlanParams, PlanSpec, _fspec, select_cross
+from repro.core.plan_api import (EXACT, PlanParams, PlanSpec, _fspec,
+                                 select_cross)
 
 # bumped whenever the per-shard table layout below changes: recorded into
 # sharded artifacts' provenance and rejected by plan_guard when a newer
@@ -454,8 +455,6 @@ def check_mesh(spec: PlanSpec, mesh) -> None:
 
 def _execute_sharded(spec, sp: ShardPlan, params: PlanParams, fn_eval,
                      cross_multiply, use_hankel, X, mesh, axis):
-    from jax.experimental.shard_map import shard_map
-
     X = jnp.asarray(X)
     squeeze = X.ndim == 1
     if squeeze:
@@ -509,7 +508,7 @@ def _execute_sharded(spec, sp: ShardPlan, params: PlanParams, fn_eval,
             M = fn_eval(o["leaf_d"][i][0])
             pm = m[:, :, None] & m[:, None, :]
             M = jnp.where(pm, M, 0.0)
-            contrib = jnp.einsum("bij,bjd->bid", M, Xl)
+            contrib = jnp.einsum("bij,bjd->bid", M, Xl, precision=EXACT)
             outp = outp.at[o["leaf_s"][i][0]].add(contrib * m[:, :, None])
 
         if sp.n_src_loc:
@@ -541,8 +540,8 @@ def _execute_sharded(spec, sp: ShardPlan, params: PlanParams, fn_eval,
         return jax.lax.psum_scatter(outp[:-1], axis, scatter_dimension=0,
                                     tiled=True)
 
-    out = shard_map(local_fn, mesh=mesh, in_specs=(in_specs,),
-                    out_specs=P(axis), check_rep=False)(ops)
+    out = jax.shard_map(local_fn, mesh=mesh, in_specs=(in_specs,),
+                        out_specs=P(axis), check_vma=False)(ops)
     res = out[:spec.n]
     if params.tree_w is not None:
         w = jnp.repeat(jnp.asarray(params.tree_w),

@@ -50,7 +50,9 @@ def _fdist_kernel(x_ref, y_ref, v_ref, c_ref, o_ref, acc_ref, *,
 
     s = x_ref[...] + y_ref[...]  # (blk_a, 1) + (1, blk_b) -> (blk_a, blk_b)
     m = _f_tile(s, c_ref[...], mode)  # tile of M — exists only in VMEM
-    acc_ref[...] += jnp.dot(m, v_ref[...], preferred_element_type=jnp.float32)
+    # full f32 MXU passes: the default single bf16 pass is ~1e-3 relative
+    acc_ref[...] += jnp.dot(m, v_ref[...], preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(j == nb - 1)
     def _done():

@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.fdist_matvec.kernel import (fdist_matvec_batched_pallas,
@@ -58,7 +57,7 @@ def fdist_matvec_batched_sharded(x, y, v, coeffs, *, mesh, axis=None,
         return fdist_matvec_batched(xl, yl, vl, cl, mode=mode, blk_a=blk_a,
                                     blk_b=blk_b, interpret=interpret)
 
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(P(axis), P(axis), P(axis), P()),
-                    out_specs=P(axis), check_rep=False)(x, y, v, coeffs)
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(P(axis), P(axis), P(axis), P()),
+                        out_specs=P(axis), check_vma=False)(x, y, v, coeffs)
     return out[:B]

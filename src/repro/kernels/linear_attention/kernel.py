@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _lin_attn_kernel(q_ref, k_ref, v_ref, g_ref, num_ref, den_ref,
                      s_ref, z_ref, *, chunk: int):
@@ -88,7 +86,7 @@ def linear_attention_pallas(qf, kf, v, log_gamma, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((m, hd), jnp.float32),
                         pltpu.VMEM((m,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr, lg)

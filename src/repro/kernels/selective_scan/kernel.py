@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _scan_kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_ref, *,
                  chunk: int):
@@ -69,7 +67,7 @@ def selective_scan_pallas(u, dt, A, B, C, D, *, chunk: int = 128,
                                lambda b, dblk, l: (b, l, dblk)),
         out_shape=jax.ShapeDtypeStruct((Bt, L, din), u.dtype),
         scratch_shapes=[pltpu.VMEM((blk_d, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, A.astype(jnp.float32), B, C, D.reshape(1, -1).astype(jnp.float32))

@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _unpack(refs, n_in, normalize):
     ins, rest = refs[:n_in], refs[n_in:]
@@ -76,7 +74,7 @@ def _decay_kernel(*refs, chunk: int, eps: float, combine: bool,
         s_ref[...] = jnp.zeros_like(s_ref)
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    lg = g_ref[0]  # log gamma (<= 0)
+    lg = g_ref[...]  # (1, 1) log gamma (<= 0)
     q = q_ref[...].astype(jnp.float32)  # (C, m)
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)  # (C, hd)
@@ -84,7 +82,9 @@ def _decay_kernel(*refs, chunk: int, eps: float, combine: bool,
     num = jnp.dot(scores, v, preferred_element_type=jnp.float32)
     den = jnp.sum(scores, axis=1)
     # inter-chunk: state decayed to each local position
-    pos = jax.lax.broadcasted_iota(jnp.float32, (chunk, 1), 0)
+    # Mosaic's iota is integer-only
+    pos = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0).astype(
+        jnp.float32)
     q_dec = q * jnp.exp(lg * pos)
     num += jnp.dot(q_dec, s_ref[...], preferred_element_type=jnp.float32)
     den += jnp.dot(q_dec, z_ref[...], preferred_element_type=jnp.float32)[:, 0]
@@ -162,8 +162,10 @@ def topo_attention_sweep_pallas(qf, kf, v, dmat, *, log_gamma=None,
     if decay:
         body = functools.partial(_decay_kernel, chunk=C, eps=eps,
                                  combine=combine, normalize=normalize)
-        in_specs.append(pl.BlockSpec((None, 1), lambda b, h, c: (h, 0)))
-        inputs.append(jnp.asarray(log_gamma, jnp.float32).reshape(H, 1))
+        # (H, 1, 1): the block's last two dims equal the array's, which is
+        # what Mosaic requires of a block narrower than the (8, 128) tile
+        in_specs.append(pl.BlockSpec((None, 1, 1), lambda b, h, c: (h, 0, 0)))
+        inputs.append(jnp.asarray(log_gamma, jnp.float32).reshape(H, 1, 1))
         scratch = [pltpu.VMEM((m, hd), jnp.float32),
                    pltpu.VMEM((m, 1), jnp.float32)]
     else:
@@ -193,7 +195,7 @@ def topo_attention_sweep_pallas(qf, kf, v, dmat, *, log_gamma=None,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)

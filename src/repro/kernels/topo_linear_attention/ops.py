@@ -289,7 +289,6 @@ def topo_linear_attention_sharded(qf, kf, v, coeffs, *, mesh,
     is bit-identical to the single-device call. An axis whose extent does
     not divide the corresponding dim is dropped (that dim runs replicated),
     mirroring `launch.sharding.shard`'s divisibility fallback."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     qf = jnp.asarray(qf)
@@ -307,5 +306,5 @@ def topo_linear_attention_sharded(qf, kf, v, coeffs, *, mesh,
         return topo_linear_attention(q, k, vv, c, **kw)
 
     io = P(ba, ha)
-    return shard_map(local, mesh=mesh, in_specs=(io, io, io, P(ha)),
-                     out_specs=io, check_rep=False)(qf, kf, v, coeffs)
+    return jax.shard_map(local, mesh=mesh, in_specs=(io, io, io, P(ha)),
+                         out_specs=io, check_vma=False)(qf, kf, v, coeffs)

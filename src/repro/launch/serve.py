@@ -9,6 +9,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch import compile_cache
 from repro.models import api
 from repro.serve.engine import Request, ServeEngine
 
@@ -32,6 +33,7 @@ def main():
                          "group (mid-wave admission); replay: legacy "
                          "token-by-token prompt replay through decode")
     args = ap.parse_args()
+    compile_cache.configure()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     if args.smoke:

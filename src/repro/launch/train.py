@@ -10,6 +10,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch import compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import TrainLoopConfig, run_training
 
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.configure()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     overrides = {"dtype": "float32"} if args.smoke else {}

@@ -12,6 +12,20 @@ def dtype_of(cfg):
             "float16": jnp.float16}[cfg.dtype]
 
 
+def maybe_remat(f, cfg):
+    """Wrap one layer body in `jax.checkpoint` per `cfg.remat` and
+    `cfg.remat_policy`: "dots" saves non-batched matmul outputs, "nothing"
+    recomputes everything, "none" (or remat=False) saves everything."""
+    pol = getattr(cfg, "remat_policy", "dots")
+    if not cfg.remat or pol == "none":
+        return f
+    if pol == "nothing":  # full recompute: minimum live activations
+        return jax.checkpoint(
+            f, policy=jax.checkpoint_policies.nothing_saveable)
+    return jax.checkpoint(
+        f, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+
 def dense_init(key, shape, scale=None, dtype=jnp.float32):
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     s = scale if scale is not None else 1.0 / jnp.sqrt(fan_in)

@@ -24,7 +24,7 @@ from repro.models import rglru as RG
 from repro.models import ssm as SSM
 from repro.models.layers import (cross_entropy_loss, dense_init, dtype_of,
                                  embed_init, gated_mlp, gated_mlp_init,
-                                 rms_norm)
+                                 maybe_remat, rms_norm)
 
 
 # ----------------------------------------------------------------------------
@@ -305,17 +305,6 @@ def init_params(cfg, key):
 # ----------------------------------------------------------------------------
 
 
-def _maybe_remat(f, cfg):
-    pol = getattr(cfg, "remat_policy", "dots")
-    if not cfg.remat or pol == "none":
-        return f
-    if pol == "nothing":  # full recompute: minimum live activations
-        return jax.checkpoint(
-            f, policy=jax.checkpoint_policies.nothing_saveable)
-    return jax.checkpoint(
-        f, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-
-
 def _run_stack(cfg, params, x, positions):
     """Shared trunk for train/prefill. Returns (x, total_aux)."""
     total_aux = jnp.zeros((), jnp.float32)
@@ -334,7 +323,7 @@ def _run_stack(cfg, params, x, positions):
                     aux = aux + a
                 return x, aux
 
-            body = _maybe_remat(superblock, cfg)
+            body = maybe_remat(superblock, cfg)
             if scanned:
                 x, auxs = jax.lax.scan(lambda c, p: body(c, p), x, sb)
                 total_aux = total_aux + jnp.sum(auxs)
@@ -352,7 +341,7 @@ def _run_stack(cfg, params, x, positions):
             def body_fn(x, layer_p, _kind=kind):
                 return _block_train(cfg, _kind, layer_p, x, positions)
 
-            body = _maybe_remat(body_fn, cfg)
+            body = maybe_remat(body_fn, cfg)
             if scanned:
                 x, auxs = jax.lax.scan(body, x, params[f"blocks{si}"])
                 total_aux = total_aux + jnp.sum(auxs)

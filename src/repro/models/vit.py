@@ -15,7 +15,8 @@ from repro.core.masks import make_tree_fastmult, masked_linear_attention
 from repro.graphs.graph import grid_graph
 from repro.graphs.mst import minimum_spanning_tree
 from repro.models import attention as A
-from repro.models.layers import dense_init, dtype_of, gated_mlp, gated_mlp_init, rms_norm
+from repro.models.layers import (dense_init, dtype_of, gated_mlp,
+                                 gated_mlp_init, maybe_remat, rms_norm)
 
 
 from repro.core.lru import BoundedLRU
@@ -182,6 +183,10 @@ def forward(cfg, params, patches, integ):
         x = x + gated_mlp(p["mlp"], h, cfg.mlp_act)
         return x, ()
 
+    # per-layer remat: Alg. 1's expanded (L, m*hd) fields and their FFTs
+    # are the largest activations; without it every layer's stay live for
+    # the backward pass
+    body = maybe_remat(body, cfg)
     # plan arrays are numpy constants: python loop over stacked params
     n = jax.tree.leaves(params["blocks"])[0].shape[0]
     for i in range(n):

@@ -32,7 +32,10 @@ class AdamWConfig:
 
 
 def adamw_init(params) -> AdamWState:
-    zeros = lambda p: jnp.zeros_like(p)
+    # f32 moments whatever the params' dtype: the update promotes them to f32
+    # anyway, and starting there keeps the state's types the same every step
+    zeros = lambda p: jnp.zeros_like(
+        p, dtype=jnp.promote_types(p.dtype, jnp.float32))
     return AdamWState(
         step=jnp.zeros((), jnp.int32),
         mu=jax.tree.map(zeros, params),

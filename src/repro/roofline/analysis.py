@@ -24,8 +24,9 @@ _DTYPE_BYTES = {
 
 # result-shape form: %all-reduce.5 = bf16[16,512]{1,0} all-reduce(
 # also matches tuple-result async starts: ... = (bf16[..], bf16[..]) all-gather-start(
+# and TPU tiled layouts: ... = f32[641,64]{1,0:T(8,128)S(1)} all-reduce(
 _COLL_LINE_RE = re.compile(
-    r"= *(\(?[a-z0-9, \[\]{}()]*?)\s*"
+    r"= *(\(?[A-Za-z0-9, \[\]{}():]*?)\s*"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(")
 _TENSOR_RE = re.compile(r"\b([a-z]?[a-z0-9]+)\[([0-9,]*)\]")

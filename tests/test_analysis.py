@@ -22,6 +22,7 @@ from repro.analysis import entry_points, runner
 from repro.core import cordial as C
 from repro.core import plan_guard
 from repro.graphs.graph import random_tree
+from repro.launch.mesh import make_mesh
 
 
 def _kinds(rep):
@@ -37,17 +38,17 @@ def test_hidden_all_gather_flagged():
     """An all_gather smuggled into a shard_map body is a structured
     collective finding naming the primitive — even on a 1-device mesh,
     where the string would also appear but wall-clock tests never notice."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("i",))
+    mesh = make_mesh((1,), ("i",))
 
     def fwd(x):
         def body(xs):
             return jax.lax.all_gather(xs, "i", tiled=True)
 
         return shard_map(body, mesh=mesh, in_specs=P("i"), out_specs=P(),
-                         check_rep=False)(x)
+                         check_vma=False)(x)
 
     rep = jaxpr_audit.audit(fwd, jnp.ones((8, 2)), name="bad.allgather",
                             budget={"collectives": {}})
@@ -64,10 +65,10 @@ def test_hidden_all_gather_flagged():
 def test_wrong_collective_count_flagged():
     """A second psum where the budget declares one is a count mismatch, not
     a pass — exact census, both directions."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("i",))
+    mesh = make_mesh((1,), ("i",))
 
     def fwd(x):
         def body(xs):
@@ -89,7 +90,7 @@ def test_wrong_collective_count_flagged():
 def test_f64_leak_flagged():
     """Under x64, a float64 constant (and the f64 compute it forces) is a
     wide_dtype finding; the same program audits clean in f32."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         big = jnp.asarray(np.random.default_rng(0).standard_normal((4, 64)))
         assert big.dtype == jnp.float64
 
@@ -104,7 +105,7 @@ def test_f64_leak_flagged():
 
 
 def test_int64_compute_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def fwd(x):
             return x.astype(jnp.int64) + 1
 
@@ -308,6 +309,13 @@ def test_lint_x64_flip_flagged():
         "jax.config.update('jax_enable_x64', True)\n",
         "src/repro/core/bad64.py")
     assert [e.rule for e in errs] == ["x64-flip"], errs
+    # the context-manager spelling (jax >= 0.9: jax.enable_x64(True))
+    errs = lint.check_source(
+        "import jax\n"
+        "with jax.enable_x64(True):\n"
+        "    pass\n",
+        "src/repro/core/bad64.py")
+    assert [e.rule for e in errs] == ["x64-flip"], errs
     # tests may flip freely
     assert lint.check_source(
         "import jax\njax.config.update('jax_enable_x64', True)\n",
@@ -349,10 +357,10 @@ def test_clean_entry_points_pass(section):
 def test_audit_walks_nested_call_eqns():
     """The walker recurses through pjit/scan/cond rather than reading the
     pretty-printed string: a collective hidden two levels down is found."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("i",))
+    mesh = make_mesh((1,), ("i",))
 
     def fwd(x):
         def body(xs):
@@ -363,7 +371,7 @@ def test_audit_walks_nested_call_eqns():
             return out
 
         return shard_map(body, mesh=mesh, in_specs=P(None, "i"),
-                         out_specs=P("i"), check_rep=False)(x)
+                         out_specs=P("i"), check_vma=False)(x)
 
     rep = jaxpr_audit.audit(jax.jit(fwd), jnp.ones((4, 1)),
                             name="nested", budget={"collectives": {}})

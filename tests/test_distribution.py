@@ -40,7 +40,8 @@ step = make_train_step(cfg, ocfg)
 # single device reference
 p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with SH.use_sharding(mesh):
     pspecs = SH.tree_param_specs(params)
     pshard = jax.tree.map(SH.named_sharding, pspecs)
@@ -77,7 +78,8 @@ rng = np.random.default_rng(0)
 batch = {{"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 32)), jnp.int32)}}
 params = api.init_params(cfg, jax.random.PRNGKey(0))
 l1 = float(api.loss_fn(cfg, params, batch)[0])
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with SH.use_sharding(mesh):
     pshard = jax.tree.map(SH.named_sharding, SH.tree_param_specs(params))
     params_s = jax.device_put(params, pshard)
@@ -115,7 +117,8 @@ patches = jnp.asarray(rng.normal(size=(4, cfg.num_prefix_embeddings, 48)),
 ref = vit.forward(cfg, params, patches, integ)
 
 cfg_s = cfg.replace(topo_shard_plan=True)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with SH.use_sharding(mesh):
     fwd = lambda p, x: vit.forward(cfg_s, p, x, integ)
     # structured census (repro.analysis): each of the 2 layers runs 2 mask
@@ -173,7 +176,8 @@ opt = adamw_init(params)
 step = make_train_step(cfg, ocfg)
 p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with SH.use_sharding(mesh):
     pshard = jax.tree.map(SH.named_sharding, SH.tree_param_specs(params))
     params_s = jax.device_put(params, pshard)
@@ -206,7 +210,8 @@ from repro.launch import sharding as SH
 from repro.launch.dryrun import lower_cell_cfg
 from repro.roofline.analysis import collective_bytes_from_hlo
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_smoke_config("llama3_2_1b")
 # smoke decode cell: shrink the assigned shape via a fake SHAPES entry
 from repro.configs import base
@@ -214,8 +219,6 @@ base.SHAPES["tiny_train"] = dict(seq_len=64, global_batch=8, kind="train")
 lowered, compiled, _, _ = lower_cell_cfg(cfg, "tiny_train", mesh)
 mem = compiled.memory_analysis()
 cost = compiled.cost_analysis()
-if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict]
-    cost = cost[0] if cost else {}
 coll = collective_bytes_from_hlo(compiled.as_text())
 assert cost.get("flops", 0) > 0
 assert coll > 0, "expected collectives on a (2,4) mesh"

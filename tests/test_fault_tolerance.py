@@ -84,7 +84,8 @@ d = os.environ["CKPT_DIR"]
 params = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
 mgr = CheckpointManager(d, keep=1)
 mgr.save(1, params)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 shardings = {"w": NamedSharding(mesh, P("data", "model"))}
 res = mgr.restore(params, shardings=shardings)
 w = res["params"]["w"]

@@ -35,6 +35,24 @@ def test_collective_bytes_parsing():
     assert total == sum(b["bytes"].values())
 
 
+def test_collective_parsing_tpu_tiled_layouts():
+    """Compiled TPU HLO prints tiled layouts (`{1,0:T(8,128)S(1)}`) on every
+    result; a collective fused into a `calls=%all-reduce-scatter` fusion is
+    counted by its inner all-reduce, the fusion line itself not at all."""
+    hlo = (
+        "  %all-reduce.2 = f32[164352,64]{1,0:T(8,128)} all-reduce(%pad.44), "
+        "replica_groups={{0,1,2,3}}, to_apply=%add\n"
+        "  %fusion.40 = f32[41088,64]{1,0:T(8,128)S(1)} fusion(%b), "
+        "kind=kCustom, calls=%all-reduce-scatter\n"
+        "  %all_to_all.3 = f32[4,609,64]{1,2,0:T(8,128)S(1)} all-to-all("
+        "%copy.156), replica_groups={{0,1,2,3}}, dimensions={0}\n"
+        "  %slice = f32[1,64]{1,0:T(1,128)S(1)} fusion(%all-reduce.2, %i)\n")
+    b = collective_breakdown(hlo)
+    assert b["counts"] == {"all-reduce": 1, "all-to-all": 1}
+    assert b["bytes"]["all-reduce"] == 164352 * 64 * 4
+    assert b["bytes"]["all-to-all"] == 4 * 609 * 64 * 4
+
+
 def test_roofline_terms_arithmetic():
     from repro.configs.base import SHAPES, get_config
 

@@ -15,7 +15,8 @@ from repro.configs.base import ARCHS, get_config
 from repro.launch import sharding as SH
 from repro.launch.specs import params_shapes
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
 for arch in ARCHS:
     if arch == "topovit_b16":
@@ -53,7 +54,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.launch import sharding as SH
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with SH.use_sharding(mesh):
     # heads and ff both map to model; only the first position may take it
     spec = SH.logical_to_spec(("batch", "heads", "ff"))
@@ -84,7 +86,8 @@ for name in ("plan_leaves", "cross_src", "cross_tgt", "tree"):
     assert SH.DEFAULT_RULES[name] == "data", name
 assert "data" in SH.DEFAULT_RULES["field_batch"]
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with SH.use_sharding(mesh):
     assert SH.logical_to_spec(("plan_leaves",)) == P("data")
     assert SH.logical_to_spec(("cross_src",)) == P("data")
@@ -94,7 +97,7 @@ with SH.use_sharding(mesh):
     assert spec == P("data", None), spec
     assert SH.plan_axis() == "data"
 assert SH.plan_axis(mesh) == "data"
-m2 = jax.make_mesh((8,), ("model",))
+m2 = make_mesh((8,), ("model",))
 assert SH.plan_axis(m2) == "model"  # no data axis: first axis fallback
 print("FTFI_AXES_OK")
 """

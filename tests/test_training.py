@@ -21,6 +21,23 @@ def test_adamw_quadratic_convergence():
     assert float(loss(params)) < 1e-3
 
 
+def test_adamw_state_dtypes_stable_over_steps():
+    """bf16 params keep f32 moments from init on, so a jitted step sees the
+    same argument types at every step, and the params stay bf16."""
+    params = {"w": jnp.asarray([3.0, -2.0], jnp.bfloat16)}
+    cfg = AdamWConfig(lr=0.1, weight_decay=0.0, warmup_steps=1,
+                      total_steps=10, clip_norm=1.0)
+    state = adamw_init(params)
+    dtypes = lambda t: [a.dtype for a in jax.tree.leaves(t)]
+    init = dtypes((params, state))
+    assert dtypes(state.mu) == dtypes(state.nu) == [jnp.float32]
+    loss = lambda p: jnp.sum(p["w"].astype(jnp.float32) ** 2)
+    for _ in range(2):
+        g = jax.grad(loss)(params)
+        params, state, _ = adamw_update(g, state, params, cfg)
+        assert dtypes((params, state)) == init
+
+
 def test_cosine_schedule_shape():
     cfg = AdamWConfig(lr=1.0, warmup_steps=10, total_steps=100,
                       min_lr_ratio=0.1)
