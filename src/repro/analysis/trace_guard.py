@@ -9,25 +9,33 @@ correctness test but destroys serving latency.
 
 This module is the cheap tripwire.  Instrumented sites call
 :func:`record` from *inside* the traced body, so the counter bumps exactly
-once per trace (jax executes the python body only when it compiles — the
-pattern proven by ``_PlanFastMult``'s trace counter).  Cache layers call
-:func:`record` with an ``event=`` tag for hit/miss accounting.  Tests and
-the CLI then wrap a workload in :func:`expect_stable` (fail on any retrace
-of a declared-stable site) or diff :func:`stats` against the
-``trace_guard`` section of ``ANALYSIS_BUDGETS.json`` via :func:`check`.
+once per trace (jax executes the python body only when it compiles).
+Cache layers call :func:`record` with an ``event=`` tag for hit/miss
+accounting.  Tests and the CLI then wrap a workload in
+:func:`expect_stable` (fail on any retrace of a declared-stable site) or
+diff :func:`stats` against the ``trace_guard`` section of
+``ANALYSIS_BUDGETS.json`` via :func:`check`.
 
-Pure stdlib — core modules import this at module scope without pulling in
-jax, so instrumentation adds zero import cost and only trace-time runtime
-cost (i.e. none on the cached path).
+Host phases (the plan build's) are timed with :func:`span`: a
+``jax.profiler.TraceAnnotation`` of the same name, so the phase sits on
+the device trace's clock whenever a profiler runs, plus its host-clock
+seconds and a count here, read through :func:`seconds` and :func:`stats`.
+
+Pure stdlib at import — core modules import this at module scope without
+pulling in jax (`span` imports the profiler when it first opens), so
+instrumentation adds zero import cost and only trace-time runtime cost
+(i.e. none on the cached path).
 """
 from __future__ import annotations
 
 import threading
+import time
 from contextlib import contextmanager
 
 __all__ = [
     "RetraceError", "record", "compiles", "stats", "reset",
-    "declare_stable", "expect_stable", "check", "snapshot",
+    "declare_stable", "expect_stable", "check", "snapshot", "span",
+    "seconds",
 ]
 
 
@@ -39,6 +47,7 @@ _lock = threading.Lock()
 _counts: dict[str, int] = {}          # site -> total records
 _by_key: dict[tuple[str, str], int] = {}  # (site, detail) -> records
 _stable: dict[str, int] = {}          # site -> max allowed compiles
+_spans: dict[str, list] = {}          # span name -> [count, host seconds]
 
 
 def record(site: str, detail: str = "", event: str = "compile") -> None:
@@ -62,11 +71,43 @@ def compiles(site: str) -> int:
         return _counts.get(site, 0)
 
 
+@contextmanager
+def span(name: str):
+    """Time the block as the host span ``name``: a
+    ``jax.profiler.TraceAnnotation`` (on the trace's clock when a profiler
+    runs), and its host-clock seconds and one count added under ``name``.
+    Spans nest; each adds its own whole duration."""
+    from jax.profiler import TraceAnnotation
+
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        with _lock:
+            c = _spans.setdefault(name, [0, 0.0])
+            c[0] += 1
+            c[1] += dt
+
+
+def seconds(name: str) -> float | None:
+    """Host seconds summed over every closed :func:`span` of ``name``, or
+    None where none closed."""
+    with _lock:
+        c = _spans.get(name)
+        return None if c is None else c[1]
+
+
 def stats() -> dict:
-    """Snapshot of all counters: {"sites": {site: n}, "keys": {...}}."""
+    """Snapshot of all counters: {"sites": {site: n}, "keys": {...},
+    "spans": {name: {"count": n, "seconds": s}}}."""
     with _lock:
         keys = {f"{s} [{d}]": n for (s, d), n in sorted(_by_key.items())}
-        return {"sites": dict(sorted(_counts.items())), "keys": keys}
+        spans = {k: {"count": c[0], "seconds": c[1]}
+                 for k, c in sorted(_spans.items())}
+        return {"sites": dict(sorted(_counts.items())), "keys": keys,
+                "spans": spans}
 
 
 def snapshot() -> dict[str, int]:
@@ -79,6 +120,7 @@ def reset() -> None:
         _counts.clear()
         _by_key.clear()
         _stable.clear()
+        _spans.clear()
 
 
 def declare_stable(site: str, max_compiles: int = 1) -> None:

@@ -86,19 +86,17 @@ def _trace_state_clean() -> bool:
 class _PlanFastMult:
     """One cached X -> M_f X closure per (plan, f-family).
 
-    `trace_count` increments once per executor trace (jitted path) or per
-    call (eager path): back-to-back jitted calls with the same shapes leave
-    it unchanged, which is exactly the no-retrace property the fastmult
-    cache exists for."""
+    Each executor trace records one `engines.plan.fastmult` compile in
+    `trace_guard`: back-to-back jitted calls with the same shapes record
+    none, which is exactly the no-retrace property the fastmult cache
+    exists for."""
 
     def __init__(self, eager: Callable, jit_compile: bool):
         import jax
 
-        self.trace_count = 0
         self.jitted = bool(jit_compile)
 
         def counted(X):
-            self.trace_count += 1
             if isinstance(X, jax.core.Tracer):  # compile, not an eager call
                 trace_guard.record("engines.plan.fastmult")
             return eager(X)

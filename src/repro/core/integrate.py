@@ -30,6 +30,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.analysis import trace_guard
 from repro.core.cordial import CordialFn
 from repro.core.integrator_tree import ITNode, build_integrator_tree
 from repro.core.lru import BoundedLRU
@@ -608,6 +609,21 @@ def _disk_cache_store(key, plan: IntegrationPlan) -> None:
     plan_cache.store(plan_cache.key_str(key), spec, params)
 
 
+def _cached_plan(key) -> IntegrationPlan | None:
+    """The plan under `key` from the in-memory LRU, recorded in trace_guard
+    as an `integrate.plan_cache` hit or miss; on a miss, from the disk
+    cache (then kept in memory too)."""
+    hit = _PLAN_CACHE.get(key)
+    if hit is not None:
+        trace_guard.record("integrate.plan_cache", event="hit")
+        return hit
+    trace_guard.record("integrate.plan_cache", event="miss")
+    hit = _disk_cache_load(key)
+    if hit is not None:
+        _PLAN_CACHE.put(key, hit)
+    return hit
+
+
 def compile_plan(tree: WeightedTree, leaf_size: int = 64, seed: int = 0,
                  detect_grid_spacing: bool = True, use_cache: bool = True,
                  reweightable: bool = False) -> IntegrationPlan:
@@ -631,22 +647,21 @@ def compile_plan(tree: WeightedTree, leaf_size: int = 64, seed: int = 0,
 
     if reweightable:
         detect_grid_spacing = False
-    fp = tree_fingerprint(tree)
+    with trace_guard.span("ftfi.build.fingerprint"):
+        fp = tree_fingerprint(tree)
     if use_cache:
         key = (fp, max(int(leaf_size), 6), int(seed), detect_grid_spacing,
                reweightable)
-        hit = _PLAN_CACHE.get(key)
+        hit = _cached_plan(key)
         if hit is not None:
-            return hit
-        hit = _disk_cache_load(key)
-        if hit is not None:
-            _PLAN_CACHE.put(key, hit)
             return hit
 
-    flat = build_flat_it(tree, leaf_size=leaf_size, seed=seed,
-                         use_cache=use_cache)
-    plan = _assemble_plan(flat, tree.num_vertices, detect_grid_spacing,
-                          expand_groups=reweightable)
+    with trace_guard.span("ftfi.build.decompose"):
+        flat = build_flat_it(tree, leaf_size=leaf_size, seed=seed,
+                             use_cache=use_cache)
+    with trace_guard.span("ftfi.build.assemble"):
+        plan = _assemble_plan(flat, tree.num_vertices, detect_grid_spacing,
+                              expand_groups=reweightable)
     plan.fingerprint = fp
     plan.leaf_size = max(int(leaf_size), 6)
     plan.seed = int(seed)
@@ -683,22 +698,21 @@ def compile_forest_plan(forest, leaf_size: int = 64, seed: int = 0,
 
     if reweightable:
         detect_grid_spacing = False
-    fps = tuple(tree_fingerprint(t) for t in forest.trees)
+    with trace_guard.span("ftfi.build.fingerprint"):
+        fps = tuple(tree_fingerprint(t) for t in forest.trees)
     if use_cache:
         key = ("forest", fps, max(int(leaf_size), 6), int(seed),
                detect_grid_spacing, reweightable)
-        hit = _PLAN_CACHE.get(key)
+        hit = _cached_plan(key)
         if hit is not None:
-            return hit
-        hit = _disk_cache_load(key)
-        if hit is not None:
-            _PLAN_CACHE.put(key, hit)
             return hit
 
-    flat = build_flat_forest(forest.trees, leaf_size=leaf_size, seed=seed,
-                             use_cache=use_cache)
-    plan = _assemble_plan(flat, forest.num_vertices, detect_grid_spacing,
-                          expand_groups=reweightable)
+    with trace_guard.span("ftfi.build.decompose"):
+        flat = build_flat_forest(forest.trees, leaf_size=leaf_size,
+                                 seed=seed, use_cache=use_cache)
+    with trace_guard.span("ftfi.build.assemble"):
+        plan = _assemble_plan(flat, forest.num_vertices, detect_grid_spacing,
+                              expand_groups=reweightable)
     plan.fingerprint = hashlib.sha1(
         "".join(fps).encode()).hexdigest()
     plan.leaf_size = max(int(leaf_size), 6)

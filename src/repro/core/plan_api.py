@@ -230,6 +230,13 @@ def specialize(plan: IntegrationPlan):
     cached = getattr(plan, "_spec_params", None)
     if cached is not None:
         return cached
+    with trace_guard.span("ftfi.build.specialize"):
+        spec, params = _specialize(plan)
+    plan._spec_params = (spec, params)
+    return spec, params
+
+
+def _specialize(plan: IntegrationPlan):
     rw = getattr(plan, "rw", None) or {}
     upd = getattr(plan, "upd", None) or {}
     spec = PlanSpec(
@@ -281,9 +288,7 @@ def specialize(plan: IntegrationPlan):
         edge_w0=rw.get("edge_w0"),
         ghosts=np.zeros(0, np.int32) if upd else None,
     )
-    params = _birth_params(spec)
-    plan._spec_params = (spec, params)
-    return spec, params
+    return spec, _birth_params(spec)
 
 
 def _birth_params(spec: PlanSpec) -> PlanParams:
@@ -553,7 +558,12 @@ def _execute(spec: PlanSpec, params: PlanParams, fn_eval: Callable,
     """The pure fused executor: one gather + segment-sum (Eq. 3), one cross
     dispatch per size bucket, one gather + scatter-add (Eq. 4), diagonal
     corrections, per-tree output weights. Everything dynamic comes from
-    `params`; everything indexing/shaping from `spec`."""
+    `params`; everything indexing/shaping from `spec`.
+
+    Each phase runs under a `jax.named_scope` (`ftfi.leaf`, `ftfi.gather`,
+    `ftfi.cross`, `ftfi.scatter`, `ftfi.diag`): trace-time names that ride
+    into the compiled ops' metadata, so a device trace can sum its ops per
+    phase. The compiled code is the same with or without them."""
     X = jnp.asarray(X)
     squeeze = X.ndim == 1
     if squeeze:
@@ -562,47 +572,54 @@ def _execute(spec: PlanSpec, params: PlanParams, fn_eval: Callable,
     Xpad = jnp.concatenate([X, jnp.zeros((1, d), X.dtype)], axis=0)
     out = jnp.zeros_like(Xpad)
 
-    for i in range(len(spec.leaf_ids)):
-        ids, mask = spec.leaf_ids[i], spec.leaf_mask[i]
-        Xl = Xpad[ids]  # (B, K, d)
-        M = fn_eval(params.leaf_dists[i])  # (B, K, K)
-        pair_mask = mask[:, :, None] & mask[:, None, :]
-        M = jnp.where(jnp.asarray(pair_mask), M, 0.0)
-        contrib = jnp.einsum("bij,bjd->bid", M, Xl, precision=EXACT)
-        out = out.at[ids].add(contrib * mask[:, :, None])
+    with jax.named_scope("ftfi.leaf"):
+        for i in range(len(spec.leaf_ids)):
+            ids, mask = spec.leaf_ids[i], spec.leaf_mask[i]
+            Xl = Xpad[ids]  # (B, K, d)
+            M = fn_eval(params.leaf_dists[i])  # (B, K, K)
+            pair_mask = mask[:, :, None] & mask[:, None, :]
+            M = jnp.where(jnp.asarray(pair_mask), M, 0.0)
+            contrib = jnp.einsum("bij,bjd->bid", M, Xl, precision=EXACT)
+            out = out.at[ids].add(contrib * mask[:, :, None])
 
     if spec.n_src_groups:
         # Eq. 3 for every node at once: X'[g] = sum of source-vertex fields
         # per distance group (pivot/pad groups are empty -> zero)
-        Xp_flat = jax.ops.segment_sum(Xpad[spec.src_gather], spec.src_seg,
-                                      num_segments=spec.n_src_groups)
+        with jax.named_scope("ftfi.gather"):
+            Xp_flat = jax.ops.segment_sum(Xpad[spec.src_gather], spec.src_seg,
+                                          num_segments=spec.n_src_groups)
         parts = []
         for i in range(len(spec.cross_src_mask)):
             B, Us = spec.cross_src_mask[i].shape
             Ut = spec.cross_tgt_mask[i].shape[1]
             off = spec.cross_src_off[i]
-            Xp = Xp_flat[off:off + B * Us].reshape(B, Us, d)
-            res = cross_multiply(
-                i, params.cross_tgt_d[i], jnp.asarray(spec.cross_tgt_mask[i]),
-                params.cross_src_d[i], jnp.asarray(spec.cross_src_mask[i]),
-                Xp)
-            parts.append(res.reshape(B * Ut, d))
-        cross_flat = (jnp.concatenate(parts, axis=0) if len(parts) > 1
-                      else parts[0])
+            # named at the call site, so every cross engine is covered
+            with jax.named_scope("ftfi.cross"):
+                Xp = Xp_flat[off:off + B * Us].reshape(B, Us, d)
+                res = cross_multiply(
+                    i, params.cross_tgt_d[i],
+                    jnp.asarray(spec.cross_tgt_mask[i]),
+                    params.cross_src_d[i],
+                    jnp.asarray(spec.cross_src_mask[i]), Xp)
+                parts.append(res.reshape(B * Ut, d))
         # Eq. 4 for every node at once: gather each target's group value and
         # scatter-add into the output field
-        out = out.at[spec.tgt_scatter].add(cross_flat[spec.tgt_gather])
+        with jax.named_scope("ftfi.scatter"):
+            cross_flat = (jnp.concatenate(parts, axis=0) if len(parts) > 1
+                          else parts[0])
+            out = out.at[spec.tgt_scatter].add(cross_flat[spec.tgt_gather])
 
-    # diagonal corrections: -f(0) X[p] once per internal node
-    f0 = fn_eval(jnp.zeros((1,)))[0]
-    out = out.at[spec.pivots].add(-f0 * Xpad[spec.pivots])
+    with jax.named_scope("ftfi.diag"):
+        # diagonal corrections: -f(0) X[p] once per internal node
+        f0 = fn_eval(jnp.zeros((1,)))[0]
+        out = out.at[spec.pivots].add(-f0 * Xpad[spec.pivots])
 
-    res = out[:-1]
-    if params.tree_w is not None:
-        w = jnp.repeat(jnp.asarray(params.tree_w),
-                       np.asarray(spec.tree_sizes, np.int64),
-                       total_repeat_length=spec.n)
-        res = res * w[:, None].astype(res.dtype)
+        res = out[:-1]
+        if params.tree_w is not None:
+            w = jnp.repeat(jnp.asarray(params.tree_w),
+                           np.asarray(spec.tree_sizes, np.int64),
+                           total_repeat_length=spec.n)
+            res = res * w[:, None].astype(res.dtype)
     return res[:, 0] if squeeze else res
 
 

@@ -156,31 +156,40 @@ def topo_vit_attention(cfg, p, p_topo, x, integ):
         out = masked_attention_bruteforce(
             qf_, kf_, v_, mask_f(cfg.topo_g, coeffs, cfg.topo_dist_scale)(D))
     else:
-        fastmult = make_tree_fastmult(
-            integ, cfg.topo_g, coeffs, cfg.topo_dist_scale,
-            sharded=getattr(cfg, "topo_shard_plan", False))
-        out = masked_linear_attention(qf_, kf_, v_, fastmult)
+        with jax.named_scope("vit.alg1"):
+            fastmult = make_tree_fastmult(
+                integ, cfg.topo_g, coeffs, cfg.topo_dist_scale,
+                sharded=getattr(cfg, "topo_shard_plan", False))
+            out = masked_linear_attention(qf_, kf_, v_, fastmult)
     out = out.transpose(0, 2, 1, 3).reshape(B, L, -1).astype(x.dtype)
     return out @ p["attn"]["wo"]
 
 
 def forward(cfg, params, patches, integ):
     """patches: (B, L, patch_dim) -> logits (B, num_classes).
-    `integ` is the grid Integrator from build_grid_integrator."""
-    x = patches.astype(dtype_of(cfg)) @ params["patch_proj"]["kernel"]
-    x = x + params["patch_proj"]["bias"] + params["pos_embed"][None]
+    `integ` is the grid Integrator from build_grid_integrator.
+
+    The step's parts run under `jax.named_scope`s that a device trace sums
+    its ops by: `vit.embed`, `vit.layer_params` (the per-layer slice of the
+    stacked weights), `vit.attn` (with Alg. 1 as `vit.alg1` inside it),
+    `vit.mlp`, `vit.head`. They name the compiled ops and change none."""
+    with jax.named_scope("vit.embed"):
+        x = patches.astype(dtype_of(cfg)) @ params["patch_proj"]["kernel"]
+        x = x + params["patch_proj"]["bias"] + params["pos_embed"][None]
     B, L, _ = x.shape
 
     def body(x, p):
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
-        if cfg.attention_variant == "topo":
-            x = x + topo_vit_attention(cfg, p, p["topo"], h, integ)
-        else:
-            x = x + A.performer_attention_train(
-                cfg, p["attn"], h,
-                jnp.zeros((B, L), jnp.int32), causal=False)
+        with jax.named_scope("vit.attn"):
+            if cfg.attention_variant == "topo":
+                x = x + topo_vit_attention(cfg, p, p["topo"], h, integ)
+            else:
+                x = x + A.performer_attention_train(
+                    cfg, p["attn"], h,
+                    jnp.zeros((B, L), jnp.int32), causal=False)
         h = rms_norm(x, p["mlp_norm"]["scale"], cfg.norm_eps, plus_one=True)
-        x = x + gated_mlp(p["mlp"], h, cfg.mlp_act)
+        with jax.named_scope("vit.mlp"):
+            x = x + gated_mlp(p["mlp"], h, cfg.mlp_act)
         return x, ()
 
     # per-layer remat: Alg. 1's expanded (L, m*hd) fields and their FFTs
@@ -190,8 +199,11 @@ def forward(cfg, params, patches, integ):
     # plan arrays are numpy constants: python loop over stacked params
     n = jax.tree.leaves(params["blocks"])[0].shape[0]
     for i in range(n):
-        layer = jax.tree.map(lambda a: a[i], params["blocks"])
+        with jax.named_scope("vit.layer_params"):
+            layer = jax.tree.map(lambda a: a[i], params["blocks"])
         x, _ = body(x, layer)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps, plus_one=True)
-    pooled = jnp.mean(x, axis=1)
-    return pooled @ params["head"]["kernel"] + params["head"]["bias"]
+    with jax.named_scope("vit.head"):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps,
+                     plus_one=True)
+        pooled = jnp.mean(x, axis=1)
+        return pooled @ params["head"]["kernel"] + params["head"]["bias"]

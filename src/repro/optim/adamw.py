@@ -60,21 +60,23 @@ def clip_by_global_norm(grads, max_norm: float):
 
 
 def adamw_update(grads, state: AdamWState, params, cfg: AdamWConfig):
-    """Returns (new_params, new_state, metrics)."""
-    grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
-    step = state.step + 1
-    lr = cosine_schedule(step, cfg)
-    b1, b2 = cfg.b1, cfg.b2
-    mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, state.mu, grads)
-    nu = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * jnp.square(g), state.nu, grads)
-    bc1 = 1.0 - b1 ** step.astype(jnp.float32)
-    bc2 = 1.0 - b2 ** step.astype(jnp.float32)
+    """Returns (new_params, new_state, metrics). Runs under the
+    `jax.named_scope` "adamw", the global-norm clip included."""
+    with jax.named_scope("adamw"):
+        grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
+        step = state.step + 1
+        lr = cosine_schedule(step, cfg)
+        b1, b2 = cfg.b1, cfg.b2
+        mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, state.mu, grads)
+        nu = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * jnp.square(g), state.nu, grads)
+        bc1 = 1.0 - b1 ** step.astype(jnp.float32)
+        bc2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, m, v):
-        mhat = m / bc1
-        vhat = v / bc2
-        return (p - lr * (mhat / (jnp.sqrt(vhat) + cfg.eps)
-                          + cfg.weight_decay * p)).astype(p.dtype)
+        def upd(p, m, v):
+            mhat = m / bc1
+            vhat = v / bc2
+            return (p - lr * (mhat / (jnp.sqrt(vhat) + cfg.eps)
+                              + cfg.weight_decay * p)).astype(p.dtype)
 
-    new_params = jax.tree.map(upd, params, mu, nu)
-    return new_params, AdamWState(step, mu, nu), {"grad_norm": gnorm, "lr": lr}
+        new_params = jax.tree.map(upd, params, mu, nu)
+        return new_params, AdamWState(step, mu, nu), {"grad_norm": gnorm, "lr": lr}

@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import trace_guard
 from repro.core import cordial as C
 from repro.core.engines import Integrator, available_backends, spec_of
 from repro.core.integrate import BTFI, ExpMP
@@ -155,12 +156,14 @@ def test_fastmult_cache_hit_no_retrace(backend, rng):
     fm2 = integ.fastmult(C.Exponential(-0.7, 1.3))  # equal, distinct object
     assert fm1 is fm2
     assert fm1.jitted
+    site = "engines.plan.fastmult"
+    n0 = trace_guard.compiles(site)
     np.asarray(fm1(X))
-    assert fm1.trace_count == 1
-    np.asarray(fm1(X))  # same shapes: cache hit, no retrace
-    assert fm1.trace_count == 1
-    np.asarray(integ.integrate(C.Exponential(-0.7, 1.3), X))
-    assert fm1.trace_count == 1
+    assert trace_guard.compiles(site) == n0 + 1
+    with trace_guard.expect_stable(site):
+        np.asarray(fm1(X))  # same shapes: cache hit, no retrace
+    with trace_guard.expect_stable(site):
+        np.asarray(integ.integrate(C.Exponential(-0.7, 1.3), X))
     # different family spec -> different compiled closure
     assert integ.fastmult(C.Exponential(-0.2)) is not fm1
 

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
+from repro.analysis import trace_guard
 from repro.core import cordial as C
 from repro.core.engines import Integrator
 from repro.core.itree_flat import build_flat_forest, build_flat_it
@@ -119,10 +120,12 @@ def test_forest_single_fused_dispatch(rng):
     X = rng.normal(size=(forest.num_vertices, 2))
     integ = Integrator.from_forest(forest, backend="plan", leaf_size=16)
     fm = integ.fastmult(C.Exponential(-0.4))
+    site = "engines.plan.fastmult"
+    n0 = trace_guard.compiles(site)
     np.asarray(fm(X))
-    assert fm.trace_count == 1
-    np.asarray(fm(X))
-    assert fm.trace_count == 1  # same shapes: no retrace
+    assert trace_guard.compiles(site) == n0 + 1
+    with trace_guard.expect_stable(site):
+        np.asarray(fm(X))  # same shapes: no retrace
     plan = integ._impl.plan
     # buckets are merged across trees by size class: far fewer buckets than
     # trees (the whole point of the shared index space)
