@@ -215,9 +215,13 @@ def vit_batch(cfg, batch: int, patch_dim: int, num_classes: int, seed: int):
 
 
 def mask_engine(cfg, integ, params) -> str:
-    """The cross engine the grid integrator runs for layer 0's mask."""
-    from repro.core.masks import mask_f
+    """What runs layer 0's mask: "dense" (the small-tree product by f(D)),
+    else the grid integrator's cross engine."""
+    from repro.core.masks import mask_f, tree_fastmult_path
     from repro.models import attention as A
+
+    if tree_fastmult_path(integ) == "dense":
+        return "dense"
 
     p_topo = {k: v[0] for k, v in params["blocks"]["topo"].items()}
     coeffs = np.asarray(A.topo_mask_coeffs(cfg, p_topo)[0])

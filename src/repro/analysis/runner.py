@@ -148,18 +148,23 @@ def run_trace_guard(budgets: dict) -> dict:
     except tg.RetraceError as e:
         issues.append(f"trace_guard[ftfi-fastmult]: {e}")
 
-    # 3. mask-closure LRU (serving / eval rebuild path)
+    # 3. mask-closure LRU (serving / eval rebuild path), on the plan
+    # executor: the 64-vertex tree would take the dense path, which
+    # compiles no executor for the counters to watch
     coeffs = np.asarray([1.0, -0.5], np.float32)
     F = jnp.asarray(rng.standard_normal((2, 64, 3)), jnp.float32)
-    mfm = masks.make_tree_fastmult(integ, "exp", coeffs, 1.0)
-    mfm(F)  # new f family -> exactly one compile
-    hits0 = tg.compiles("masks.tree_fastmult:hit")
+    n_dense, masks.N_DENSE = masks.N_DENSE, 0
     try:
+        mfm = masks.make_tree_fastmult(integ, "exp", coeffs, 1.0)
+        mfm(F)  # new f family -> exactly one compile
+        hits0 = tg.compiles("masks.tree_fastmult:hit")
         with stable("engines.plan.fastmult", "ftfi.fastmult"):
             masks.make_tree_fastmult(integ, "exp", coeffs, 1.0)(F)
             mfm(F)
     except tg.RetraceError as e:
         issues.append(f"trace_guard[mask-memo]: {e}")
+    finally:
+        masks.N_DENSE = n_dense
     if tg.compiles("masks.tree_fastmult:hit") <= hits0:
         issues.append("trace_guard[mask-memo]: rebuilding an identical mask "
                       "closure missed the _TREE_FM_CACHE")

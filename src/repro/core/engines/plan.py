@@ -133,6 +133,10 @@ class PlanBackend:
         # a Forest compiles into ONE fused plan over the packed vertex space:
         # the executor below is oblivious to how many trees it covers
         self.forest = tree if isinstance(tree, Forest) else None
+        # the single tree the plan was built from (None for a forest or a
+        # loaded plan): `masks.make_tree_fastmult` takes its exact
+        # all-pairs distances from it for the dense small-tree path
+        self.tree = tree if self.forest is None else None
         if plan is not None:  # facade-from-artifact path: zero IT rebuild
             self.plan = plan
         elif self.forest is not None:

@@ -4,6 +4,8 @@ The mask is M = [f(dist(i,j))] with f = g(sum_t a_t x^t) and (a_t) learnable —
 **3 extra scalars** per layer (synced) or per head (asynced). FastMult_M:
   - sequences (LM archs): Toeplitz FFT, exact for any f (core.toeplitz);
   - grids/graphs (ViT):   IT-plan executor, exact engines (core.integrate);
+    on a single tree of at most N_DENSE vertices, one dense f32 product by
+    f(D) over the exact all-pairs distances (make_tree_fastmult);
   - many graphs at once:  make_forest_fastmult over a packed Forest — each
     request's own mask applied block-diagonally in ONE fused dispatch.
 
@@ -179,6 +181,15 @@ def make_sequence_fastmult(g: str, coeffs, L: int, causal: bool,
 
 
 _TREE_FM_CACHE = BoundedLRU(64)
+_TREE_DIST_CACHE = BoundedLRU(8)
+
+# Largest single tree whose mask FastMult is one dense f32 product by
+# M = f(D) (see make_tree_fastmult). On a TPU v5e at width 4096 the dense
+# product is 2.3x (n = 196) to 18x (n = 4096) faster than the plan
+# executor (benchmarks/sweep_tree_fastmult.py, PERF.md); above 4096 it is
+# not measured, and D, a constant of n^2 f32 held on the host and in each
+# compiled program, reaches 64 MiB here.
+N_DENSE = 4096
 
 
 def _purge_dead_tree_fm_entries():
@@ -204,29 +215,95 @@ def _resolve_plan_handle(integrator):
     return (impl, getattr(impl, "spec", None), getattr(impl, "params", None))
 
 
+def _has_tracer(tree) -> bool:
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def _takes_dense(spec, params, *, use_shard: bool = False,
+                 params_traced: bool = False) -> bool:
+    """The dense path's condition: a single tree of at most N_DENSE
+    vertices, concrete params, no sharding."""
+    return (not use_shard and not params_traced and spec is not None
+            and params is not None and spec.num_trees == 1
+            and spec.n <= N_DENSE)
+
+
+def tree_fastmult_path(integrator) -> str:
+    """"dense" or "plan": the path `make_tree_fastmult` takes over
+    `integrator` (unsharded, with its own concrete params)."""
+    _, spec, params = _resolve_plan_handle(integrator)
+    return "dense" if _takes_dense(spec, params) else "plan"
+
+
+def _tree_distances(impl, spec, params) -> np.ndarray:
+    """(n, n) f32 all-pairs distances of a single-tree plan, computed once
+    per (spec, params) on the host. From the backend's own tree where it
+    has one (`tree_all_pairs`); otherwise (a `(spec, params)` pair, a
+    loaded plan, reweighted params) from the plan itself: the executor with
+    f(s) = s, the exact polynomial engine, applied to the identity field.
+    Per-tree output weights are left out: they scale the output, not D."""
+    key = (spec.digest, id(params))
+    hit = _TREE_DIST_CACHE.get(key)
+    if hit is not None and hit[1]() is params:
+        return hit[0]
+    tree = getattr(impl, "tree", None)
+    if tree is not None:
+        from repro.graphs.traverse import tree_all_pairs
+
+        D = tree_all_pairs(tree)
+    else:
+        from repro.core import cordial, plan_api
+
+        unweighted = dataclasses.replace(params, tree_w=None)
+        # may run inside an enclosing trace: evaluate now, stage nothing
+        with jax.ensure_compile_time_eval():
+            D = jax.jit(plan_api.fastmult(spec, cordial.Polynomial(
+                (0.0, 1.0))))(unweighted, jnp.eye(spec.n, dtype=jnp.float32))
+    D = np.asarray(D, np.float32)
+    _TREE_DIST_CACHE.put(key, (D, weakref.ref(params)))
+    return D
+
+
 def make_tree_fastmult(integrator, g: str, coeffs,
                        dist_scale: float = 1.0, *, sharded: bool = False,
                        mesh=None) -> Callable:
-    """FastMult_M for M = [f(dist_T(i,j))] via the functional plan API.
+    """FastMult_M for M = [f(dist_T(i,j))] over a tree's plan.
 
-    Works on fields with arbitrary leading batch/head axes: the mask multiply
-    is linear in the field, so everything folds into the trailing field dim of
-    one plan execution. `integrator` is a repro.core.engines.Integrator (any
-    backend with a jit-able fastmult, i.e. plan or pallas) OR a functional
-    `(spec, params)` pair from `ftfi.build` / `ftfi.load_plan`.
+    Works on fields with arbitrary leading batch/head axes (..., L, c).
+    `integrator` is a repro.core.engines.Integrator (any backend with a
+    jit-able fastmult, i.e. plan or pallas) OR a functional `(spec, params)`
+    pair from `ftfi.build` / `ftfi.load_plan`. One of two paths runs,
+    chosen from what the closure can observe:
+
+    - dense: a single tree (`spec.num_trees == 1`) of at most `N_DENSE`
+      vertices, with concrete params and no sharding. The closure applies
+      M = f(D) as one f32 contraction at `HIGHEST` precision, D the tree's
+      exact all-pairs distances (computed once per plan on the host, a
+      constant of the compiled program). It is the same product the plan
+      evaluates, with no term dropped, and exact in f32; on trees this
+      small the executor's gathers and scatter-adds cost far more than
+      the n x n product. M is built from `coeffs` inside the trace, so
+      gradients reach them as on the plan path.
+    - plan: everything else (forests, whose cross-tree entries must stay
+      0; params traced under an enclosing jit, e.g. reweighted edge
+      weights; the sharded path; larger trees). The mask multiply is
+      linear in the field, so every leading axis folds into the trailing
+      field dim of one plan execution.
 
     `sharded=True` rides the multi-device shard_map executor
     (`plan_shard.sharded_fastmult`) over `mesh` (default: the active
     `launch.sharding` mesh): leaf blocks over the plan axis, halo exchange +
     psum_scatter, exact to the single-device path. With no mesh (or one
-    device) it falls back to the single-device executor, so model code can
+    device) it falls back to the single-device paths, so model code can
     pass `sharded=cfg.topo_shard_plan` unconditionally.
 
-    For concrete (non-traced) coefficients the closure is memoized per
-    (integrator-or-spec, g, coeffs, dist_scale[, mesh]), so repeated mask
-    rebuilds (serving, eval loops) reuse one compiled executor; traced
-    coeffs (training under jit) bypass the cache and trace inline as
-    before."""
+    Each bound closure records `masks.tree_fastmult:dense` or `:plan` in
+    `trace_guard`. For concrete (non-traced) coefficients the closure is
+    memoized per (integrator-or-spec, g, coeffs, dist_scale[, mesh]), so
+    repeated mask rebuilds (serving, eval loops) reuse one compiled
+    executor; traced coeffs (training under jit) bypass the cache and
+    trace inline as before."""
     impl, p_spec, p_params = _resolve_plan_handle(integrator)
     if sharded and mesh is None:
         from repro.launch import sharding
@@ -236,15 +313,13 @@ def make_tree_fastmult(integrator, g: str, coeffs,
                  and int(mesh.devices.size) > 1
                  and p_spec is not None and p_params is not None)
     ref_target = integrator if impl is not None else p_spec
+    # reweighted params may themselves be traced (training edge weights
+    # under an enclosing jit): never cache a tracer-capturing closure
+    params_traced = (impl is None or use_shard) and _has_tracer(p_params)
+    traced = params_traced or _has_tracer(coeffs)
+    dense = _takes_dense(p_spec, p_params, use_shard=use_shard,
+                         params_traced=params_traced)
     key = None
-    traced = any(isinstance(leaf, jax.core.Tracer)
-                 for leaf in jax.tree_util.tree_leaves(coeffs))
-    if impl is None or use_shard:
-        # reweighted params may themselves be traced (training edge weights
-        # under an enclosing jit): never cache a tracer-capturing closure
-        traced = traced or any(
-            isinstance(leaf, jax.core.Tracer)
-            for leaf in jax.tree_util.tree_leaves(p_params))
     if not traced:
         _purge_dead_tree_fm_entries()
         c = np.asarray(coeffs)
@@ -255,14 +330,51 @@ def make_tree_fastmult(integrator, g: str, coeffs,
         key = (id(ref_target),
                id(p_params) if (impl is None or use_shard) else None,
                g, float(dist_scale), c.shape, c.tobytes(),
-               id(mesh) if use_shard else 0)
+               id(mesh) if use_shard else 0, dense)
         hit = _TREE_FM_CACHE.get(key)
         if hit is not None and hit[1]() is ref_target:
             trace_guard.record("masks.tree_fastmult", event="hit")
             return hit[0]
         trace_guard.record("masks.tree_fastmult", event="miss")
+    trace_guard.record("masks.tree_fastmult",
+                       event="dense" if dense else "plan")
     f_eval = mask_f(g, coeffs, dist_scale)
-    if use_shard:
+    if dense:
+        D = _tree_distances(impl, p_spec, p_params)
+        w = p_params.tree_w
+
+        def fastmult(X):  # X: (..., L, c)
+            with jax.named_scope("ftfi.dense"):
+                M = f_eval(jnp.asarray(D))
+                if w is not None:
+                    M = M * jnp.asarray(w, jnp.float32)[0]
+                return jnp.einsum("ij,...jc->...ic", M,
+                                  X.astype(jnp.float32),
+                                  precision=jax.lax.Precision.HIGHEST)
+    else:
+        fastmult = _plan_fastmult(impl, p_spec, p_params, f_eval, traced,
+                                  mesh if use_shard else None)
+
+    if key is not None:
+        try:
+            ref = weakref.ref(ref_target)
+        except TypeError:
+            ref = None
+        if ref is not None:
+            # weakly referenced: the purge above drops the entry (and the
+            # plan/closure memory it pins) once the integrator/spec dies.
+            # p_params rides along strongly so the id() in the key cannot
+            # be recycled while the entry lives (None on the impl path).
+            _TREE_FM_CACHE.put(key, (fastmult, ref, p_params))
+    return fastmult
+
+
+def _plan_fastmult(impl, p_spec, p_params, f_eval, traced: bool,
+                   mesh) -> Callable:
+    """The plan path of `make_tree_fastmult`: every leading axis of the
+    field folded into the trailing field dim of one plan execution, on the
+    sharded executor when `mesh` is given."""
+    if mesh is not None:
         # multi-device path: shard_map executor over the mesh; the closure
         # pins `mesh`, so the id() in the memo key stays valid for the
         # entry's lifetime
@@ -297,17 +409,6 @@ def make_tree_fastmult(integrator, g: str, coeffs,
         out = out.reshape(L, shape[-1], -1)
         return jnp.moveaxis(out, -1, 0).reshape(shape)
 
-    if key is not None:
-        try:
-            ref = weakref.ref(ref_target)
-        except TypeError:
-            ref = None
-        if ref is not None:
-            # weakly referenced: the purge above drops the entry (and the
-            # plan/closure memory it pins) once the integrator/spec dies.
-            # p_params rides along strongly so the id() in the key cannot
-            # be recycled while the entry lives (None on the impl path).
-            _TREE_FM_CACHE.put(key, (fastmult, ref, p_params))
     return fastmult
 
 

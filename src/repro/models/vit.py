@@ -2,7 +2,9 @@
 
 Performer attention with the RPE mask M = [f(dist_MST(i,j))] over the
 2D-grid-graph MST of image patches, applied through Algorithm 1 with the
-IT-plan FastMult (exact). 3 learnable mask scalars per layer (synced).
+tree FastMult of `masks.make_tree_fastmult` (exact: a grid of at most
+`masks.N_DENSE` patches takes the dense product by f(D), larger ones the
+IT plan). 3 learnable mask scalars per layer (synced).
 """
 from __future__ import annotations
 
@@ -136,9 +138,10 @@ def init_params(cfg, key, num_classes: int = 1000, patch_dim: int = 768):
 def topo_vit_attention(cfg, p, p_topo, x, integ):
     """Grid-MST masked linear attention. The cfg.topo_attn_impl axis rides
     through here too: `ref` materializes the dense tree mask (oracle), any
-    other impl runs Algorithm 1 with the IT-plan FastMult — whose executor
-    backend (plan vs fused pallas fdist_matvec) was picked when `integ` was
-    built (build_grid_integrator)."""
+    other impl runs Algorithm 1 with `make_tree_fastmult` over `integ`: the
+    dense product by f(D) on a grid of at most `masks.N_DENSE` patches,
+    else the IT plan on the executor backend (plan vs fused pallas
+    fdist_matvec) picked when `integ` was built (build_grid_integrator)."""
     B, L, _ = x.shape
     q, k, v = A._project_qkv(cfg, p["attn"], x,
                              jnp.zeros((B, L), jnp.int32), rope=False)
@@ -192,9 +195,9 @@ def forward(cfg, params, patches, integ):
             x = x + gated_mlp(p["mlp"], h, cfg.mlp_act)
         return x, ()
 
-    # per-layer remat: Alg. 1's expanded (L, m*hd) fields and their FFTs
-    # are the largest activations; without it every layer's stay live for
-    # the backward pass
+    # per-layer remat: Alg. 1's expanded (L, m*hd) fields are the largest
+    # activations; without it every layer's stay live for the backward
+    # pass
     body = maybe_remat(body, cfg)
     # plan arrays are numpy constants: python loop over stacked params
     n = jax.tree.leaves(params["blocks"])[0].shape[0]

@@ -130,15 +130,31 @@ def test_chebyshev_separable_decode(rng):
     assert max(errs) < 1e-4
 
 
+def _pin_path(monkeypatch, path):
+    """Steer make_tree_fastmult: N_DENSE = 0 forces the plan executor."""
+    if path == "plan":
+        monkeypatch.setattr(MK, "N_DENSE", 0)
+
+
+def _path_counts():
+    from repro.analysis import trace_guard
+
+    return {p: trace_guard.compiles(f"masks.tree_fastmult:{p}")
+            for p in ("dense", "plan")}
+
+
+@pytest.mark.parametrize("path", ["dense", "plan"])
 @pytest.mark.parametrize("backend", ["plan", "pallas"])
-def test_grid_mask_fastmult(backend, rng):
+def test_grid_mask_fastmult(backend, path, rng, monkeypatch):
     """ViT grid masks through the Integrator == dense mask multiply, with
-    batch/head axes folded by the tree fastmult factory."""
+    batch/head axes folded by the tree fastmult factory, on both of its
+    paths (the dense product by f(D), and the plan executor)."""
     from repro.core.engines import Integrator
     from repro.graphs.graph import grid_graph
     from repro.graphs.mst import minimum_spanning_tree
     from repro.graphs.traverse import tree_all_pairs
 
+    _pin_path(monkeypatch, path)
     g = grid_graph(6, 6)
     mst = minimum_spanning_tree(g)
     integ = Integrator(mst, backend=backend, leaf_size=8)
@@ -146,6 +162,145 @@ def test_grid_mask_fastmult(backend, rng):
     coeffs = jnp.asarray([0.0, -0.3], jnp.float32)
     X = jnp.asarray(rng.normal(size=(2, 36, 5)), jnp.float32)  # batched field
     ref = np.einsum("lk,bkd->bld", np.exp(-0.3 * D), np.asarray(X))
+    n0 = _path_counts()
     fm = MK.make_tree_fastmult(integ, "exp", coeffs, dist_scale=1.0)
+    n1 = _path_counts()
+    assert n1[path] == n0[path] + 1
     got = np.asarray(fm(X))
     assert np.max(np.abs(got - ref)) / np.max(np.abs(ref)) < 1e-5
+
+
+def test_grid14_dense_and_plan_agree_with_f64_reference(monkeypatch):
+    """The cell's tree (the 14 x 14 grid MST, n = 196) under a degree-2 exp
+    mask: the dense product and the plan executor give the same outputs
+    and the same gradients w.r.t. the mask coefficients and the field, and
+    both match a float64 dense reference to 1e-5 relative."""
+    import jax
+    from repro.core.engines import Integrator
+    from repro.graphs.graph import grid_graph
+    from repro.graphs.mst import minimum_spanning_tree
+    from repro.graphs.traverse import tree_all_pairs
+
+    mst = minimum_spanning_tree(grid_graph(14, 14))
+    integ = Integrator(mst, backend="plan", leaf_size=16)
+    scale = 0.0625
+    c = np.asarray([0.1, -0.5, -0.2])
+    r = np.random.default_rng(14)
+    X = r.normal(size=(2, 196, 6))
+    G = r.normal(size=(2, 196, 6))
+
+    def loss(c, X):
+        fm = MK.make_tree_fastmult(integ, "exp", c, scale)
+        return jnp.sum(fm(X) * G), fm(X)
+
+    def run():
+        (_, out), (dc, dX) = jax.value_and_grad(loss, argnums=(0, 1),
+                                                has_aux=True)(
+            jnp.asarray(c, jnp.float32), jnp.asarray(X, jnp.float32))
+        return [np.asarray(a, np.float64) for a in (out, dc, dX)]
+
+    n0 = _path_counts()
+    dense = run()
+    monkeypatch.setattr(MK, "N_DENSE", 0)
+    plan = run()
+    n1 = _path_counts()
+    assert n1["dense"] > n0["dense"] and n1["plan"] > n0["plan"]
+
+    S = tree_all_pairs(mst) * scale
+    M = np.exp(c[0] + c[1] * S + c[2] * S * S)
+    GX = np.einsum("bic,bjc->ij", G, X)
+    ref = [np.einsum("ij,bjc->bic", M, X),
+           np.asarray([np.sum(GX * M * S ** t) for t in range(3)]),
+           np.einsum("ij,bic->bjc", M, G)]
+
+    def rel(a, b):
+        return np.max(np.abs(a - b)) / np.max(np.abs(b))
+
+    for name, d, p, want in zip(("out", "d_coeffs", "d_X"), dense, plan,
+                                ref):
+        assert rel(d, want) < 1e-5, name
+        assert rel(p, want) < 1e-5, name
+        assert rel(d, p) < 1e-5, name
+
+
+def _counter_case(case, monkeypatch):
+    """(make the closure, the path it must take) for each kind of plan."""
+    import jax
+    import repro.ftfi as ftfi
+    from repro.core.engines import Integrator
+    from repro.graphs.graph import Forest, random_tree
+
+    coeffs = np.asarray([0.1, -0.4], np.float32)
+    if case == "single_tree":
+        integ = Integrator(random_tree(40, seed=2), leaf_size=8)
+        return (lambda: MK.make_tree_fastmult(integ, "exp", coeffs)), "dense"
+    if case == "forest":
+        forest = Forest([random_tree(20 + 3 * i, seed=i) for i in range(3)])
+        integ = Integrator.from_forest(forest, leaf_size=8)
+        return (lambda: MK.make_forest_fastmult(integ, forest, "exp",
+                                                coeffs)), "plan"
+    if case == "traced_params":
+        tree = random_tree(30, seed=1)
+        spec, _ = ftfi.build(tree, leaf_size=8, reweightable=True)
+
+        def make():
+            @jax.jit
+            def use(w):
+                fm = MK.make_tree_fastmult((spec, ftfi.reweight(spec, w)),
+                                           "exp", coeffs)
+                return fm(jnp.ones((spec.n, 2), jnp.float32))
+
+            return use(jnp.asarray(tree.weights, jnp.float32))
+
+        return make, "plan"
+    assert case == "above_n_dense"
+    monkeypatch.setattr(MK, "N_DENSE", 32)
+    integ = Integrator(random_tree(40, seed=2), leaf_size=8)
+    return (lambda: MK.make_tree_fastmult(integ, "exp", coeffs)), "plan"
+
+
+@pytest.mark.parametrize("case", ["single_tree", "forest", "traced_params",
+                                  "above_n_dense"])
+def test_tree_fastmult_path_counter(case, monkeypatch):
+    """Each bound closure records which path it took: dense for a single
+    small tree, the plan executor for a forest, for params traced under an
+    enclosing jit, and for a tree above N_DENSE."""
+    make, want = _counter_case(case, monkeypatch)
+    other = "plan" if want == "dense" else "dense"
+    n0 = _path_counts()
+    make()
+    n1 = _path_counts()
+    assert n1[want] == n0[want] + 1
+    assert n1[other] == n0[other]
+
+
+@pytest.mark.parametrize("source", ["pair", "from_plan", "reweighted",
+                                    "tree_weight"])
+def test_dense_distances_from_plan(source, rng):
+    """Without a tree to read, the dense path derives D from the plan
+    itself (the executor with f(s) = s on the identity field): the closure
+    matches the plan executor's own mask multiply, a per-tree output weight
+    included."""
+    import repro.ftfi as ftfi
+    from repro.core.engines import Integrator
+    from repro.graphs.graph import random_tree
+
+    tree = random_tree(50, seed=5)
+    spec, params = ftfi.build(tree, leaf_size=8, reweightable=True)
+    if source == "reweighted":
+        params = ftfi.reweight(spec, rng.uniform(
+            0.3, 1.5, size=tree.num_edges).astype(np.float32))
+    elif source == "tree_weight":
+        params = ftfi.reweight(spec, np.asarray(tree.weights, np.float32),
+                               tree_w=np.asarray([1.7], np.float32))
+    handle = ((spec, params) if source != "from_plan"
+              else Integrator.from_plan(spec, params))
+    coeffs = np.asarray([0.2, -0.3, -0.05], np.float32)
+    X = jnp.asarray(rng.normal(size=(3, 50, 4)), jnp.float32)
+    n0 = _path_counts()
+    got = MK.make_tree_fastmult(handle, "exp", coeffs, 0.5)(X)
+    assert _path_counts()["dense"] == n0["dense"] + 1
+    f = MK.mask_f("exp", coeffs, 0.5)
+    want = np.stack([np.asarray(ftfi.apply(spec, params, f, x)) for x in X])
+    assert np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)) \
+        < 1e-5

@@ -116,9 +116,9 @@ def test_vit_step_parts_named_forward_and_backward(remat):
         assert any("transpose(" in n.split(scope)[0] for n in ops), \
             f"no backward op under {scope}"
     assert _scoped(names, "adamw")
-    # the executor's phases nest inside Alg. 1 (the 4 x 4 grid's plan is
-    # one leaf block)
-    assert any("vit.alg1/ftfi.leaf" in n for n in names)
+    # the mask product nests inside Alg. 1 (the 4 x 4 grid is a single
+    # small tree, so it is the dense product by f(D))
+    assert any("vit.alg1/ftfi.dense" in n for n in names)
 
 
 def test_span_nests_and_accumulates():
