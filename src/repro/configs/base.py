@@ -24,6 +24,7 @@ class ModelConfig:
     performer_phi: str = "relu"  # relu | sq | quart | exp
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rotary_dim: int = 0  # leading dims of each head that rotate (0: all)
     attn_logit_softcap: float = 0.0
 
     # topological (paper) masking
@@ -55,6 +56,10 @@ class ModelConfig:
     first_dense_layers: int = 0
     router_aux_loss: float = 0.001
     moe_groups: int = 1  # data-local dispatch groups (§Perf iteration B)
+    # chip-share expert layer: the global ids of the experts held here (the
+    # router still scores all num_experts; the absent ones' part is left
+    # out). Non-empty selects the dropless held-expert layer (moe.py).
+    moe_held: tuple = ()
 
     # MLA (deepseek)
     mla: bool = False
@@ -76,6 +81,9 @@ class ModelConfig:
     tail_blocks: tuple = ()
     lru_width: int = 0
     local_window: int = 0
+    # lightning (minimax): per-head decays 2^(-8(h+1)/H) (1 - l/(N-1) + 1e-5)
+    # at published layer l of N = lightning_decay_layers
+    lightning_decay_layers: int = 0
 
     # encoder-decoder
     is_encdec: bool = False
@@ -89,6 +97,10 @@ class ModelConfig:
 
     # norm / misc
     norm_eps: float = 1e-6
+    # post-norm residual (minimax): h = norm(x); x = alpha h + beta f(h)
+    postnorm: bool = False
+    residual_alpha: float = 1.0
+    residual_beta: float = 1.0
     remat_policy: str = "dots"  # dots | nothing (full remat) | none (no remat)
     seq_sharded_residuals: bool = False  # Megatron-SP residual stream
     tie_embeddings: bool = False
@@ -128,6 +140,7 @@ ARCHS = [
     "deepseek_v2_lite_16b",
     "deepseek_v3_671b",
     "topovit_b16",
+    "minimax_text_01",
 ]
 
 _ALIASES = {
@@ -142,6 +155,7 @@ _ALIASES = {
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
     "deepseek-v3-671b": "deepseek_v3_671b",
     "topovit-b16": "topovit_b16",
+    "minimax-text-01": "minimax_text_01",
 }
 
 

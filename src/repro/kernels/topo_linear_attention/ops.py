@@ -23,6 +23,11 @@ Mask families (selected from `g` and the coefficient count, both paths):
 
 Coefficients are per-head (H, t+1) (a synced (t+1,) vector broadcasts), i.e.
 both synced and asynced mask parameterizations ride the same kernel.
+
+`normalize=False` returns the masked sum (M ⊙ QK^T) V itself, with no
+denominator: the features may then have any sign (lightning attention's
+SiLU features). Each call records the family it took in `trace_guard`
+(`attention.topo:decay` / `attention.topo:rank`) once per trace.
 """
 from __future__ import annotations
 
@@ -32,6 +37,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import trace_guard
 from repro.core import masks as MK
 from repro.kernels.topo_linear_attention.kernel import (
     topo_attention_sweep_pallas)
@@ -46,6 +52,7 @@ class TopoSpec(NamedTuple):
     rank: int
     eps: float
     interpret: bool
+    normalize: bool = True
 
 
 def _round_up(n: int, k: int) -> int:
@@ -90,18 +97,25 @@ def _prepare(spec: TopoSpec, coeffs, Lp: int):
 
 
 def _pad_inputs(spec: TopoSpec, qf, kf, v, coeffs):
+    """Pad L to a chunk multiple, in the inputs' own dtype (the kernel reads
+    them into float32 tiles; the XLA twin upcasts)."""
     L = qf.shape[2]
     Lp = _round_up(L, spec.chunk)
     pad = ((0, 0), (0, 0), (0, Lp - L), (0, 0))
-    kf = kf.astype(jnp.float32)
-    if _is_separable(spec.g, coeffs):
+    if _is_separable(spec.g, coeffs) and spec.normalize:
         # decay mode carries gamma^(i-j) only; fold the mask's e^{a0} factor
         # into kf so num/den match the other impls even where the eps
-        # denominator clamp binds
-        kf = kf * jnp.exp(coeffs[:, 0])[None, :, None, None]
-    return (jnp.pad(qf.astype(jnp.float32), pad),
-            jnp.pad(kf, pad),
-            jnp.pad(v.astype(jnp.float32), pad), Lp)
+        # denominator clamp binds (unnormalized, it scales the output)
+        kf = kf.astype(jnp.float32) * jnp.exp(coeffs[:, 0])[None, :, None,
+                                                             None]
+    return jnp.pad(qf, pad), jnp.pad(kf, pad), jnp.pad(v, pad), Lp
+
+
+def _a0_scale(spec: TopoSpec, coeffs, out):
+    """The unnormalized decay-mode output times the mask's e^{a0}."""
+    if _is_separable(spec.g, coeffs) and not spec.normalize:
+        return out * jnp.exp(coeffs[:, 0])[None, :, None, None]
+    return out
 
 
 def _flip(t):
@@ -118,7 +132,8 @@ def _pallas_forward(spec: TopoSpec, qf, kf, v, coeffs):
     if spec.causal:
         out = topo_attention_sweep_pallas(
             qp, kp, vp, dmat_inc, log_gamma=lg, alpha=alpha, beta=beta,
-            normalize=True, **kw)
+            normalize=spec.normalize, **kw)
+        out = out if spec.normalize else _a0_scale(spec, coeffs, out[0])
         return out[:, :, :L]
     num, den = topo_attention_sweep_pallas(
         qp, kp, vp, dmat_inc, log_gamma=lg, alpha=alpha, beta=beta,
@@ -133,7 +148,9 @@ def _pallas_forward(spec: TopoSpec, qf, kf, v, coeffs):
         _flip(qp), _flip(kp), _flip(vp), dmat_strict, log_gamma=lg,
         alpha=alpha, beta=beta,
         res_num=_flip(num), res_den=jnp.flip(den, axis=2),
-        normalize=True, **kw)
+        normalize=spec.normalize, **kw)
+    if not spec.normalize:
+        out_rev = _a0_scale(spec, coeffs, out_rev[0])
     return _flip(out_rev)[:, :, :L]
 
 
@@ -145,6 +162,7 @@ def _pallas_forward(spec: TopoSpec, qf, kf, v, coeffs):
 
 def _sweep_xla(qp, kp, vp, dmat, lg=None, alpha=None, beta=None):
     """One causal sweep over chunks; returns (num, den) pre-normalization."""
+    qp, kp, vp = (t.astype(jnp.float32) for t in (qp, kp, vp))
     B, H, Lp, m = qp.shape
     hd = vp.shape[-1]
     C = dmat.shape[-1]
@@ -213,6 +231,8 @@ def _xla_forward(spec: TopoSpec, qf, kf, v, coeffs):
                             lg, alpha, beta)
         num = num + _flip(nb)
         den = den + jnp.flip(db, axis=2)
+    if not spec.normalize:
+        return _a0_scale(spec, coeffs, num)[:, :, :L]
     den = jnp.where(jnp.abs(den) < spec.eps, spec.eps, den)
     return (num / den[..., None])[:, :, :L]
 
@@ -248,11 +268,14 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 def topo_linear_attention(qf, kf, v, coeffs, *, g: str = "exp",
                           dist_scale: float = 1.0, causal: bool = True,
                           chunk: int = 128, rank: int = 16,
-                          eps: float = 1e-6, use_kernel: bool | None = None,
+                          eps: float = 1e-6, normalize: bool = True,
+                          use_kernel: bool | None = None,
                           interpret: bool | None = None):
     """Fused Alg.-1 masked linear attention over the sequence mask.
 
-    qf/kf: (B, H, L, m) nonneg phi features; v: (B, H, L, hd);
+    qf/kf: (B, H, L, m) phi features (nonneg where normalized; any sign
+    with normalize=False, which returns the unnormalized masked sum);
+    v: (B, H, L, hd);
     coeffs: (H, t+1) or (t+1,) effective mask coefficients (already
     constraint-shaped, e.g. attention.topo_mask_coeffs). Any L (padded to a
     chunk multiple internally), any head count. Returns (B, H, L, hd) f32.
@@ -273,7 +296,9 @@ def topo_linear_attention(qf, kf, v, coeffs, *, g: str = "exp",
         interpret = not on_tpu
     C = min(chunk, _round_up(L, 8))
     spec = TopoSpec(g, float(dist_scale), bool(causal), C, int(rank),
-                    float(eps), bool(interpret))
+                    float(eps), bool(interpret), bool(normalize))
+    trace_guard.record("attention.topo", event=(
+        "decay" if _is_separable(g, coeffs) else "rank"))
     if use_kernel:
         return _fused(spec, qf, kf, v, coeffs)
     return _xla_forward(spec, qf, kf, v, coeffs)

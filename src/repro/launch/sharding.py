@@ -128,6 +128,10 @@ PARAM_RULES: list[tuple[str, tuple]] = [
     (r"lm_head/kernel", ("embed", "vocab")),
     (r"(attn|cross_attn)/(wq|wkv|wk|wv)\b.*", ("embed", "heads")),
     (r"(attn|cross_attn)/wo", ("heads", "embed")),
+    # lightning: W_qkv columns are per head [q|k|v], so a head shard holds
+    # its heads' q, k and v; the output norm spans the heads' channels
+    (r"attn/(w_qkv|w_gate)", ("embed", "heads")),
+    (r"attn/out_norm", ("heads",)),
     (r"attn/w_dq", ("embed", None)),
     (r"attn/w_uq", (None, "heads")),
     (r"attn/w_dkv", ("embed", None)),

@@ -90,6 +90,16 @@ def _positions_vec(pos, B):
     return p
 
 
+def _rope(cfg, x, positions):
+    """RoPE on the leading `cfg.rotary_dim` dims of each head (all of them
+    where it is 0); the rest pass through."""
+    rd = getattr(cfg, "rotary_dim", 0) or x.shape[-1]
+    if rd == x.shape[-1]:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return jnp.concatenate([apply_rope(x[..., :rd], positions, cfg.rope_theta),
+                            x[..., rd:]], axis=-1)
+
+
 def _project_qkv(cfg, p, x, positions, rope=True):
     B, L, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -102,8 +112,8 @@ def _project_qkv(cfg, p, x, positions, rope=True):
     k = k.reshape(B, L, KV, hd)
     v = v.reshape(B, L, KV, hd)
     if rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(cfg, q, positions)
+        k = _rope(cfg, k, positions)
     q = shard_q_heads(q)
     k = shard(k, ("batch", "seq", "kv_heads", "head_dim"))
     v = shard(v, ("batch", "seq", "kv_heads", "head_dim"))
@@ -176,6 +186,52 @@ def _sdpa_chunked(cfg, q, k, v, causal: bool, window: int, blk: int = 512):
     return out.reshape(B, Lq, H, vd).astype(q.dtype)
 
 
+def _sdpa_causal(q, k, v, blk: int = 1024):
+    """Causal attention that visits only the key blocks at or before each
+    query block: `lax.map` over query blocks, each an online softmax over
+    its own prefix of key blocks (a loop whose trip count is the block's
+    index), so the work is triangular and no (Lq, Lk) score matrix exists.
+    bf16 matmul inputs accumulate in f32; statistics are f32. Forward only
+    (the loop bound is traced). q: (B, L, H, hd); k, v: (B, L, KV, hd)."""
+    B, L, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    blk = min(blk, L)
+    if L % blk:
+        return None
+    nb = L // blk
+    qg = q.reshape(B, L, KV, G, hd)
+    scale = 1.0 / math.sqrt(hd)
+    diag = (jnp.arange(blk)[:, None] >= jnp.arange(blk)[None, :])
+
+    def one(i):
+        qi = jax.lax.dynamic_slice_in_dim(qg, i * blk, blk, axis=1)
+
+        def body(j, carry):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * blk, blk, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * blk, blk, axis=1)
+            s = jnp.einsum("bqkgh,bskh->bkgqs", qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where((j < i) | diag, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            pexp = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bkgqs,bskh->bkgqh", pexp.astype(v.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return m_new, l * corr + jnp.sum(pexp, axis=-1), acc
+
+        m0 = jnp.full((B, KV, G, blk), -1e30, jnp.float32)
+        l0 = jnp.zeros((B, KV, G, blk), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, blk, hd), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, i + 1, body, (m0, l0, a0))
+        return (acc / l[..., None]).astype(q.dtype)
+
+    out = jax.lax.map(one, jnp.arange(nb))  # (nb, B, KV, G, blk, hd)
+    return out.transpose(1, 0, 4, 2, 3, 5).reshape(B, L, H, hd)
+
+
 def full_attention_train(cfg, p, x, positions, causal=True, window=0,
                          rope=True, kv_x=None, kv_positions=None):
     """Training/prefill attention; kv_x enables cross-attention."""
@@ -243,11 +299,16 @@ def full_attention_prefill(cfg, p, x, positions, lengths, cache,
     read. Returns (out (B, Lp, d), new_cache)."""
     B, Lp, _ = x.shape
     q, k_new, v_new = _project_qkv(cfg, p, x, positions, rope=rope)
-    idx = jnp.arange(Lp)
-    mask = (idx[:, None] >= idx[None, :])[None]  # causal (1,Lp,Lp)
-    if window and window > 0:
-        mask = mask & (idx[None, :, None] - idx[None, None, :] < window)
-    out = _sdpa(cfg, q, k_new, v_new, mask[:, None])
+    out = None
+    if (getattr(cfg, "attn_impl", "naive") == "chunked" and not window
+            and not cfg.attn_logit_softcap):
+        out = _sdpa_causal(q, k_new, v_new)
+    if out is None:
+        idx = jnp.arange(Lp)
+        mask = (idx[:, None] >= idx[None, :])[None]  # causal (1,Lp,Lp)
+        if window and window > 0:
+            mask = mask & (idx[None, :, None] - idx[None, None, :] < window)
+        out = _sdpa(cfg, q, k_new, v_new, mask[:, None])
     out = out.reshape(B, Lp, -1) @ p["wo"]
     valid = (lengths > 0)[:, None, None, None]
     k = jax.lax.dynamic_update_slice_in_dim(
@@ -632,8 +693,16 @@ def _expand_kv(cfg, k, v):
     return k, v
 
 
+# sequences up to this length take the Toeplitz mask as one dense f32
+# product: an FFT product's rounding error is absolute (a few ulp of the
+# whole signal), so a row whose normalizer is tiny against the others loses
+# its relative accuracy, and at such lengths the dense product is cheap
+SEQ_DENSE = 256
+
+
 def _topo_fft_attention(cfg, qf, kf, v, coeffs, causal, col_chunk=8):
-    """Algorithm 1 with Toeplitz-FFT FastMult, chunked over feature columns.
+    """Algorithm 1 with Toeplitz-FFT FastMult, chunked over feature columns
+    (a dense Toeplitz product up to `SEQ_DENSE` tokens).
 
     Exact for any g/degree; memory O(B L H chunk*hd) instead of O(B L H m hd).
     Accumulation is float32 end-to-end: inputs are upcast once, the single
@@ -646,8 +715,15 @@ def _topo_fft_attention(cfg, qf, kf, v, coeffs, causal, col_chunk=8):
     B, L, H, m = qf.shape
     hd = v.shape[-1]
     from repro.core.toeplitz import causal_toeplitz_matvec, symmetric_toeplitz_matvec
+    from repro.core.toeplitz import toeplitz_dense
     F = sequence_mask_values(cfg.topo_g, coeffs, L, cfg.topo_dist_scale)  # (H, L)
     fastmult = causal_toeplitz_matvec if causal else symmetric_toeplitz_matvec
+    if L <= SEQ_DENSE:
+        M = toeplitz_dense(F, L, causal)  # (H, L, L)
+
+        def fastmult(_, X):  # X: (B, H, L, c)
+            return jnp.einsum("hij,bhjc->bhic", M, X,
+                              precision=jax.lax.Precision.HIGHEST)
     Fb = F[None]  # (1,H,L)
     qf32, kf32, v32 = (t.astype(jnp.float32) for t in (qf, kf, v))
     d2 = fastmult(Fb, kf32.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
@@ -669,9 +745,9 @@ def _topo_fft_attention(cfg, qf, kf, v, coeffs, causal, col_chunk=8):
 
 
 def topo_decomposition(cfg, coeffs, L: int, rank: int = 24):
-    """f(i-j) = sum_r alpha_r(i) beta_r(j) for i,j in [0,L).
-
-    Exact rank-1 for g=exp,t<=1; otherwise the Chebyshev rank-`rank`
+    """f(i-j) = sum_r alpha_r(i) beta_r(j) for i,j in [0,L), for masks that
+    are not separable (separable ones decode by the relative recurrence of
+    `topo_attention_decode`): the Chebyshev rank-`rank`
     expansion of (i,j) -> f(i-j) on [0,L)^2 (spectral accuracy for smooth f)
     shared with the fused attention kernel
     (core.masks.chebyshev_separable_expansion) — decode states and the fused
@@ -684,16 +760,6 @@ def topo_decomposition(cfg, coeffs, L: int, rank: int = 24):
 
     s = cfg.topo_dist_scale
     H = coeffs.shape[0]
-    if cfg.topo_g == "exp" and cfg.topo_degree <= 1:
-        a1 = coeffs[:, 1] if coeffs.shape[1] > 1 else jnp.zeros(H)
-
-        def alpha(pos):
-            return jnp.exp(a1 * s * pos)[..., None]  # (H,1)
-
-        def beta(pos):
-            return jnp.exp(-a1 * s * pos)[..., None]
-
-        return alpha, beta, 1
     nodes, Bmat = chebyshev_separable_expansion(cfg.topo_g, coeffs, L, s, rank)
     nodes = jnp.asarray(nodes)
 
@@ -711,10 +777,22 @@ def topo_decomposition(cfg, coeffs, L: int, rank: int = 24):
     return alpha, beta, rank
 
 
+def _separable(cfg) -> bool:
+    return cfg.topo_g == "exp" and cfg.topo_degree <= 1
+
+
+def _decay_terms(cfg, coeffs):
+    """(e^{a0}, log gamma) per head of a separable mask e^{a0} gamma^(i-j),
+    gamma = e^{a1 * dist_scale} <= 1."""
+    H = coeffs.shape[0]
+    a1 = coeffs[:, 1] if coeffs.shape[1] > 1 else jnp.zeros(H)
+    return jnp.exp(coeffs[:, 0]), a1 * cfg.topo_dist_scale
+
+
 def topo_decode_init(cfg, B, L, dtype=jnp.float32, rank: int = 24):
     H, hd = cfg.num_heads, cfg.head_dim
     m = hd  # deterministic elementwise phi keeps feature dim = head_dim
-    R = 1 if (cfg.topo_g == "exp" and cfg.topo_degree <= 1) else rank
+    R = 1 if _separable(cfg) else rank
     return {
         "S": jnp.zeros((B, H, R, m, hd), dtype),
         "z": jnp.zeros((B, H, R, m), dtype),
@@ -722,9 +800,13 @@ def topo_decode_init(cfg, B, L, dtype=jnp.float32, rank: int = 24):
 
 
 def topo_attention_decode(cfg, p, p_topo, x, pos, cache, L: int, rank: int = 24):
-    """O(1)-state masked linear attention decode step. pos: () or (B,) —
-    alpha/beta are evaluated per slot position (vmapped), so slots at
-    different sequence depths share one batched step."""
+    """O(1)-state masked linear attention decode step. pos: () or (B,).
+
+    Separable masks (g = exp, degree <= 1) carry the relative recurrence
+    S_n = gamma S_{n-1} + e^{a0} kf_n (x) v_n (and z alike): every factor is
+    <= 1, so the state stays finite at any position. Other masks carry the
+    Chebyshev rank-R moments, alpha/beta evaluated per slot position
+    (vmapped), so slots at different sequence depths share one step."""
     B = x.shape[0]
     H, hd = cfg.num_heads, cfg.head_dim
     pos_v = _positions_vec(pos, B)
@@ -734,16 +816,27 @@ def topo_attention_decode(cfg, p, p_topo, x, pos, cache, L: int, rank: int = 24)
     scale = topo_logit_scale(cfg, p_topo)  # (H,)
     qf = phi_features(q[:, 0] * scale[None, :, None], cfg.performer_phi)
     kf = phi_features(k[:, 0], cfg.performer_phi)
+    vf = v[:, 0].astype(jnp.float32)
     coeffs = topo_mask_coeffs(cfg, p_topo)
-    alpha, beta, R = topo_decomposition(cfg, coeffs, L, rank)
-    pos_f = pos_v.astype(jnp.float32)
-    b = jax.vmap(beta)(pos_f)  # (B,H,R)
-    S = cache["S"] + b[:, :, :, None, None] * (
-        kf[:, :, None, :, None] * v[:, 0].astype(jnp.float32)[:, :, None, None, :])
-    z = cache["z"] + b[:, :, :, None] * kf[:, :, None, :]
-    a = jax.vmap(alpha)(pos_f)  # (B,H,R)
-    num = jnp.einsum("bhm,bhrmv,bhr->bhv", qf, S, a)
-    den = jnp.einsum("bhm,bhrm,bhr->bh", qf, z, a)
+    if _separable(cfg):
+        e0, lg = _decay_terms(cfg, coeffs)
+        g = jnp.exp(lg)[None, :, None]
+        kf = kf * e0[None, :, None]
+        S = (cache["S"] * g[..., None, None]
+             + (kf[..., None] * vf[:, :, None, :])[:, :, None])
+        z = cache["z"] * g[..., None] + kf[:, :, None]
+        num = jnp.einsum("bhm,bhmv->bhv", qf, S[:, :, 0])
+        den = jnp.einsum("bhm,bhm->bh", qf, z[:, :, 0])
+    else:
+        alpha, beta, R = topo_decomposition(cfg, coeffs, L, rank)
+        pos_f = pos_v.astype(jnp.float32)
+        b = jax.vmap(beta)(pos_f)  # (B,H,R)
+        S = cache["S"] + b[:, :, :, None, None] * (
+            kf[:, :, None, :, None] * vf[:, :, None, None, :])
+        z = cache["z"] + b[:, :, :, None] * kf[:, :, None, :]
+        a = jax.vmap(alpha)(pos_f)  # (B,H,R)
+        num = jnp.einsum("bhm,bhrmv,bhr->bhv", qf, S, a)
+        den = jnp.einsum("bhm,bhrm,bhr->bh", qf, z, a)
     den = jnp.where(jnp.abs(den) < 1e-6, 1e-6, den)
     out = (num / den[..., None]).astype(x.dtype).reshape(B, 1, H * hd) @ p["wo"]
     return out, {"S": S, "z": z}
@@ -752,14 +845,14 @@ def topo_attention_decode(cfg, p, p_topo, x, pos, cache, L: int, rank: int = 24)
 def topo_attention_prefill(cfg, p, p_topo, x, positions, lengths, cache,
                            L: int, rank: int = 24, tree_mask=None):
     """Fused topo prefill: exact train-path attention over the prompt plus
-    the closed-form cordial decode state for the prompt tokens,
+    the decode state for the prompt tokens, written (set, not accumulated)
+    into the cache so a reused slot never inherits a previous request's
+    state. Rows with lengths[b] == 0 keep their state untouched.
 
-        S = sum_{j < len_b} beta(j) kf_j (x) v_j,
-        z = sum_{j < len_b} beta(j) kf_j,
-
-    written (set, not accumulated) into the cache so a reused slot never
-    inherits a previous request's state. Rows with lengths[b] == 0 keep
-    their state untouched.
+    Separable masks take the state the relative recurrence reaches after
+    token len_b - 1, S = sum_{j < len_b} e^{a0} gamma^(len_b - 1 - j)
+    kf_j (x) v_j (every exponent <= 0); other masks the Chebyshev moments
+    S = sum_{j < len_b} beta(j) kf_j (x) v_j (and z alike).
 
     `tree_mask` (optional) replaces the sequence Toeplitz mask with a
     per-request tree mask served from a packed forest plan (see
@@ -768,9 +861,8 @@ def topo_attention_prefill(cfg, p, p_topo, x, positions, lengths, cache,
     (-1 = foreign block), 'unpack': (B*Lp,) token -> packed row (-1 = not
     in a tree)}. The prompt attends bidirectionally under the tree metric
     (prefix-LM style — the prompt is completed context); generated tokens
-    continue through the causal cordial recurrence."""
+    continue through the causal recurrence."""
     B, Lp, _ = x.shape
-    H, hd = cfg.num_heads, cfg.head_dim
     if tree_mask is None:
         out = topo_attention_train(cfg, p, p_topo, x, positions, causal=True)
     else:
@@ -780,12 +872,20 @@ def topo_attention_prefill(cfg, p, p_topo, x, positions, lengths, cache,
     k, v = _expand_kv(cfg, k, v)
     kf = phi_features(k, cfg.performer_phi)  # (B,Lp,H,m)
     coeffs = topo_mask_coeffs(cfg, p_topo)
-    alpha, beta, R = topo_decomposition(cfg, coeffs, L, rank)
-    bet = jax.vmap(beta)(jnp.arange(Lp, dtype=jnp.float32))  # (Lp,H,R)
-    vmask = (jnp.arange(Lp)[None, :] < lengths[:, None]).astype(jnp.float32)
-    S = jnp.einsum("blhm,blhv,lhr,bl->bhrmv", kf,
-                   v.astype(jnp.float32), bet, vmask)
-    z = jnp.einsum("blhm,lhr,bl->bhrm", kf, bet, vmask)
+    if _separable(cfg):
+        e0, lg = _decay_terms(cfg, coeffs)
+        w = e0 * _decay_weights(lg, lengths, Lp)  # (B,Lp,H)
+        S = jnp.einsum("blhm,blhv,blh->bhmv", kf, v.astype(jnp.float32),
+                       w)[:, :, None]
+        z = jnp.einsum("blhm,blh->bhm", kf, w)[:, :, None]
+    else:
+        alpha, beta, R = topo_decomposition(cfg, coeffs, L, rank)
+        bet = jax.vmap(beta)(jnp.arange(Lp, dtype=jnp.float32))  # (Lp,H,R)
+        vm = (jnp.arange(Lp)[None, :] < lengths[:, None]).astype(
+            jnp.float32)  # (B,Lp)
+        S = jnp.einsum("blhm,blhv,lhr,bl->bhrmv", kf,
+                       v.astype(jnp.float32), bet, vm)
+        z = jnp.einsum("blhm,lhr,bl->bhrm", kf, bet, vm)
     valid = lengths > 0
     return out, {
         "S": jnp.where(valid[:, None, None, None, None],
@@ -793,6 +893,16 @@ def topo_attention_prefill(cfg, p, p_topo, x, positions, lengths, cache,
         "z": jnp.where(valid[:, None, None, None],
                        z.astype(cache["z"].dtype), cache["z"]),
     }
+
+
+def _decay_weights(lg, lengths, Lp: int):
+    """gamma^(len_b - 1 - j) for j < len_b, else 0: (B, Lp, H) from the
+    per-head log decays lg (H,) <= 0. The exponent is clamped at 0 before
+    exp, so a row past the prompt never overflows on its way to 0."""
+    back = (lengths[:, None] - 1 - jnp.arange(Lp)[None, :])  # (B,Lp)
+    w = jnp.exp(lg[None, None, :]
+                * jnp.maximum(back, 0)[..., None].astype(jnp.float32))
+    return jnp.where((back >= 0)[..., None], w, 0.0)
 
 
 def _topo_tree_masked_attention(cfg, p, p_topo, x, positions, tree_mask):
@@ -832,6 +942,119 @@ def _topo_tree_masked_attention(cfg, p, p_topo, x, positions, tree_mask):
     out_tok = out_tok * (unpack >= 0).astype(out_tok.dtype)[:, None, None]
     out = out_tok.reshape(B, Lp, H, hd)
     return out.astype(x.dtype).reshape(B, Lp, H * hd) @ p["wo"]
+
+
+# --- lightning attention (MiniMax-Text-01): the unnormalized decay mask ----
+#
+# A lightning layer is the sequence topological mask with g = exp, degree 1
+# and fixed per-head coefficients [0, -s_{h,l}] on SiLU features, with no
+# 1/sqrt(d) and no denominator:
+#   [q|k|v]_h = SiLU(x W_qkv)      (W_qkv columns per head: q, k, v)
+#   o_{h,i}   = sum_{j<=i} exp(-s_{h,l} (i-j)) (q_{h,i} . k_{h,j}) v_{h,j}
+#   y         = W_out(sigmoid(x W_g) * RMSNorm(concat_h o_h))
+# with s_{h,l} = 2^(-8(h+1)/H) (1 - l/(N-1) + 1e-5) at published layer l of
+# N. The norm spans all H*hd channels, so it crosses head shards.
+
+
+def lightning_init(key, cfg, dtype=jnp.float32):
+    d, H, hd = cfg.d_model, cfg.num_heads, cfg.head_dim
+    ks = jax.random.split(key, 3)
+    return {"w_qkv": dense_init(ks[0], (d, 3 * H * hd), dtype=dtype),
+            "w_gate": dense_init(ks[1], (d, H * hd), dtype=dtype),
+            "wo": dense_init(ks[2], (H * hd, d), dtype=dtype),
+            "out_norm": jnp.zeros((H * hd,), dtype)}
+
+
+def lightning_slopes(cfg, layer):
+    """Per-head decays s_{h,l} (H,) of published layer `layer` (an int or a
+    traced int) of `cfg.lightning_decay_layers`."""
+    H, N = cfg.num_heads, cfg.lightning_decay_layers
+    base = 2.0 ** (-8.0 * (np.arange(H) + 1) / H)
+    frac = 1.0 - jnp.asarray(layer, jnp.float32) / (N - 1) + 1e-5
+    return jnp.asarray(base, jnp.float32) * frac
+
+
+def _lightning_qkv(cfg, p, x):
+    B, L, _ = x.shape
+    H, hd = cfg.num_heads, cfg.head_dim
+    qkv = jax.nn.silu((x @ p["w_qkv"]).astype(jnp.float32)).astype(x.dtype)
+    qkv = shard(qkv.reshape(B, L, H, 3, hd),
+                ("batch", "seq", "heads", None, None))
+    return qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+
+
+def _lightning_out(cfg, p, x, o):
+    """W_out(sigmoid(x W_g) * RMSNorm(o)), o: (B, L, H, hd) f32."""
+    B, L = o.shape[:2]
+    o = shard(o.reshape(B, L, -1), ("batch", "seq", "heads"))
+    o = rms_norm(o, p["out_norm"], cfg.norm_eps, plus_one=True)
+    gate = jax.nn.sigmoid((x @ p["w_gate"]).astype(jnp.float32))
+    y = shard((gate * o).astype(x.dtype), ("batch", "seq", "heads"))
+    return y @ p["wo"]
+
+
+def lightning_core(q, k, v, slopes):
+    """sum_{j<=i} exp(-s_h (i-j)) (q_i . k_j) v_j for (B, L, H, hd) q/k/v:
+    the topo kernel's decay sweep, unnormalized (the Pallas kernel on TPU,
+    its XLA twin elsewhere; heads over the mesh's model axis)."""
+    from repro.kernels.topo_linear_attention.ops import (
+        topo_linear_attention, topo_linear_attention_sharded)
+    from repro.launch.sharding import current_mesh
+
+    coeffs = jnp.stack([jnp.zeros_like(slopes), -slopes], axis=1)  # (H, 2)
+    args = [t.transpose(0, 2, 1, 3) for t in (q, k, v)]
+    kw = dict(g="exp", dist_scale=1.0, causal=True, normalize=False)
+    mesh = current_mesh()
+    if mesh is not None and "model" in mesh.axis_names:
+        out = topo_linear_attention_sharded(*args, coeffs, mesh=mesh, **kw)
+    else:
+        out = topo_linear_attention(*args, coeffs, **kw)
+    return out.transpose(0, 2, 1, 3)
+
+
+def lightning_attention_train(cfg, p, x, layer):
+    """The lightning layer over a whole sequence. x: (B, L, d)."""
+    with jax.named_scope("lm.lightning"):
+        q, k, v = _lightning_qkv(cfg, p, x)
+        with jax.named_scope("lm.lightning.core"):
+            o = lightning_core(q, k, v, lightning_slopes(cfg, layer))
+        return _lightning_out(cfg, p, x, o)
+
+
+def lightning_decode_init(cfg, B):
+    H, hd = cfg.num_heads, cfg.head_dim
+    return {"S": jnp.zeros((B, H, hd, hd), jnp.float32)}
+
+
+def lightning_attention_prefill(cfg, p, x, lengths, cache, layer):
+    """The lightning layer over right-padded prompts, and the state the
+    recurrence S_n = gamma S_{n-1} + k_n v_n^T reaches after each row's
+    last token: S = sum_{j < len_b} gamma^(len_b - 1 - j) k_j v_j^T with
+    gamma = exp(-s) (every exponent <= 0). Rows with lengths[b] == 0 keep
+    their state. Returns (out (B, Lp, d), new_cache)."""
+    with jax.named_scope("lm.lightning"):
+        q, k, v = _lightning_qkv(cfg, p, x)
+        slopes = lightning_slopes(cfg, layer)
+        with jax.named_scope("lm.lightning.core"):
+            o = lightning_core(q, k, v, slopes)
+        out = _lightning_out(cfg, p, x, o)
+        w = _decay_weights(-slopes, lengths, x.shape[1])  # (B, Lp, H)
+        S = jnp.einsum("blhm,blhv,blh->bhmv", k.astype(jnp.float32),
+                       v.astype(jnp.float32), w)
+        S = shard(S, ("batch", "heads", None, None))
+        valid = (lengths > 0)[:, None, None, None]
+        return out, {"S": jnp.where(valid, S, cache["S"])}
+
+
+def lightning_attention_decode(cfg, p, x, cache, layer):
+    """One token through the recurrence. x: (B, 1, d)."""
+    with jax.named_scope("lm.lightning"):
+        q, k, v = _lightning_qkv(cfg, p, x)
+        g = jnp.exp(-lightning_slopes(cfg, layer))[None, :, None, None]
+        q, k, v = (t[:, 0].astype(jnp.float32) for t in (q, k, v))
+        S = cache["S"] * g + k[..., None] * v[..., None, :]
+        o = jnp.einsum("bhm,bhmv->bhv", q, S)[:, None]
+        return _lightning_out(cfg, p, x, o), {"S": S}
 
 
 # --- plain performer decode (unmasked linear attention state) ----------------

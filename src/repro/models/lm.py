@@ -5,7 +5,9 @@ optional per-layer remat. Families:
   dense   — [norm->attn, norm->mlp] x L
   moe     — first_dense_layers dense blocks, then MoE blocks (scan)
   ssm     — mamba blocks (no MLP, as mamba-1)
-  hybrid  — superblocks (rec, rec, attn) x num_superblocks + tail rec blocks
+  hybrid  — superblocks (rec, rec, attn) x num_superblocks + tail rec blocks,
+            or (lightning x 7, softmax) periods with MoE and post-norm
+            residuals (minimax)
   vlm     — dense backbone over [projected patch embeddings ; text tokens]
 Attention variant per config: full | performer | topo (the paper's technique).
 """
@@ -53,6 +55,15 @@ def _block_init(key, cfg, kind: str, dtype):
             p["topo"] = A.topo_init(ks[1], cfg, dtype)
         p["mlp_norm"] = {"scale": jnp.zeros((d,), dtype)}
         p["moe"] = MOE.moe_init(ks[2], cfg, dtype)
+    elif kind in ("lightning", "softmax"):
+        p["attn_norm"] = {"scale": jnp.zeros((d,), dtype)}
+        p["attn"] = (A.lightning_init(ks[0], cfg, dtype) if kind == "lightning"
+                     else A.attn_init(ks[0], cfg, dtype))
+        p["mlp_norm"] = {"scale": jnp.zeros((d,), dtype)}
+        if cfg.moe:
+            p["moe"] = MOE.moe_init(ks[2], cfg, dtype)
+        else:
+            p["mlp"] = gated_mlp_init(ks[2], d, cfg.d_ff, dtype)
     elif kind == "mamba":
         p["norm"] = {"scale": jnp.zeros((d,), dtype)}
         p["ssm"] = SSM.ssm_init(ks[0], cfg, dtype)
@@ -80,10 +91,38 @@ def _attn_train(cfg, p, x, positions, causal=True, window=0):
                                   window=window)
 
 
-def _block_train(cfg, kind, p, x, positions, window=0):
-    """Returns (x, aux)."""
+def _residual(cfg, x, h, y):
+    """x + y, or with `cfg.postnorm` alpha * h + beta * y (the residual
+    taken after the norm)."""
+    if cfg.postnorm:
+        return cfg.residual_alpha * h + cfg.residual_beta * y
+    return x + y
+
+
+def _ffn(cfg, p, x):
+    """The second half of a lightning / softmax block: norm, then the MoE
+    (or MLP), then the residual. Returns (x, aux)."""
+    h = rms_norm(x, p["mlp_norm"]["scale"], cfg.norm_eps, plus_one=True)
+    if "moe" in p:
+        y, aux = MOE.moe_block(cfg, p["moe"], h)
+    else:
+        y, aux = gated_mlp(p["mlp"], h, cfg.mlp_act), jnp.zeros((), jnp.float32)
+    return _residual(cfg, x, h, y), aux
+
+
+def _block_train(cfg, kind, p, x, positions, window=0, layer=0):
+    """Returns (x, aux). `layer` is the block's published layer index (the
+    lightning decays depend on it)."""
     aux = jnp.zeros((), jnp.float32)
-    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
+    if kind in ("lightning", "softmax"):
+        h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
+        if kind == "lightning":
+            y = A.lightning_attention_train(cfg, p["attn"], h, layer)
+        else:
+            with jax.named_scope("lm.softmax"):
+                y = A.full_attention_train(cfg, p["attn"], h, positions)
+        x, aux = _ffn(cfg, p, _residual(cfg, x, h, y))
+    elif kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         w = window if kind == "attn_local_mlp" else 0
         x = x + _attn_train(cfg, p, x, positions, window=w)
         if kind == "moe":
@@ -109,9 +148,19 @@ def _block_train(cfg, kind, p, x, positions, window=0):
     return x, aux
 
 
-def _block_decode(cfg, kind, p, x, pos, cache, S, window=0):
+def _block_decode(cfg, kind, p, x, pos, cache, S, window=0, layer=0):
     """x: (B, 1, d). Returns (x, new_cache)."""
-    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
+    if kind in ("lightning", "softmax"):
+        h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
+        if kind == "lightning":
+            y, cache = A.lightning_attention_decode(cfg, p["attn"], h, cache,
+                                                    layer)
+        else:
+            with jax.named_scope("lm.softmax"):
+                y, cache = A.full_attention_decode(cfg, p["attn"], h, pos,
+                                                   cache)
+        x, _ = _ffn(cfg, p, _residual(cfg, x, h, y))
+    elif kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
         if cfg.mla:
             y, cache = A.mla_attention_decode(cfg, p["attn"], h, pos, cache)
@@ -146,12 +195,24 @@ def _block_decode(cfg, kind, p, x, pos, cache, S, window=0):
 
 
 def _block_prefill(cfg, kind, p, x, positions, lengths, cache, S, window=0,
-                   tree_mask=None):
+                   tree_mask=None, layer=0):
     """Whole-prompt forward (same math as `_block_train`) that also writes
     the decode cache for positions [0, lengths[b]). x: (B, Lp, d) right-
     padded; rows with lengths[b] == 0 leave their cache untouched (they
     belong to other live serve slots). Returns (x, new_cache)."""
-    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
+    if kind in ("lightning", "softmax"):
+        h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
+        if kind == "lightning":
+            y, cache = A.lightning_attention_prefill(cfg, p["attn"], h,
+                                                     lengths, cache, layer)
+        else:
+            with jax.named_scope("lm.softmax"):
+                y, cache = A.full_attention_prefill(cfg, p["attn"], h,
+                                                    positions, lengths, cache)
+                cache = {k: shard(c, ("batch", None, "kv_heads", None))
+                         for k, c in cache.items()}
+        x, _ = _ffn(cfg, p, _residual(cfg, x, h, y))
+    elif kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
         if cfg.mla:
             y, cache = A.mla_attention_prefill(cfg, p["attn"], h, positions,
@@ -193,7 +254,9 @@ def _block_prefill(cfg, kind, p, x, positions, lengths, cache, S, window=0,
 
 
 def _block_cache_init(cfg, kind, B, S, dtype):
-    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
+    if kind == "lightning":
+        return A.lightning_decode_init(cfg, B)
+    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe", "softmax"):
         if cfg.mla:
             return {"ckv": jnp.zeros((B, S, cfg.kv_lora_rank), dtype),
                     "krope": jnp.zeros((B, S, cfg.qk_rope_dim), dtype)}
@@ -210,6 +273,20 @@ def _block_cache_init(cfg, kind, B, S, dtype):
     if kind == "rec_mlp":
         return RG.lru_decode_init(cfg, B, dtype)
     raise ValueError(kind)
+
+
+_SB_KINDS = {"rec": "rec_mlp", "attn": "attn_local_mlp",
+             "lightning": "lightning", "softmax": "softmax"}
+
+
+def _sb_kind(bkind: str) -> str:
+    """The block kind of one entry of a hybrid config's `superblock`."""
+    return _SB_KINDS[bkind]
+
+
+def _layer(cfg, j, bi: int):
+    """Published layer index of block `bi` of superblock `j`."""
+    return j * len(cfg.superblock) + bi
 
 
 # ----------------------------------------------------------------------------
@@ -271,14 +348,14 @@ def init_params(cfg, key):
                 kk = next(ki)
                 sb[f"b{bi}_{bkind}"] = (
                     jax.vmap(lambda x: _block_init(
-                        x, cfg, "rec_mlp" if bkind == "rec" else "attn_local_mlp",
-                        dtype))(jax.random.split(kk, count)))
+                        x, cfg, _sb_kind(bkind), dtype))(
+                            jax.random.split(kk, count)))
             params[f"blocks{si}"] = sb
         elif kind == "hybrid_tail":
             for bi, bkind in enumerate(cfg.tail_blocks):
                 params[f"tail{bi}"] = _block_init(
                     next(ki), cfg,
-                    "rec_mlp" if bkind == "rec" else "attn_local_mlp", dtype)
+                    _sb_kind(bkind), dtype)
         else:
             # params are ALWAYS stacked; cfg.scan_layers only selects the
             # execution strategy (lax.scan vs unrolled indexing)
@@ -314,26 +391,28 @@ def _run_stack(cfg, params, x, positions):
         if kind == "hybrid_superblocks":
             sb = params[f"blocks{si}"]
 
-            def superblock(x, layer_p):
+            def superblock(x, pj):
+                layer_p, j = pj
                 aux = jnp.zeros((), jnp.float32)
                 for bi, bkind in enumerate(cfg.superblock):
-                    bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                    bk = _sb_kind(bkind)
                     x, a = _block_train(cfg, bk, layer_p[f"b{bi}_{bkind}"], x,
-                                        positions, window=cfg.local_window)
+                                        positions, window=cfg.local_window,
+                                        layer=_layer(cfg, j, bi))
                     aux = aux + a
                 return x, aux
 
             body = maybe_remat(superblock, cfg)
             if scanned:
-                x, auxs = jax.lax.scan(lambda c, p: body(c, p), x, sb)
+                x, auxs = jax.lax.scan(body, x, (sb, jnp.arange(count)))
                 total_aux = total_aux + jnp.sum(auxs)
             else:
                 for j in range(count):
-                    x, a = body(x, jax.tree.map(lambda t: t[j], sb))
+                    x, a = body(x, (jax.tree.map(lambda t: t[j], sb), j))
                     total_aux = total_aux + a
         elif kind == "hybrid_tail":
             for bi, bkind in enumerate(cfg.tail_blocks):
-                bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                bk = _sb_kind(bkind)
                 x, a = _block_train(cfg, bk, params[f"tail{bi}"], x, positions,
                                     window=cfg.local_window)
                 total_aux = total_aux + a
@@ -353,6 +432,7 @@ def _run_stack(cfg, params, x, positions):
     return x, total_aux
 
 
+@jax.named_scope("lm.embed")
 def embed_tokens(cfg, params, tokens):
     x = params["embed"]["table"][tokens]
     if cfg.emb_scale:
@@ -360,6 +440,7 @@ def embed_tokens(cfg, params, tokens):
     return x
 
 
+@jax.named_scope("lm.head")
 def unembed(cfg, params, x):
     table = (params["embed"]["table"] if cfg.tie_embeddings
              else params["lm_head"]["kernel"])
@@ -448,14 +529,14 @@ def init_decode_cache(cfg, B, S):
         if kind == "hybrid_superblocks":
             sb = {}
             for bi, bkind in enumerate(cfg.superblock):
-                bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                bk = _sb_kind(bkind)
                 one = _block_cache_init(cfg, bk, B, S, dtype)
                 sb[f"b{bi}_{bkind}"] = jax.tree.map(
                     lambda a: jnp.broadcast_to(a[None], (count,) + a.shape), one)
             cache[f"blocks{si}"] = sb
         elif kind == "hybrid_tail":
             for bi, bkind in enumerate(cfg.tail_blocks):
-                bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                bk = _sb_kind(bkind)
                 cache[f"tail{bi}"] = _block_cache_init(cfg, bk, B, S, dtype)
         else:
             one = _block_cache_init(cfg, kind, B, S, dtype)
@@ -475,29 +556,32 @@ def forward_decode(cfg, params, cache, token, pos, S):
             sb_p = params[f"blocks{si}"]
             sb_c = cache[f"blocks{si}"]
 
-            def sb_body(x, pc):
-                layer_p, layer_c = pc
+            def sb_body(x, pcj):
+                layer_p, layer_c, j = pcj
                 new_c = {}
                 for bi, bkind in enumerate(cfg.superblock):
-                    bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                    bk = _sb_kind(bkind)
                     key = f"b{bi}_{bkind}"
                     x, c = _block_decode(cfg, bk, layer_p[key], x, pos,
-                                         layer_c[key], S, window=cfg.local_window)
+                                         layer_c[key], S, window=cfg.local_window,
+                                         layer=_layer(cfg, j, bi))
                     new_c[key] = c
                 return x, new_c
 
             if scanned:
-                x, nc = jax.lax.scan(sb_body, x, (sb_p, sb_c))
+                x, nc = jax.lax.scan(sb_body, x,
+                                     (sb_p, sb_c, jnp.arange(count)))
             else:
                 ncs = []
                 for j in range(count):
-                    x, c = sb_body(x, jax.tree.map(lambda t: t[j], (sb_p, sb_c)))
+                    x, c = sb_body(x, jax.tree.map(lambda t: t[j], (sb_p, sb_c))
+                                   + (j,))
                     ncs.append(c)
                 nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
             new_cache[f"blocks{si}"] = nc
         elif kind == "hybrid_tail":
             for bi, bkind in enumerate(cfg.tail_blocks):
-                bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                bk = _sb_kind(bkind)
                 x, c = _block_decode(cfg, bk, params[f"tail{bi}"], x, pos,
                                      cache[f"tail{bi}"], S,
                                      window=cfg.local_window)
@@ -547,30 +631,33 @@ def forward_prefill_into_cache(cfg, params, cache, tokens, lengths, S,
             sb_p = params[f"blocks{si}"]
             sb_c = cache[f"blocks{si}"]
 
-            def sb_body(x, pc):
-                layer_p, layer_c = pc
+            def sb_body(x, pcj):
+                layer_p, layer_c, j = pcj
                 new_c = {}
                 for bi, bkind in enumerate(cfg.superblock):
-                    bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                    bk = _sb_kind(bkind)
                     key = f"b{bi}_{bkind}"
                     x, c = _block_prefill(cfg, bk, layer_p[key], x, positions,
                                           lengths, layer_c[key], S,
-                                          window=cfg.local_window)
+                                          window=cfg.local_window,
+                                          layer=_layer(cfg, j, bi))
                     new_c[key] = c
                 return x, new_c
 
             if scanned:
-                x, nc = jax.lax.scan(sb_body, x, (sb_p, sb_c))
+                x, nc = jax.lax.scan(sb_body, x,
+                                     (sb_p, sb_c, jnp.arange(count)))
             else:
                 ncs = []
                 for j in range(count):
-                    x, c = sb_body(x, jax.tree.map(lambda t: t[j], (sb_p, sb_c)))
+                    x, c = sb_body(x, jax.tree.map(lambda t: t[j], (sb_p, sb_c))
+                                   + (j,))
                     ncs.append(c)
                 nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
             new_cache[f"blocks{si}"] = nc
         elif kind == "hybrid_tail":
             for bi, bkind in enumerate(cfg.tail_blocks):
-                bk = "rec_mlp" if bkind == "rec" else "attn_local_mlp"
+                bk = _sb_kind(bkind)
                 x, c = _block_prefill(cfg, bk, params[f"tail{bi}"], x,
                                       positions, lengths, cache[f"tail{bi}"],
                                       S, window=cfg.local_window)

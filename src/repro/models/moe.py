@@ -6,6 +6,10 @@ offsets, scatter into an (E, C, d) buffer whose expert axis is sharded over
 the `model` mesh axis (EP); XLA inserts the all-to-alls from the sharding
 constraints. Capacity overflow drops lowest-priority assignments (standard
 capacity-factor semantics); aux load-balancing loss included.
+
+A configuration with `moe_held` takes the chip-share layer instead
+(`held_moe_block`): told which experts it holds, it routes over all of
+them, drops no token, and computes only its own experts' part.
 """
 from __future__ import annotations
 
@@ -17,13 +21,16 @@ from repro.models.layers import dense_init
 
 
 def moe_init(key, cfg, dtype=jnp.float32):
+    """Router over all `num_experts`; expert weights for the held ones
+    (`moe_held`, where given) or all of them."""
     d, E, ffe = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    n_held = len(cfg.moe_held) or E
     ks = jax.random.split(key, 8)
     p = {
         "router": dense_init(ks[0], (d, E), scale=0.02, dtype=jnp.float32),
-        "experts_w_gate": dense_init(ks[1], (E, d, ffe), dtype=dtype),
-        "experts_w_in": dense_init(ks[2], (E, d, ffe), dtype=dtype),
-        "experts_w_out": dense_init(ks[3], (E, ffe, d), dtype=dtype),
+        "experts_w_gate": dense_init(ks[1], (n_held, d, ffe), dtype=dtype),
+        "experts_w_in": dense_init(ks[2], (n_held, d, ffe), dtype=dtype),
+        "experts_w_out": dense_init(ks[3], (n_held, ffe, d), dtype=dtype),
     }
     if cfg.num_shared_experts > 0:
         ffs = cfg.moe_d_ff * cfg.num_shared_experts
@@ -98,6 +105,8 @@ def moe_block(cfg, p, x):
     local) and the dispatch buffers meet the expert sharding through one
     all-to-all instead of replicating the token tensor (§Perf iteration B).
     Per-group capacity C/G preserves total capacity."""
+    if cfg.moe_held:
+        return held_moe_block(cfg, p, x)
     B, L, d = x.shape
     T = B * L
     G = max(1, getattr(cfg, "moe_groups", 1))
@@ -129,3 +138,103 @@ def moe_block(cfg, p, x):
         yt = yt + hs @ p["shared_w_out"]
 
     return yt.reshape(B, L, d), aux
+
+
+# ----------------------------------------------------------------------------
+# chip-share expert layer: the held experts' part, dropless
+# ----------------------------------------------------------------------------
+
+TILE = 512  # rows of one expert matmul of the dropless layer
+
+
+def held_moe_block(cfg, p, x):
+    """The part of a Mixtral-style MoE layer that the experts in
+    `cfg.moe_held` give: p = softmax(h W_r) over all `num_experts`, the top
+    k renormalized to sum to 1, and each token's sum over its chosen experts
+    that are held here of weight * W2(SiLU(W1 h) * W3 h). Chosen experts
+    that are not held add nothing (they live on other chips of the stated
+    deployment). Dropless: every assignment is computed.
+
+    Under a mesh with a `model` axis the held experts are split over it; each
+    chip, holding every token, computes its own experts for the tokens
+    routed to them and one all-reduce (`lm.moe.exchange`) sums the parts.
+    x: (B, L, d) -> ((B, L, d), aux)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.analysis import trace_guard
+    from repro.launch.sharding import current_mesh
+
+    B, L, d = x.shape
+    E, K = cfg.num_experts, cfg.top_k
+    held = jnp.asarray(cfg.moe_held, jnp.int32)
+    n_held = len(cfg.moe_held)
+    trace_guard.record("moe.held", event=str(n_held))
+    xt = x.reshape(B * L, d)
+    with jax.named_scope("lm.moe"):
+        with jax.named_scope("lm.moe.router"):
+            logits = jnp.dot(xt.astype(jnp.float32), p["router"],
+                             precision=jax.lax.Precision.HIGHEST)
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate_vals, ids = jax.lax.top_k(probs, K)
+            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+            # (T, n_held): each held expert's weight for each token, 0 where
+            # the token did not choose it
+            wts = jnp.sum(jnp.where(ids[:, :, None] == held[None, None, :],
+                                    gate_vals[:, :, None], 0.0), axis=1)
+            me = jnp.mean(probs, axis=0)
+            ce = jnp.mean(jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32),
+                          axis=0)
+            aux = jnp.sum(me * ce) * E * cfg.router_aux_loss
+        ws = (p["experts_w_gate"], p["experts_w_in"], p["experts_w_out"])
+        mesh = current_mesh()
+        size = mesh.shape.get("model", 1) if mesh is not None else 1
+        if size > 1 and n_held % size == 0:
+            yt = jax.shard_map(
+                lambda xt, wts, *ws: _held_experts(xt, wts, ws, "model"),
+                mesh=mesh, in_specs=(P(), P(None, "model")) + (P("model"),) * 3,
+                out_specs=P(), check_vma=False)(xt, wts, *ws)
+        else:
+            yt = _held_experts(xt, wts, ws)
+    return yt.reshape(B, L, d), aux
+
+
+def _held_experts(xt, wts, ws, axis=None):
+    """sum_e wts[:, e] * FFN_e(xt) over the experts of `ws` (stacked on
+    their first axis), in xt's dtype (a token meets at most top_k experts),
+    then, under shard_map, the sum over `axis`."""
+    with jax.named_scope("lm.moe.experts"):
+        # a scan over the experts: each step reads its own expert's slice of
+        # the stacked weights, so no expert's weights are copied out ahead
+        y, _ = jax.lax.scan(
+            lambda y, ex: (_expert_tiles(xt, ex[0], ex[1:], y), None),
+            jnp.zeros_like(xt), (wts.T,) + tuple(ws))
+    if axis is None:
+        return y
+    with jax.named_scope("lm.moe.exchange"):
+        return jax.lax.psum(y, axis)
+
+
+def _expert_tiles(xt, w, expert, y):
+    """y + w[t] * W2(SiLU(W1 x_t) * W3 x_t) for every token t with w[t] > 0:
+    the routed tokens, gathered in order, go through the expert in tiles of
+    TILE rows; a tile that holds none of them is skipped (the loop has a
+    static trip count and a `cond`, so it differentiates)."""
+    T = xt.shape[0]
+    tm = min(TILE, T)
+    n_tiles = -(-T // tm)
+    w_gate, w_in, w_out = expert
+    n = jnp.sum(w > 0)
+    idx = jnp.nonzero(w > 0, size=n_tiles * tm, fill_value=0)[0]
+
+    def tile(t, y):
+        def run(y):
+            rows = jax.lax.dynamic_slice_in_dim(idx, t * tm, tm)
+            xr = xt[rows]
+            h = jax.nn.silu(xr @ w_gate) * (xr @ w_in)
+            o = (h @ w_out).astype(jnp.float32)
+            wr = jnp.where(t * tm + jnp.arange(tm) < n, w[rows], 0.0)
+            return y.at[rows].add((o * wr[:, None]).astype(y.dtype))
+
+        return jax.lax.cond(t * tm < n, run, lambda y: y, y)
+
+    return jax.lax.fori_loop(0, n_tiles, tile, y)

@@ -6,12 +6,12 @@ run against a deterministic set of representative examples: the boundary
 values of every strategy plus a few seeded random draws. That keeps tier-1
 green without the dependency while preserving the property-test shape.
 
-Usage in tests:  from _hypothesis_compat import given, settings, st
+Usage in tests:  from _hypothesis_compat import example, given, settings, st
 """
 from __future__ import annotations
 
 try:  # pragma: no cover - exercised implicitly by either branch
-    from hypothesis import given, settings  # noqa: F401
+    from hypothesis import example, given, settings  # noqa: F401
     from hypothesis import strategies as st  # noqa: F401
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -53,11 +53,21 @@ except ImportError:
     def settings(*_a, **_kw):  # accepts max_examples=, deadline=, ...
         return lambda f: f
 
+    def example(**drawn):
+        """A pinned example, run before the drawn ones."""
+        def deco(f):
+            f._examples = [drawn] + list(getattr(f, "_examples", []))
+            return f
+
+        return deco
+
     def given(**strategies):
         names = sorted(strategies)
 
         def deco(f):
             def wrapper(*args, **kwargs):
+                for drawn in getattr(f, "_examples", []):
+                    f(*args, **drawn, **kwargs)
                 rng = random.Random(0)
                 cols = {k: strategies[k].examples(rng) for k in names}
                 rounds = max(len(v) for v in cols.values())

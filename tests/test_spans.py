@@ -186,3 +186,96 @@ def test_build_records_its_spans_and_the_plan_cache():
     spans2, miss2, hit2 = counts()
     assert [b - a for a, b in zip(spans1, spans2)] == [1, 0, 0, 0]
     assert (miss2 - miss1, hit2 - hit1) == (0, 1)
+
+
+# ----------------------------------------------------------------------------
+# the MiniMax prefill: lightning, softmax, MoE, embed and head scopes and the
+# counters of the topo family and the held experts
+# ----------------------------------------------------------------------------
+
+LM_SCOPES = ("lm.lightning", "lm.lightning.core", "lm.softmax", "lm.moe",
+             "lm.moe.router", "lm.moe.experts", "lm.embed", "lm.head")
+
+
+def _minimax_prefill():
+    from repro.configs.base import get_smoke_config
+    from repro.models import api
+
+    cfg = get_smoke_config("minimax_text_01").replace(dtype="float32")
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    B, L, S = 2, 16, 18
+    cache = api.init_cache(cfg, B, S)
+    toks = jnp.zeros((B, L), jnp.int32)
+    lens = jnp.full((B,), L, jnp.int32)
+    fn = jax.jit(lambda p, c, t, n: api.prefill_into_cache(cfg, p, c, t, n,
+                                                           S))
+    return cfg, fn.lower(params, cache, toks, lens)
+
+
+def test_minimax_prefill_scopes_named_in_compiled_hlo():
+    _, lowered = _minimax_prefill()
+    names = _op_names(lowered.compile())
+    for scope in LM_SCOPES:
+        assert _scoped(names, scope), scope
+    # the decay sweep sits inside its layer's scope
+    assert all("lm.lightning/" in n or "lm.lightning)" in n
+               for n in _scoped(names, "lm.lightning.core"))
+
+
+def test_minimax_prefill_counts_decay_family_and_held_experts():
+    """Each trace of the prefill records the decay family once per
+    lightning layer (7), never the rank family, and the held-expert layer
+    once per layer (8) with the 8 experts it holds."""
+    before = trace_guard.snapshot()
+    cfg, _ = _minimax_prefill()
+    after = trace_guard.snapshot()
+
+    def grew(key):
+        return after.get(key, 0) - before.get(key, 0)
+
+    assert grew("attention.topo:decay") == 7
+    assert grew("attention.topo:rank") == 0
+    assert grew(f"moe.held:{len(cfg.moe_held)}") == 8
+
+
+def test_topo_rank_family_is_counted():
+    from repro.kernels.topo_linear_attention.ops import topo_linear_attention
+
+    x = jnp.ones((1, 2, 8, 4), jnp.float32)
+    before = trace_guard.compiles("attention.topo:rank")
+    topo_linear_attention(x, x, x, jnp.asarray([0.0, -0.1, -0.1]))
+    assert trace_guard.compiles("attention.topo:rank") == before + 1
+
+
+EXCHANGE = r"""
+import os, re
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp
+from repro.configs.base import get_smoke_config
+from repro.launch import sharding as SH
+from repro.launch.mesh import make_mesh
+from repro.models import moe as MOE
+
+cfg = get_smoke_config("minimax_text_01").replace(dtype="float32")
+p = MOE.moe_init(jax.random.PRNGKey(0), cfg)
+x = jnp.ones((2, 16, cfg.d_model), jnp.float32)
+with SH.use_sharding(make_mesh((1, 4), ("data", "model"))):
+    text = jax.jit(lambda p, x: MOE.moe_block(cfg, p, x)[0]).lower(
+        p, x).compile().as_text()
+ops = [l for l in text.splitlines() if re.search(r"all-reduce(-start)?\(", l)]
+assert ops and all("lm.moe.exchange" in l for l in ops), ops
+print("EXCHANGE_OK")
+"""
+
+
+def test_moe_exchange_scope_names_the_all_reduce():
+    """On a 4-device mesh the held experts' parts meet in one all-reduce,
+    named `lm.moe.exchange`."""
+    import os
+
+    env = dict(os.environ, PYTHONPATH=os.path.abspath("src"),
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", EXCHANGE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert "EXCHANGE_OK" in out.stdout, (out.stdout[-800:],
+                                         out.stderr[-3000:])
