@@ -5,17 +5,20 @@ on a mesh of data 1 x model <chips>, the weights placed by
 `tree_param_specs`.
 
 Set-up makes the weights and the prompt pools from the seed on the device,
-compiles one prefill program per call shape and the decode step, and runs
-each shape once. A call prefills one shape's prompts into a fresh cache
-and brings the last-position logits to the host; the window cycles the
-shapes in the seeded order. Two calls of the window, drawn by a seeded
-reservoir sample, keep their caches: after the window each runs the decode
-steps on seeded next ids, and the check compares those calls' prefill and
-decode logits with `topolm_prefill_ref` on the extended prompts.
+compiles one prefill program per call shape (lowered in turn, compiled
+side by side) and runs each shape once. A call prefills one shape's prompts
+into a fresh cache and brings the last-position logits to the host; the
+window cycles the shapes in the seeded order. Two calls of the window,
+drawn by a seeded reservoir sample, keep their caches: after the window
+each runs the decode steps on seeded next ids, and the check compares
+those calls' prefill and decode logits with `topolm_prefill_ref` on the
+extended prompts, the reference taking the experts the program chose (its
+route log, in the cache) and measuring how far they are from its own.
 """
 from __future__ import annotations
 
 import random
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -100,13 +103,12 @@ class PrefillCell:
         key = ("compiled", tuple(shapes))
         with self.sharding(), host.span("compile", timed=True):
             if key not in memo:
-                progs = {}
-                for B, L in shapes:
-                    S = L + n
-                    tok = jax.ShapeDtypeStruct((B, L), jnp.int32)
-                    progs[(B, L)] = jax.jit(prefill(cfg, S)).lower(
-                        self.params, tok).compile()
-                memo[key] = progs
+                lowered = [jax.jit(prefill(cfg, L + n)).lower(
+                    self.params, jax.ShapeDtypeStruct((B, L), jnp.int32))
+                    for B, L in shapes]
+                with ThreadPoolExecutor(len(shapes)) as pool:
+                    memo[key] = dict(zip(shapes, pool.map(
+                        lambda lo: lo.compile(), lowered)))
             self.progs = memo[key]
         self.decode = {}
         with self.sharding(), host.span("warmup"):
@@ -172,7 +174,8 @@ class PrefillCell:
                         self.params, cache, jnp.asarray(ids[:, t:t + 1]),
                         jnp.asarray(L + t, jnp.int32))
                     steps.append(np.asarray(lg))
-                self.checked.append((call, s, i, ids, np.stack(steps, 1)))
+                self.checked.append((call, s, i, ids, np.stack(steps, 1),
+                                     route_log(self.config, cache)))
                 del cache
         self.kept = []
         return 0
@@ -183,21 +186,37 @@ class PrefillCell:
     def check(self, limits: dict) -> dict:
         n = self.traffic["decode_steps"]
         V = self.config["vocab_size"]
-        reference = ref.Reference(self.config,
-                                  rows=self.traffic["ref_rows"])
-        lg, dg = 0.0, 0.0
+        reference = ref.Reference(self.config, rows=self.traffic["ref_rows"],
+                                  max_len=max_len(self.traffic))
+        lg, dg, rg = 0.0, 0.0, 0.0
         with self.sharding():
-            for call, s, i, ids, got in self.checked:
+            for call, s, i, ids, got, routes in self.checked:
                 toks = np.concatenate(
                     [ref.prompts(self.seed, self.config, s,
                                  self.traffic["pool"])[i], ids], axis=1)
-                want = reference.last_logits(self.params, toks, n + 1)
+                want, _, route_gap = reference.forward(self.params, toks,
+                                                       n + 1, routes)
                 lg = max(lg, ref.gap(got[:, 0, :V], want[:, 0]))
                 dg = max(dg, ref.gap(got[:, 1:, :V], want[:, 1:]))
+                rg = max(rg, route_gap)
         del self.params
-        nums = {"logit_gap": lg, "decode_gap": dg,
+        nums = {"logit_gap": lg, "decode_gap": dg, "route_gap": rg,
                 "nonfinite_calls": float(self.nonfinite)}
         return {k: {"value": nums[k], "limit": v} for k, v in limits.items()}
+
+
+def max_len(traffic: dict) -> int:
+    """Tokens of the longest sequence the check compares."""
+    return max(L for _, L in traffic["shapes"]) + traffic["decode_steps"]
+
+
+def route_log(c: dict, cache) -> list:
+    """The program's route log of a filled cache, [row][layer] (S, k): the
+    experts each position was routed to (`lm._log_routes`)."""
+    kinds = ref.sizes(c)["kinds"]
+    logs = [np.asarray(cache["blocks0"][ref.block_key(l, kind)]["routes"][0])
+            for l, kind in enumerate(kinds)]
+    return [[log[b] for log in logs] for b in range(logs[0].shape[0])]
 
 
 def setup(config, traffic, seed, host, memo=None):
@@ -205,11 +224,12 @@ def setup(config, traffic, seed, host, memo=None):
 
 
 def control(config, traffic, seed, memo=None, variant: str = "fp8") -> dict:
-    """The numbers `correct` compares, read off a control (the reference
-    with every matmul's inputs in float8 e4m3 (`fp8`), with no lightning
-    decay (`no_decay`), or with the lightning sum normalized
-    (`normalized`)) against the reference, on the first call of the seed's
-    order and its decode ids."""
+    """The numbers `correct` compares, read off a control in the program's
+    place: the reference with every matmul's inputs in float8 e4m3 (`fp8`),
+    with no lightning decay (`no_decay`), or with the lightning sum
+    normalized (`normalized`), its logits and its own expert choices set
+    against the reference's, on the first call of the seed's order and its
+    decode ids."""
     import jax
     import jax.numpy as jnp
     from repro.launch import sharding as SH
@@ -221,27 +241,23 @@ def control(config, traffic, seed, memo=None, variant: str = "fp8") -> dict:
     if "mesh" not in memo:
         memo["mesh"] = make_mesh((1, model), ("data", "model"))
     with SH.use_sharding(memo["mesh"]):
-        if memo.get("control_seed") != seed:
-            shapes_tree = jax.eval_shape(
-                lambda: ref.init_params(seed, config, jnp.bfloat16))
-            shardings = jax.tree.map(SH.named_sharding,
-                                     SH.tree_param_specs(shapes_tree))
-            memo["control_params"] = ref.init_params(
-                seed, config, jnp.dtype(config["dtype"]), shardings)
-            memo.pop("control_ref", None)
-            memo["control_seed"] = seed
-        params = memo["control_params"]
+        shapes_tree = jax.eval_shape(
+            lambda: ref.init_params(seed, config, jnp.bfloat16))
+        shardings = jax.tree.map(SH.named_sharding,
+                                 SH.tree_param_specs(shapes_tree))
+        params = ref.init_params(seed, config, jnp.dtype(config["dtype"]),
+                                 shardings)
         s = ref.call_order(seed, [tuple(x) for x in traffic["shapes"]])[0]
         toks = np.concatenate([ref.prompts(seed, config, s,
                                            traffic["pool"])[0],
                                ref.next_ids(seed, config, 0, s[0], n)], 1)
-        rows = traffic["ref_rows"]
-        if "control_ref" not in memo:
-            memo["control_ref"] = ref.Reference(config, rows=rows) \
-                .last_logits(params, toks, n + 1)
-        want = memo["control_ref"]
-        got = ref.Reference(config, variant, rows=rows).last_logits(
+        kw = dict(rows=traffic["ref_rows"], max_len=max_len(traffic))
+        got, routes, _ = ref.Reference(config, variant, **kw).forward(
             params, toks, n + 1)
+        want, _, route_gap = ref.Reference(config, **kw).forward(
+            params, toks, n + 1, routes)
+        del params
     return {"logit_gap": ref.gap(got[:, 0], want[:, 0]),
             "decode_gap": ref.gap(got[:, 1:], want[:, 1:]),
+            "route_gap": route_gap,
             "nonfinite_calls": float(not np.isfinite(got).all())}

@@ -28,19 +28,26 @@ exact decayed sum sum_{j < b0} exp(-s(i-j)) k_j v_j^T of the rows before
 block b0 is carried from block to block (every exponent <= 0); the softmax
 layer runs each query block over the key blocks at or before it with a
 running maximum and sum. A chosen expert's rows are gathered on the host's
-count of them (padded to a multiple of 1024 rows of weight 0). Every matmul goes through `mm`: float32 at `highest`, or
-with its inputs rounded to float8 (e4m3) for the fp8 control. The other
-controls change the lightning layer: `no_decay` sets s = 0, `normalized`
-divides o_i by q_i . sum_{j<=i} exp(-s(i-j)) k_j.
+count of them, in tiles of TILE rows (padding rows of weight 0). Every
+matmul goes through `mm`: float32 at `highest`, or with its inputs rounded
+to float8 (e4m3) for the fp8 control. The other controls change the
+lightning layer: `no_decay` sets s = 0, `normalized` divides o_i by
+q_i . sum_{j<=i} exp(-s(i-j)) k_j.
+
+Routing takes the experts it is given, where it is given them: the
+program's own top-k choices, read from its route log. Rounding moves a
+router logit by a little, and where a token's k-th and (k+1)-th experts lie
+that close, the program and an independent reference choose differently,
+and that token's output differs by an expert's share. So the comparison
+follows the program's choices, and the route gap checks them: by how much
+the logit of a chosen expert falls below the reference's k-th largest.
 """
 from __future__ import annotations
 
 import numpy as np
 
 VOCAB_PAD = 256  # the program pads its embedding and head rows to this
-# the router's logits are ~8-normal for a unit-RMS input: a confident router,
-# whose second expert weighs ~0.06 where rounding swaps it with the third
-ROUTER_LOGIT_STD = 8.0
+ROUTER_STD = 0.02  # the program's router init (`moe.moe_init`)
 
 
 def prng_key(seed: int, stream: int):
@@ -83,7 +90,7 @@ def init_params(seed: int, c: dict, dtype, shardings=None):
     """The weights in the program's layout (one period, each block's leaves
     stacked on a leading axis of 1), made on the device in one jitted call:
     normal / sqrt(fan_in) matrices in `dtype`, the router in float32 at
-    ROUTER_LOGIT_STD / sqrt(d)-normal, the embedding 0.02-normal, norm gains
+    ROUTER_STD-normal, the embedding 0.02-normal, norm gains
     1 + 0.1-normal.
     `shardings`, a matching tree of shardings, places them."""
     import jax
@@ -119,7 +126,7 @@ def init_params(seed: int, c: dict, dtype, shardings=None):
             blocks[block_key(l, kind)] = {
                 "attn": attn, "attn_norm": {"scale": gain((1, d))},
                 "mlp_norm": {"scale": gain((1, d))},
-                "moe": {"router": ROUTER_LOGIT_STD / np.sqrt(d)
+                "moe": {"router": ROUTER_STD
                         * jax.random.normal(next(ks), (1, d, z["E"]),
                                             jnp.float32),
                         "experts_w_gate": dense((1, n, d, ff)),
@@ -183,14 +190,15 @@ def slopes(z: dict, layer: int) -> np.ndarray:
 
 
 def _lightning(z, mm, rows: int, normalized: bool):
-    """(p, x (Lp, d), s (H,)) -> x after the attention half of the block."""
+    """(p, x (Lp, d), s (H,), nb) -> x after the attention half of the block
+    in its first nb blocks of `rows` rows (the rest stay 0)."""
     import jax
     import jax.numpy as jnp
 
     H, hd, d, R = z["H"], z["hd"], z["d"], rows
     a, b, eps = z["alpha"], z["beta"], z["eps"]
 
-    def f(p, x, s):
+    def f(p, x, s, nb):
         i = jnp.arange(R, dtype=jnp.float32)
         dist = i[:, None] - i[None, :]
         intra = jnp.where(dist >= 0, jnp.exp(-s[:, None, None]
@@ -199,8 +207,9 @@ def _lightning(z, mm, rows: int, normalized: bool):
         dec_k = jnp.exp(-s[None, :] * (R - 1 - i[:, None]))  # (R, H)
         g_R = jnp.exp(-s * R)
 
-        def block(carry, xr):
-            S, zk = carry  # (H, hd, hd), (H, hd)
+        def block(n, carry):
+            S, zk, out = carry  # (H, hd, hd), (H, hd), (Lp, d)
+            xr = jax.lax.dynamic_slice_in_dim(x, n * R, R)
             h = _rms(xr, p["attn_norm"]["scale"], eps)
             qkv = jax.nn.silu(mm("rd,de->re", h, p["attn"]["w_qkv"]))
             qkv = qkv.reshape(R, H, 3, hd)
@@ -218,12 +227,13 @@ def _lightning(z, mm, rows: int, normalized: bool):
             o = _rms(o.reshape(R, H * hd), p["attn"]["out_norm"], eps)
             gate = jax.nn.sigmoid(mm("rd,de->re", h, p["attn"]["w_gate"]))
             y = mm("re,ed->rd", gate * o, p["attn"]["wo"])
-            return (S, zk), a * h + b * y
+            out = jax.lax.dynamic_update_slice_in_dim(out, a * h + b * y,
+                                                      n * R, 0)
+            return S, zk, out
 
         carry = (jnp.zeros((H, hd, hd), jnp.float32),
-                 jnp.zeros((H, hd), jnp.float32))
-        _, out = jax.lax.scan(block, carry, x.reshape(-1, R, d))
-        return out.reshape(-1, d)
+                 jnp.zeros((H, hd), jnp.float32), jnp.zeros_like(x))
+        return jax.lax.fori_loop(0, nb, block, carry)[2]
 
     return f
 
@@ -242,7 +252,8 @@ def _rope(x, pos, z):
 
 
 def _softmax(z, mm, rows: int):
-    """(p, x (Lp, d)) -> x after the attention half of the block."""
+    """(p, x (Lp, d), nb) -> x after the attention half of the block in its
+    first nb blocks of `rows` rows (the rest stay 0)."""
     import jax
     import jax.numpy as jnp
 
@@ -250,7 +261,7 @@ def _softmax(z, mm, rows: int):
     G = H // KV
     a, b, eps = z["alpha"], z["beta"], z["eps"]
 
-    def f(p, x):
+    def f(p, x, nb):
         Lp = x.shape[0]
         h = _rms(x, p["attn_norm"]["scale"], eps)
         pos = jnp.arange(Lp)
@@ -259,7 +270,7 @@ def _softmax(z, mm, rows: int):
         v = mm("ld,de->le", h, p["attn"]["wv"]).reshape(Lp, KV, hd)
         tri = jnp.arange(R)[:, None] >= jnp.arange(R)[None, :]
 
-        def qblock(i):
+        def qblock(i, out):
             hi = jax.lax.dynamic_slice_in_dim(h, i * R, R)
             q = _rope(mm("rd,de->re", hi, p["attn"]["wq"]).reshape(R, H, hd),
                       i * R + jnp.arange(R), z) / np.sqrt(hd)
@@ -283,38 +294,75 @@ def _softmax(z, mm, rows: int):
                 0, i + 1, body, (m0, zero, jnp.zeros((KV, G, R, hd),
                                                      jnp.float32)))
             o = (acc / l[..., None]).transpose(2, 0, 1, 3).reshape(R, H * hd)
-            return a * hi + b * mm("re,ed->rd", o, p["attn"]["wo"])
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, a * hi + b * mm("re,ed->rd", o, p["attn"]["wo"]),
+                i * R, 0)
 
-        return jax.lax.map(qblock, jnp.arange(Lp // R)).reshape(Lp, d)
+        return jax.lax.fori_loop(0, nb, qblock, jnp.zeros_like(x))
 
     return f
 
 
-def _route(z, mm, p, x):
-    """(h, weight of each held expert per row (Lp, held)) of the MoE half:
-    h = RMSNorm(x), top k of softmax(h W_r) renormalized."""
+def _router(z, mm):
+    """(p, x) -> (h = RMSNorm(x), router logits h W_r (Lp, E))."""
+
+    def f(p, x):
+        h = _rms(x, p["mlp_norm"]["scale"], z["eps"])
+        return h, mm("ld,de->le", h, p["moe"]["router"])
+
+    return f
+
+
+def _top(logits, k: int):
+    """(values, ids) of the k largest logits of each row, by k passes of
+    argmax (a sort compiles slowly on the chip)."""
+    import jax.numpy as jnp
+
+    vals, ids = [], []
+    for _ in range(k):
+        i = jnp.argmax(logits, axis=-1)
+        vals.append(jnp.take_along_axis(logits, i[:, None], axis=-1)[:, 0])
+        ids.append(i)
+        logits = jnp.where(jnp.arange(logits.shape[-1]) == i[:, None],
+                           -jnp.inf, logits)
+    return jnp.stack(vals, -1), jnp.stack(ids, -1).astype(jnp.int32)
+
+
+def _gates(z):
+    """(logits (Lp, E), ids (Lp, k), Lx) -> (weight of each held expert per
+    row (Lp, held), the route gap): the top-k weights of softmax(logits) at
+    `ids`, renormalized to 1, 0 past row Lx; the gap is the most by which
+    the logit of an expert in `ids` falls below the k-th largest logit,
+    over the rows before Lx."""
     import jax
     import jax.numpy as jnp
 
-    h = _rms(x, p["mlp_norm"]["scale"], z["eps"])
-    probs = jax.nn.softmax(mm("ld,de->le", h, p["moe"]["router"]), axis=-1)
-    top = jnp.argsort(-probs, axis=-1)[:, :z["K"]]
-    g = jnp.take_along_axis(probs, top, axis=-1)
-    g = g / jnp.sum(g, axis=-1, keepdims=True)
-    held = jnp.arange(z["held"])
-    return h, jnp.sum(jnp.where(top[:, :, None] == held, g[:, :, None], 0.0),
-                      axis=1)
+    def f(logits, ids, Lx):
+        pick = ids[:, :, None] == jnp.arange(logits.shape[-1])  # (Lp, k, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        g = jnp.sum(jnp.where(pick, probs[:, None, :], 0.0), axis=-1)
+        g = g / jnp.sum(g, axis=-1, keepdims=True)
+        w = jnp.sum(jnp.where(pick[:, :, :z["held"]], g[:, :, None], 0.0),
+                    axis=1)
+        valid = jnp.arange(logits.shape[0]) < Lx
+        kth = _top(logits, z["K"])[0][:, -1]
+        low = jnp.min(jnp.sum(jnp.where(pick, logits[:, None, :], 0.0),
+                              axis=-1), axis=-1)
+        return (jnp.where(valid[:, None], w, 0.0),
+                jnp.max(jnp.where(valid, kth - low, 0.0)))
+
+    return f
 
 
 def _experts(z, mm):
-    """(h, rows (held, cap), weights (held, cap), stacked expert weights)
-    -> alpha h + beta sum_e weight * W2(SiLU(W1 h) W3 h) over each expert's
-    rows; padding rows carry weight 0. One expert at a time, its weights
-    upcast to float32 as it runs."""
+    """(h, y, rows (held, T), weights (held, T), stacked expert weights)
+    -> y + sum_e weight * W2(SiLU(W1 h) W3 h) over one tile of each
+    expert's rows; padding rows carry weight 0. One expert at a time, its
+    weights upcast to float32 as it runs."""
     import jax
     import jax.numpy as jnp
 
-    def f(h, idx, wt, w1, w3, w2):
+    def f(h, y, idx, wt, w1, w3, w2):
         def one(y, ex):
             i, w, a, b, c = ex
             a, b, c = (t.astype(jnp.float32) for t in (a, b, c))
@@ -323,31 +371,33 @@ def _experts(z, mm):
                    * mm("rd,df->rf", xr, b), c)
             return y.at[i].add(o * w[:, None]), None
 
-        y, _ = jax.lax.scan(one, jnp.zeros_like(h), (idx, wt, w1, w3, w2))
-        return z["alpha"] * h + z["beta"] * y
+        return jax.lax.scan(one, y, (idx, wt, w1, w3, w2))[0]
 
     return f
 
 
 _CACHE: dict = {}
+TILE = 1024  # rows of one expert per call of the experts piece
 
 
 class Reference:
     """The reference for one configuration and one variant ("f32", "fp8",
-    "no_decay", "normalized"). Its jitted pieces are shared by every
-    instance of the same sizes, variant and rows, so that a process
-    compiles each once: per prompt length, the attention half with the
-    routing of each layer kind, the experts half, the embedding and the
-    head."""
+    "no_decay", "normalized"). Every sequence is held in buffers of
+    `max_len` tokens (rounded up to `rows`) and computed in its first
+    blocks only, so that each jitted piece (the attention half of each
+    layer kind, the router, the gates, the experts, the embedding and the
+    head) compiles once for all prompt lengths, and is shared by every
+    instance of the same sizes, variant, rows and length."""
 
-    def __init__(self, c: dict, variant: str = "f32", rows: int = 2048):
+    def __init__(self, c: dict, variant: str = "f32", rows: int = 2048,
+                 max_len: int = 0):
         import json
 
         import jax
         import jax.numpy as jnp
 
         self.z = z = sizes(c)
-        self.variant, self.rows = variant, rows
+        self.variant, self.rows, self.max_len = variant, rows, max_len
         key = (json.dumps(z, sort_keys=True), variant, rows)
         if key in _CACHE:
             self.fns = _CACHE[key]
@@ -360,57 +410,92 @@ class Reference:
             return jax.tree.map(lambda a: a[0].astype(jnp.float32), t)
 
         self.fns = _CACHE[key] = {
-            "lightning": jax.jit(lambda p, x, s: _route(
-                z, mm, up(p), light(up(p), x, s))),
-            "softmax": jax.jit(lambda p, x, s: _route(
-                z, mm, up(p), soft(up(p), x))),
-            "experts": jax.jit(_experts(z, mm)),
-            "embed": jax.jit(lambda t, ids, n: jnp.pad(
-                t[ids].astype(jnp.float32), ((0, n - ids.shape[0]), (0, 0))),
-                static_argnums=(2,)),
-            "head": jax.jit(lambda x, g, w: mm(
-                "ld,dv->lv", _rms(x, g.astype(jnp.float32), z["eps"]),
-                w.astype(jnp.float32))),
+            "lightning": jax.jit(lambda p, x, s, nb: light(up(p), x, s, nb)),
+            "softmax": jax.jit(lambda p, x, s, nb: soft(up(p), x, nb)),
+            "router": jax.jit(lambda p, x: _router(z, mm)(up(p), x)),
+            "top": jax.jit(lambda lg: _top(lg, z["K"])[1]),
+            "gates": jax.jit(_gates(z)),
+            "experts": jax.jit(_experts(z, mm), donate_argnums=(1,)),
+            "residual": jax.jit(lambda h, y: z["alpha"] * h + z["beta"] * y),
+            "embed": jax.jit(lambda t, ids: t[ids].astype(jnp.float32)),
+            "head": jax.jit(lambda x, at, g, w, n: mm(
+                "ld,dv->lv", _rms(jax.lax.dynamic_slice_in_dim(x, at, n),
+                                  g.astype(jnp.float32), z["eps"]),
+                w.astype(jnp.float32)), static_argnums=(4,)),
         }
+
+    def forward(self, params, tokens: np.ndarray, n_last: int,
+                routes: list | None = None):
+        """(logits (B, n_last, vocab) at each row's last `n_last` positions,
+        float64 on the host; the expert ids each row's tokens took in each
+        layer, [row][layer] (Lx, k); the route gap). tokens: (B, Lx) ids.
+        `routes`, in the same layout, gives the ids to take (the program's
+        choices, which the route gap then measures); else the reference
+        takes its own top k."""
+        import jax
+
+        out = []
+        with jax.default_matmul_precision("highest"):
+            for b, t in enumerate(tokens):
+                out.append(self._row(params, np.asarray(t), n_last,
+                                     None if routes is None else routes[b]))
+        return (np.stack([o[0] for o in out]), [o[1] for o in out],
+                max(o[2] for o in out))
 
     def last_logits(self, params, tokens: np.ndarray, n_last: int
                     ) -> np.ndarray:
-        """Logits (B, n_last, vocab) at each row's last `n_last` positions,
-        float64 on the host. tokens: (B, Lx) ids."""
-        import jax
+        return self.forward(params, tokens, n_last)[0]
 
-        with jax.default_matmul_precision("highest"):
-            return np.stack([self._row(params, np.asarray(t), n_last)
-                             for t in tokens])
-
-    def _row(self, params, ids, n_last):
+    def _row(self, params, ids, n_last, given):
         import jax
         import jax.numpy as jnp
 
         z, R, fns = self.z, self.rows, self.fns
         Lx = ids.shape[0]
-        Lp = -(-Lx // R) * R  # zero rows past the end see nothing before
-        x = fns["embed"](params["embed"]["table"], jnp.asarray(ids), Lp)
+        Lp = -(-max(Lx, self.max_len) // R) * R
+        nb = jnp.asarray(-(-Lx // R), jnp.int32)
+        toks = np.zeros(Lp, np.int32)
+        toks[:Lx] = ids
+        x = fns["embed"](params["embed"]["table"], jnp.asarray(toks))
+        took, worst = [], 0.0
         for l, kind in enumerate(z["kinds"]):
             blk = params["blocks0"][block_key(l, kind)]
-            p = {k: blk[k] for k in ("attn", "attn_norm", "mlp_norm")} \
-                | {"moe": {"router": blk["moe"]["router"]}}
             s = slopes(z, l) * (0.0 if self.variant == "no_decay" else 1.0)
-            h, w = fns[kind](p, x, jnp.asarray(s))
-            w_host = np.asarray(jax.device_get(w))[:Lx]
+            x = fns[kind]({k: blk[k] for k in ("attn", "attn_norm")}, x,
+                          jnp.asarray(s), nb)
+            h, logits = fns["router"](
+                {"mlp_norm": blk["mlp_norm"],
+                 "moe": {"router": blk["moe"]["router"]}}, x)
+            del x  # (Lp, d) float32 buffers are the reference's largest
+            if given is None:
+                top = fns["top"](logits)
+            else:
+                top = np.zeros((Lp, z["K"]), np.int32)
+                top[:Lx] = given[l]
+                top = jnp.asarray(top)
+            w, gap = fns["gates"](logits, top, jnp.asarray(Lx, jnp.int32))
+            took.append(np.asarray(jax.device_get(top))[:Lx])
+            worst = max(worst, float(gap))
+            w_host = np.asarray(jax.device_get(w))
             rows = [np.nonzero(w_host[:, e] > 0)[0] for e in range(z["held"])]
-            cap = max(1024, -(-max(r.size for r in rows) // 1024) * 1024)
-            idx = np.zeros((z["held"], cap), np.int32)
-            wt = np.zeros((z["held"], cap), np.float32)
-            for e, r in enumerate(rows):
-                idx[e, :r.size], wt[e, :r.size] = r, w_host[r, e]
             m = blk["moe"]
-            x = fns["experts"](h, jnp.asarray(idx), jnp.asarray(wt),
-                               m["experts_w_gate"][0], m["experts_w_in"][0],
-                               m["experts_w_out"][0])
-        out = fns["head"](x[Lx - n_last:Lx], params["final_norm"]["scale"],
-                          params["lm_head"]["kernel"])
-        return np.asarray(jax.device_get(out), np.float64)[:, :z["V"]]
+            y = jnp.zeros_like(h)
+            for t0 in range(0, max(r.size for r in rows), TILE):
+                idx = np.zeros((z["held"], TILE), np.int32)
+                wt = np.zeros((z["held"], TILE), np.float32)
+                for e, r in enumerate(rows):
+                    r = r[t0:t0 + TILE]
+                    idx[e, :r.size], wt[e, :r.size] = r, w_host[r, e]
+                y = fns["experts"](h, y, jnp.asarray(idx), jnp.asarray(wt),
+                                   m["experts_w_gate"][0],
+                                   m["experts_w_in"][0],
+                                   m["experts_w_out"][0])
+            x = fns["residual"](h, y)
+        out = fns["head"](x, jnp.asarray(Lx - n_last, jnp.int32),
+                          params["final_norm"]["scale"],
+                          params["lm_head"]["kernel"], n_last)
+        return (np.asarray(jax.device_get(out), np.float64)[:, :z["V"]],
+                took, worst)
 
 
 def gap(prog: np.ndarray, ref: np.ndarray) -> float:

@@ -38,12 +38,17 @@ def lightning_sweep_work(c: dict, shape) -> tuple[float, float]:
     """(operations, bytes) of one call's decay sweeps, all lightning layers:
     per chunk of CHUNK rows and head, the masked q k^T (2 C^2 hd), its
     product with v (2 C^2 hd), the state read (2 C hd^2) and update
-    (2 C hd^2); reading q, k, v and writing the sum and the row sums once,
-    in float32. The same for every call shape of one token count."""
+    (2 C hd^2); reading q, k and v once in the configuration's dtype (the
+    kernel upcasts them in its tiles) and writing the unnormalized sum and
+    the row sums once in float32. The same for every call shape of one
+    token count."""
+    import jax.numpy as jnp
+
     z = ref.sizes(c)
     B, L = shape
     n = sum(k == "lightning" for k in z["kinds"])
     hd = z["hd"]
     per = 4.0 * CHUNK * hd + 4.0 * hd * hd  # per token and head
     rows = float(B * L * z["H"] * n)
-    return rows * per, rows * F32 * (4 * hd + 1)
+    qkv = jnp.dtype(c["dtype"]).itemsize
+    return rows * per, rows * (3 * hd * qkv + F32 * (hd + 1))

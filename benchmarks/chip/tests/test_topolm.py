@@ -1,7 +1,8 @@
 """The `topolm_prefill` kind at a size a CPU test run can hold: its
 reference against the plain one in `src/` (the explicit masked quadratic
-form), a sound run, the three controls and the fault planted under the
-timed path, and its work counts at the published widths."""
+form) and its route gap, a sound run, the three controls and the faults
+planted under the timed path, and its work counts at the published
+widths."""
 import contextlib
 
 import numpy as np
@@ -30,6 +31,27 @@ def test_reference_matches_the_plain_reference():
     for b in range(2):
         want = np.asarray(minimax_ref.forward(cfg, params, toks[b]))
         assert ref.gap(got[b], want[-3:, :c["vocab_size"]]) < 1e-5
+
+
+def test_route_gap_reads_a_wrong_choice():
+    """Given its own top-k choices, the reference reads the same logits and
+    a route gap of 0; given the least likely expert in place of one token's
+    second choice, a gap of its logit's distance to the second."""
+    import jax.numpy as jnp
+
+    c = lm_small.config(dtype="float32")
+    params = ref.init_params(7, c, jnp.float32)
+    toks = ref.prompts(7, c, (1, 40), 1)[0]
+    r = ref.Reference(c, rows=16, max_len=64)
+    own, routes, gap0 = r.forward(params, toks, 3)
+    again, _, gap1 = r.forward(params, toks, 3, routes)
+    assert gap0 == 0.0 and gap1 == 0.0
+    np.testing.assert_array_equal(own, again)
+    wrong = [[t.copy() for t in row] for row in routes]
+    used = set(wrong[0][3][20])
+    wrong[0][3][20, 1] = next(e for e in range(32) if e not in used)
+    _, _, gap2 = r.forward(params, toks, 3, wrong)
+    assert gap2 > 0.0
 
 
 def test_sound_run_is_correct():
@@ -61,8 +83,33 @@ def half_prompt():
         KIND.prefill = prefill
 
 
+@contextlib.contextmanager
+def second_expert_dropped():
+    """The program computes each token's first expert alone, at its
+    renormalized weight, and still logs both choices."""
+    from repro.models import moe
+
+    gates = moe._top_k_gates
+
+    def first_only(probs, k):
+        g, ids = gates(probs, k)
+        return g.at[:, 1:].set(0.0), ids
+
+    moe._top_k_gates = first_only
+    try:
+        yield
+    finally:
+        moe._top_k_gates = gates
+
+
 def test_half_prompt_is_caught():
     with half_prompt():
+        out = lm_small.run()
+    assert not out["correct"], out["checks"]
+
+
+def test_second_expert_dropped_is_caught():
+    with second_expert_dropped():
         out = lm_small.run()
     assert not out["correct"], out["checks"]
 
@@ -82,4 +129,5 @@ def test_work_at_published_widths():
     assert 0.566 < light / max(total) and light / min(total) < 0.669
     sweep, nbytes = lightning_sweep_work(c, shapes[0])
     assert 0.005 < sweep / min(total) < 0.015
-    assert nbytes == 7 * 64 * T * 4 * (4 * 128 + 1)
+    # bf16 q, k, v in; f32 sum and row sums out
+    assert nbytes == 7 * 64 * T * (3 * 128 * 2 + 4 * (128 + 1))

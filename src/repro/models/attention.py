@@ -136,100 +136,79 @@ def _sdpa(cfg, q, k, v, mask):
 
 
 def _sdpa_chunked(cfg, q, k, v, causal: bool, window: int, blk: int = 512):
-    """Flash-style attention in plain XLA: lax.scan over KV blocks with
-    online-softmax stats. Never materializes the (Lq, Lk) score matrix —
-    peak temp drops from O(L^2) to O(L * blk). Exact (fp32 statistics).
-    This is the dry-run/CPU twin of kernels/flash_attention (Pallas is the
-    TPU hot path); selected via cfg.attn_impl == 'chunked'."""
+    """Flash-style attention in plain XLA: `lax.map` over query blocks, each
+    an online softmax (fp32 statistics) over a `lax.scan` of key blocks.
+    A key block wholly after the query block (causal) or outside its window
+    is skipped by a `cond`, so causal work is triangular; the (Lq, Lk) score
+    matrix never exists (peak temp O(blk^2)). Matmul inputs keep their
+    dtype and accumulate in fp32. Differentiable. Query and key positions
+    are taken as aranges from 0. This is the dry-run/CPU twin of
+    kernels/flash_attention (Pallas is the TPU hot path); selected via
+    cfg.attn_impl == 'chunked'."""
     B, Lq, H, hd = q.shape
     Lk = k.shape[1]
     KV = k.shape[2]
     G = H // KV
-    blk = min(blk, Lk)
-    if Lk % blk:  # fall back when blocks don't tile
-        return None
-    nblk = Lk // blk
-    qg = (q.reshape(B, Lq, KV, G, hd).astype(jnp.float32)
-          / math.sqrt(hd)).transpose(0, 2, 3, 1, 4)  # (B,KV,G,Lq,hd)
-    kb = k.reshape(B, nblk, blk, KV, hd).transpose(1, 0, 3, 2, 4)
-    vb = v.reshape(B, nblk, blk, KV, v.shape[-1]).transpose(1, 0, 3, 2, 4)
-    qpos = jnp.arange(Lq)
-
-    def step(carry, inp):
-        m, l, acc = carry
-        kc, vc, bi = inp
-        s = jnp.einsum("bkgqh,bksh->bkgqs", qg, kc.astype(jnp.float32))
-        s = softcap(s, cfg.attn_logit_softcap)
-        kpos = bi * blk + jnp.arange(blk)
-        mask = jnp.ones((Lq, blk), bool)
-        if causal:
-            mask = mask & (qpos[:, None] >= kpos[None, :])
-        if window and window > 0:
-            mask = mask & (qpos[:, None] - kpos[None, :] < window)
-        s = jnp.where(mask[None, None, None], s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        pexp = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(pexp, axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bkgqs,bksh->bkgqh", pexp, vc.astype(jnp.float32))
-        return (m_new, l_new, acc_new), ()
-
     vd = v.shape[-1]  # may differ from hd (MLA: qk 192, v 128)
-    m0 = jnp.full((B, KV, G, Lq), -1e30, jnp.float32)
-    l0 = jnp.zeros((B, KV, G, Lq), jnp.float32)
-    a0 = jnp.zeros((B, KV, G, Lq, vd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        step, (m0, l0, a0), (kb, vb, jnp.arange(nblk)))
-    l = jnp.where(l == 0.0, 1.0, l)
-    out = (acc / l[..., None]).transpose(0, 3, 1, 2, 4)
-    return out.reshape(B, Lq, H, vd).astype(q.dtype)
-
-
-def _sdpa_causal(q, k, v, blk: int = 1024):
-    """Causal attention that visits only the key blocks at or before each
-    query block: `lax.map` over query blocks, each an online softmax over
-    its own prefix of key blocks (a loop whose trip count is the block's
-    index), so the work is triangular and no (Lq, Lk) score matrix exists.
-    bf16 matmul inputs accumulate in f32; statistics are f32. Forward only
-    (the loop bound is traced). q: (B, L, H, hd); k, v: (B, L, KV, hd)."""
-    B, L, H, hd = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    blk = min(blk, L)
-    if L % blk:
+    bk = min(blk, Lk)
+    if Lk % bk:  # fall back when blocks don't tile
         return None
-    nb = L // blk
-    qg = q.reshape(B, L, KV, G, hd)
+    bq = min(blk, Lq)
+    if Lq % bq:
+        bq = Lq
+    nk, nq = Lk // bk, Lq // bq
     scale = 1.0 / math.sqrt(hd)
-    diag = (jnp.arange(blk)[:, None] >= jnp.arange(blk)[None, :])
+    qb = q.reshape(B, nq, bq, KV, G, hd).transpose(1, 0, 3, 4, 2, 5)
+    kb = k.reshape(B, nk, bk, KV, hd).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(B, nk, bk, KV, vd).transpose(1, 0, 3, 2, 4)
 
-    def one(i):
-        qi = jax.lax.dynamic_slice_in_dim(qg, i * blk, blk, axis=1)
+    def qblock(qi_i):
+        qi, i = qi_i  # (B, KV, G, bq, hd)
+        qpos = i * bq + jnp.arange(bq)
 
-        def body(j, carry):
+        def attend(carry, kc, vc, j):
             m, l, acc = carry
-            kj = jax.lax.dynamic_slice_in_dim(k, j * blk, blk, axis=1)
-            vj = jax.lax.dynamic_slice_in_dim(v, j * blk, blk, axis=1)
-            s = jnp.einsum("bqkgh,bskh->bkgqs", qi, kj,
+            s = jnp.einsum("bkgqh,bksh->bkgqs", qi, kc,
                            preferred_element_type=jnp.float32) * scale
-            s = jnp.where((j < i) | diag, s, -1e30)
+            s = softcap(s, cfg.attn_logit_softcap)
+            kpos = j * bk + jnp.arange(bk)
+            mask = jnp.ones((bq, bk), bool)
+            if causal:
+                mask = mask & (qpos[:, None] >= kpos[None, :])
+            if window and window > 0:
+                mask = mask & (qpos[:, None] - kpos[None, :] < window)
+            s = jnp.where(mask, s, -1e30)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             pexp = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             acc = acc * corr[..., None] + jnp.einsum(
-                "bkgqs,bskh->bkgqh", pexp.astype(v.dtype), vj,
+                "bkgqs,bksh->bkgqh", pexp.astype(vc.dtype), vc,
                 preferred_element_type=jnp.float32)
             return m_new, l * corr + jnp.sum(pexp, axis=-1), acc
 
-        m0 = jnp.full((B, KV, G, blk), -1e30, jnp.float32)
-        l0 = jnp.zeros((B, KV, G, blk), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, blk, hd), jnp.float32)
-        _, l, acc = jax.lax.fori_loop(0, i + 1, body, (m0, l0, a0))
-        return (acc / l[..., None]).astype(q.dtype)
+        def step(carry, inp):
+            kc, vc, j = inp
+            live = True
+            if causal:
+                live = j * bk <= i * bq + bq - 1
+            if window and window > 0:
+                live = live & (i * bq - (j * bk + bk - 1) < window)
+            if live is True:
+                return attend(carry, kc, vc, j), ()
+            return jax.lax.cond(live, lambda c: attend(c, kc, vc, j),
+                                lambda c: c, carry), ()
 
-    out = jax.lax.map(one, jnp.arange(nb))  # (nb, B, KV, G, blk, hd)
-    return out.transpose(1, 0, 4, 2, 3, 5).reshape(B, L, H, hd)
+        m0 = jnp.full((B, KV, G, bq), -1e30, jnp.float32)
+        l0 = jnp.zeros((B, KV, G, bq), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, bq, vd), jnp.float32)
+        (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0),
+                                      (kb, vb, jnp.arange(nk)))
+        l = jnp.where(l == 0.0, 1.0, l)
+        return acc / l[..., None]
+
+    out = jax.lax.map(qblock, (qb, jnp.arange(nq)))  # (nq,B,KV,G,bq,vd)
+    return out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Lq, H, vd).astype(
+        q.dtype)
 
 
 def full_attention_train(cfg, p, x, positions, causal=True, window=0,
@@ -300,9 +279,8 @@ def full_attention_prefill(cfg, p, x, positions, lengths, cache,
     B, Lp, _ = x.shape
     q, k_new, v_new = _project_qkv(cfg, p, x, positions, rope=rope)
     out = None
-    if (getattr(cfg, "attn_impl", "naive") == "chunked" and not window
-            and not cfg.attn_logit_softcap):
-        out = _sdpa_causal(q, k_new, v_new)
+    if getattr(cfg, "attn_impl", "naive") == "chunked":
+        out = _sdpa_chunked(cfg, q, k_new, v_new, True, window)
     if out is None:
         idx = jnp.arange(Lp)
         mask = (idx[:, None] >= idx[None, :])[None]  # causal (1,Lp,Lp)
@@ -693,16 +671,49 @@ def _expand_kv(cfg, k, v):
     return k, v
 
 
-# sequences up to this length take the Toeplitz mask as one dense f32
-# product: an FFT product's rounding error is absolute (a few ulp of the
-# whole signal), so a row whose normalizer is tiny against the others loses
-# its relative accuracy, and at such lengths the dense product is cheap
-SEQ_DENSE = 256
+# the Toeplitz mask's diagonals |i - j| < FFT_NEAR are multiplied exactly
+# (shifted products in f32) and the FFT takes only the far field: an FFT
+# product's rounding error is absolute (a few ulp of the whole signal), so a
+# row whose normalizer gathers few, small terms (the first rows of a causal
+# mask) lost its relative accuracy to it
+FFT_NEAR = 8
+
+
+def _near_far_fastmult(F, causal: bool):
+    """X (B, H, L, c) -> the Toeplitz product of F (1, H, L) with X: the
+    band |i - j| < FFT_NEAR as shifted products, the rest by FFT."""
+    from repro.core.toeplitz import causal_toeplitz_matvec, symmetric_toeplitz_matvec
+
+    L = F.shape[-1]
+    w = min(FFT_NEAR, L)
+    far = jnp.where(jnp.arange(L) >= w, F, 0.0)
+    fft = causal_toeplitz_matvec if causal else symmetric_toeplitz_matvec
+
+    def shift(X, d):  # row i gets X[i - d] (0 outside)
+        if d > 0:
+            return jnp.pad(X[..., :L - d, :], ((0, 0), (0, 0), (d, 0), (0, 0)))
+        return jnp.pad(X[..., -d:, :], ((0, 0), (0, 0), (0, -d), (0, 0)))
+
+    def mult(X):
+        y = F[..., 0, None, None] * X
+        for d in range(1, w):
+            fd = F[..., d, None, None]
+            y = y + fd * shift(X, d)
+            if not causal:
+                y = y + fd * shift(X, -d)
+        if w < L:
+            yf = fft(far, X)
+            if causal:  # rows i < w have no far field: keep its error out
+                yf = jnp.where((jnp.arange(L) >= w)[:, None], yf, 0.0)
+            y = y + yf
+        return y
+
+    return mult
 
 
 def _topo_fft_attention(cfg, qf, kf, v, coeffs, causal, col_chunk=8):
-    """Algorithm 1 with Toeplitz-FFT FastMult, chunked over feature columns
-    (a dense Toeplitz product up to `SEQ_DENSE` tokens).
+    """Algorithm 1 with Toeplitz-FFT FastMult (the near band exact, see
+    `FFT_NEAR`), chunked over feature columns.
 
     Exact for any g/degree; memory O(B L H chunk*hd) instead of O(B L H m hd).
     Accumulation is float32 end-to-end: inputs are upcast once, the single
@@ -714,19 +725,10 @@ def _topo_fft_attention(cfg, qf, kf, v, coeffs, causal, col_chunk=8):
 
     B, L, H, m = qf.shape
     hd = v.shape[-1]
-    from repro.core.toeplitz import causal_toeplitz_matvec, symmetric_toeplitz_matvec
-    from repro.core.toeplitz import toeplitz_dense
     F = sequence_mask_values(cfg.topo_g, coeffs, L, cfg.topo_dist_scale)  # (H, L)
-    fastmult = causal_toeplitz_matvec if causal else symmetric_toeplitz_matvec
-    if L <= SEQ_DENSE:
-        M = toeplitz_dense(F, L, causal)  # (H, L, L)
-
-        def fastmult(_, X):  # X: (B, H, L, c)
-            return jnp.einsum("hij,bhjc->bhic", M, X,
-                              precision=jax.lax.Precision.HIGHEST)
-    Fb = F[None]  # (1,H,L)
+    mult = _near_far_fastmult(F[None], causal)
     qf32, kf32, v32 = (t.astype(jnp.float32) for t in (qf, kf, v))
-    d2 = fastmult(Fb, kf32.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
+    d2 = mult(kf32.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
     den = jnp.einsum("blhm,blhm->blh", qf32, d2)
     num = jnp.zeros((B, L, H, hd), jnp.float32)
     for c0 in range(0, m, col_chunk):
@@ -734,7 +736,7 @@ def _topo_fft_attention(cfg, qf, kf, v, coeffs, causal, col_chunk=8):
         kc = kf32[..., c0:c1]  # (B,L,H,c)
         v1 = kc[..., None] * v32[..., None, :]  # (B,L,H,c,hd)
         v1 = v1.reshape(B, L, H, -1).transpose(0, 2, 1, 3)  # (B,H,L,c*hd)
-        d1 = fastmult(Fb, v1).transpose(0, 2, 1, 3).reshape(B, L, H, c1 - c0, hd)
+        d1 = mult(v1).transpose(0, 2, 1, 3).reshape(B, L, H, c1 - c0, hd)
         num = num + jnp.einsum("blhc,blhcv->blhv", qf32[..., c0:c1], d1)
     assert num.dtype == jnp.float32 and den.dtype == jnp.float32, (
         "topo fft accumulators must stay fp32")

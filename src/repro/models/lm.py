@@ -101,13 +101,29 @@ def _residual(cfg, x, h, y):
 
 def _ffn(cfg, p, x):
     """The second half of a lightning / softmax block: norm, then the MoE
-    (or MLP), then the residual. Returns (x, aux)."""
+    (or MLP), then the residual. Returns (x, aux, ids): ids (B, L, top_k)
+    are the experts a chip-share MoE routed each token to, else None."""
     h = rms_norm(x, p["mlp_norm"]["scale"], cfg.norm_eps, plus_one=True)
-    if "moe" in p:
+    ids = None
+    if "moe" in p and cfg.moe_held:
+        y, aux, ids = MOE.held_moe_block(cfg, p["moe"], h)
+    elif "moe" in p:
         y, aux = MOE.moe_block(cfg, p["moe"], h)
     else:
         y, aux = gated_mlp(p["mlp"], h, cfg.mlp_act), jnp.zeros((), jnp.float32)
-    return _residual(cfg, x, h, y), aux
+    return _residual(cfg, x, h, y), aux, ids
+
+
+def _log_routes(log, ids, pos=None, lengths=None):
+    """The route log of a chip-share MoE block's cache: `log` (B, S, top_k)
+    holds the experts each cached position was routed to. A decode step
+    writes ids (B, 1, top_k) at `pos` (() or (B,)); a prefill writes ids
+    (B, Lp, top_k) at [0, Lp), but not in rows with lengths[b] == 0."""
+    if lengths is None:
+        B = ids.shape[0]
+        return log.at[jnp.arange(B), A._positions_vec(pos, B)].set(ids[:, 0])
+    new = jax.lax.dynamic_update_slice_in_dim(log, ids, 0, axis=1)
+    return jnp.where((lengths > 0)[:, None, None], new, log)
 
 
 def _block_train(cfg, kind, p, x, positions, window=0, layer=0):
@@ -121,7 +137,7 @@ def _block_train(cfg, kind, p, x, positions, window=0, layer=0):
         else:
             with jax.named_scope("lm.softmax"):
                 y = A.full_attention_train(cfg, p["attn"], h, positions)
-        x, aux = _ffn(cfg, p, _residual(cfg, x, h, y))
+        x, aux, _ = _ffn(cfg, p, _residual(cfg, x, h, y))
     elif kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         w = window if kind == "attn_local_mlp" else 0
         x = x + _attn_train(cfg, p, x, positions, window=w)
@@ -151,6 +167,7 @@ def _block_train(cfg, kind, p, x, positions, window=0, layer=0):
 def _block_decode(cfg, kind, p, x, pos, cache, S, window=0, layer=0):
     """x: (B, 1, d). Returns (x, new_cache)."""
     if kind in ("lightning", "softmax"):
+        log = cache.get("routes")
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
         if kind == "lightning":
             y, cache = A.lightning_attention_decode(cfg, p["attn"], h, cache,
@@ -159,7 +176,9 @@ def _block_decode(cfg, kind, p, x, pos, cache, S, window=0, layer=0):
             with jax.named_scope("lm.softmax"):
                 y, cache = A.full_attention_decode(cfg, p["attn"], h, pos,
                                                    cache)
-        x, _ = _ffn(cfg, p, _residual(cfg, x, h, y))
+        x, _, ids = _ffn(cfg, p, _residual(cfg, x, h, y))
+        if log is not None:
+            cache = dict(cache, routes=_log_routes(log, ids, pos=pos))
     elif kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
         if cfg.mla:
@@ -201,6 +220,7 @@ def _block_prefill(cfg, kind, p, x, positions, lengths, cache, S, window=0,
     padded; rows with lengths[b] == 0 leave their cache untouched (they
     belong to other live serve slots). Returns (x, new_cache)."""
     if kind in ("lightning", "softmax"):
+        log = cache.get("routes")
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
         if kind == "lightning":
             y, cache = A.lightning_attention_prefill(cfg, p["attn"], h,
@@ -211,7 +231,9 @@ def _block_prefill(cfg, kind, p, x, positions, lengths, cache, S, window=0,
                                                     positions, lengths, cache)
                 cache = {k: shard(c, ("batch", None, "kv_heads", None))
                          for k, c in cache.items()}
-        x, _ = _ffn(cfg, p, _residual(cfg, x, h, y))
+        x, _, ids = _ffn(cfg, p, _residual(cfg, x, h, y))
+        if log is not None:
+            cache = dict(cache, routes=_log_routes(log, ids, lengths=lengths))
     elif kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps, plus_one=True)
         if cfg.mla:
@@ -254,9 +276,15 @@ def _block_prefill(cfg, kind, p, x, positions, lengths, cache, S, window=0,
 
 
 def _block_cache_init(cfg, kind, B, S, dtype):
-    if kind == "lightning":
-        return A.lightning_decode_init(cfg, B)
-    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe", "softmax"):
+    if kind in ("lightning", "softmax"):
+        c = (A.lightning_decode_init(cfg, B) if kind == "lightning" else
+             {"k": jnp.zeros((B, S, cfg.num_kv_heads, cfg.head_dim), dtype),
+              "v": jnp.zeros((B, S, cfg.num_kv_heads, cfg.head_dim), dtype)})
+        if cfg.moe and cfg.moe_held:
+            # the chip-share MoE's route log (`_log_routes`)
+            c["routes"] = jnp.zeros((B, S, cfg.top_k), jnp.int32)
+        return c
+    if kind in ("attn_mlp", "attn_local_mlp", "attn_only", "moe"):
         if cfg.mla:
             return {"ckv": jnp.zeros((B, S, cfg.kv_lora_rank), dtype),
                     "krope": jnp.zeros((B, S, cfg.qk_rope_dim), dtype)}

@@ -106,7 +106,7 @@ def moe_block(cfg, p, x):
     all-to-all instead of replicating the token tensor (§Perf iteration B).
     Per-group capacity C/G preserves total capacity."""
     if cfg.moe_held:
-        return held_moe_block(cfg, p, x)
+        return held_moe_block(cfg, p, x)[:2]
     B, L, d = x.shape
     T = B * L
     G = max(1, getattr(cfg, "moe_groups", 1))
@@ -158,7 +158,7 @@ def held_moe_block(cfg, p, x):
     Under a mesh with a `model` axis the held experts are split over it; each
     chip, holding every token, computes its own experts for the tokens
     routed to them and one all-reduce (`lm.moe.exchange`) sums the parts.
-    x: (B, L, d) -> ((B, L, d), aux)."""
+    x: (B, L, d) -> ((B, L, d), aux, the chosen experts' ids (B, L, k))."""
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis import trace_guard
@@ -175,8 +175,7 @@ def held_moe_block(cfg, p, x):
             logits = jnp.dot(xt.astype(jnp.float32), p["router"],
                              precision=jax.lax.Precision.HIGHEST)
             probs = jax.nn.softmax(logits, axis=-1)
-            gate_vals, ids = jax.lax.top_k(probs, K)
-            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+            gate_vals, ids = _top_k_gates(probs, K)
             # (T, n_held): each held expert's weight for each token, 0 where
             # the token did not choose it
             wts = jnp.sum(jnp.where(ids[:, :, None] == held[None, None, :],
@@ -195,7 +194,14 @@ def held_moe_block(cfg, p, x):
                 out_specs=P(), check_vma=False)(xt, wts, *ws)
         else:
             yt = _held_experts(xt, wts, ws)
-    return yt.reshape(B, L, d), aux
+    return yt.reshape(B, L, d), aux, ids.reshape(B, L, K)
+
+
+def _top_k_gates(probs, k):
+    """The k most probable experts of each row, their weights renormalized
+    to sum to 1: (weights (T, k), ids (T, k))."""
+    g, ids = jax.lax.top_k(probs, k)
+    return g / jnp.sum(g, axis=-1, keepdims=True), ids
 
 
 def _held_experts(xt, wts, ws, axis=None):

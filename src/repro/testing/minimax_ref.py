@@ -110,8 +110,9 @@ def layer_kinds(cfg) -> list[str]:
     return list(cfg.superblock) * cfg.num_superblocks
 
 
-def forward(cfg, params, tokens) -> jax.Array:
-    """Logits (L, padded vocab) of one sequence `tokens` (L,)."""
+def forward(cfg, params, tokens, routes: list | None = None) -> jax.Array:
+    """Logits (L, padded vocab) of one sequence `tokens` (L,). `routes`, a
+    list, receives each layer's top-k expert ids (L, k), sorted."""
     with jax.default_matmul_precision("highest"):
         P = _f32(params)
         a, b = cfg.residual_alpha, cfg.residual_beta
@@ -126,5 +127,8 @@ def forward(cfg, params, tokens) -> jax.Array:
             x = a * h + b * y
             h = rms_norm(x, p["mlp_norm"]["scale"], cfg.norm_eps)
             x = a * h + b * moe(cfg, p["moe"], h)
+            if routes is not None:
+                top = jnp.argsort(-(h @ p["moe"]["router"]), axis=-1)
+                routes.append(np.sort(np.asarray(top[:, :cfg.top_k]), -1))
         x = rms_norm(x, P["final_norm"]["scale"], cfg.norm_eps)
         return x @ P["lm_head"]["kernel"]

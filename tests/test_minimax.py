@@ -49,6 +49,39 @@ def test_lightning_layer_matches_dense_masked_form(layer, rng):
     assert s[0] == pytest.approx(2 ** -0.125 * (1 - layer / 79 + 1e-5))
 
 
+@pytest.mark.parametrize("causal,window,cap,Lq", [
+    (True, 0, 0.0, 48), (True, 20, 0.0, 48), (True, 0, 5.0, 48),
+    (False, 0, 0.0, 48), (False, 0, 0.0, 20)])
+def test_chunked_attention_matches_masked(causal, window, cap, Lq, rng):
+    """`_sdpa_chunked` (query blocks, key blocks skipped past the diagonal
+    or the window) equals the masked dense attention, values and
+    gradients; Lq = 20 does not tile the blocks of 16 (one query block)."""
+    cfg = _cfg(attn_logit_softcap=cap)
+    B, Lk, H, KV, hd = 2, 48, 4, 2, 8
+    q, k, v = (jnp.asarray(rng.normal(size=(B, L, n, hd)), jnp.float32)
+               for L, n in ((Lq, H), (Lk, KV), (Lk, KV)))
+    i, j = np.arange(Lq)[:, None], np.arange(Lk)[None, :]
+    mask = np.ones((Lq, Lk), bool)
+    if causal:
+        mask &= i >= j
+    if window:
+        mask &= i - j < window
+    mask = jnp.asarray(mask)[None, None]
+
+    def chunked(q, k, v):
+        return A._sdpa_chunked(cfg, q, k, v, causal, window, blk=16)
+
+    def dense(q, k, v):
+        return A._sdpa(cfg, q, k, v, mask)
+
+    assert _rel(chunked(q, k, v), dense(q, k, v)) < 1e-5
+    w = jnp.asarray(rng.normal(size=(B, Lq, H, hd)), jnp.float32)
+    gc = jax.grad(lambda *a: jnp.sum(chunked(*a) * w), (0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b in zip(gc, gd):
+        assert _rel(a, b) < 1e-4
+
+
 def _prompt(cfg, rng, B, L):
     return jnp.asarray(rng.integers(0, cfg.vocab_size, (B, L)), jnp.int32)
 
@@ -73,9 +106,15 @@ def test_prefill_and_decode_match_reference(rng, monkeypatch):
         steps.append(lg[:, 0])
     V = cfg.vocab_size
     for b in range(B):
-        ref = R.forward(cfg, params, toks[b])
+        routes = []
+        ref = R.forward(cfg, params, toks[b], routes)
         for t, got in enumerate(steps):
             assert _rel(got[b, :V], ref[L - 1 + t, :V]) < 1e-4, (b, t)
+        # the route log holds each position's experts, prefill and decode
+        for l, kind in enumerate(R.layer_kinds(cfg)):
+            log = cache["blocks0"][f"b{l}_{kind}"]["routes"][0, b]
+            np.testing.assert_array_equal(np.sort(np.asarray(log), -1),
+                                          routes[l], err_msg=str(l))
 
 
 def test_expert_shares_sum_to_uncut_layer(rng):
