@@ -4,9 +4,10 @@ the program's normal path, `api.init_cache` -> `api.prefill_into_cache`
 on a mesh of data 1 x model <chips>, the weights placed by
 `tree_param_specs`.
 
-Set-up makes the weights and the prompt pools from the seed on the device,
-compiles one prefill program per call shape (lowered in turn, compiled
-side by side) and runs each shape once. A call prefills one shape's prompts
+Set-up makes the weights and the prompt pools from the seed on the device
+(the routers balanced, `topolm_prefill_ref.balance_routers`), compiles one
+prefill program per call shape (lowered in turn, compiled side by side)
+and runs each shape once. A call prefills one shape's prompts
 into a fresh cache and brings the last-position logits to the host; the
 window cycles the shapes in the seeded order. Two calls of the window,
 drawn by a seeded reservoir sample, keep their caches: after the window
@@ -46,6 +47,21 @@ def model_config(c: dict):
         dtype=c["dtype"], remat=False)
 
 
+def weights(seed: int, c: dict, traffic: dict):
+    """The seed's weights, placed by `tree_param_specs` on the current mesh,
+    their routers balanced on one block of the reference's rows."""
+    import jax
+    import jax.numpy as jnp
+    from repro.launch import sharding as SH
+
+    shapes_tree = jax.eval_shape(
+        lambda: ref.init_params(seed, c, jnp.bfloat16))
+    shardings = jax.tree.map(SH.named_sharding,
+                             SH.tree_param_specs(shapes_tree))
+    params = ref.init_params(seed, c, jnp.dtype(c["dtype"]), shardings)
+    return ref.balance_routers(params, c, seed, traffic["ref_rows"])
+
+
 def prefill(cfg, S):
     """(params, tokens (B, L)) -> (last logits (B, V), filled cache)."""
     import jax.numpy as jnp
@@ -74,7 +90,6 @@ class PrefillCell:
     def __init__(self, config, traffic, seed, host, memo):
         import jax
         import jax.numpy as jnp
-        from repro.launch import sharding as SH
         from repro.launch.mesh import make_mesh
 
         memo = {} if memo is None else memo
@@ -87,13 +102,7 @@ class PrefillCell:
             memo["mesh"] = make_mesh((1, model), ("data", "model"))
         self.mesh = memo["mesh"]
         with self.sharding(), host.span("prepare"):
-            shapes_tree = jax.eval_shape(
-                lambda: ref.init_params(seed, config, jnp.bfloat16))
-            specs = SH.tree_param_specs(shapes_tree)
-            shardings = jax.tree.map(SH.named_sharding, specs)
-            self.params = ref.init_params(seed, config,
-                                          jnp.dtype(config["dtype"]),
-                                          shardings)
+            self.params = weights(seed, config, traffic)
             pool = traffic["pool"]
             self.prompts = {s: [jnp.asarray(p) for p in
                                 ref.prompts(seed, config, s, pool)]
@@ -231,7 +240,6 @@ def control(config, traffic, seed, memo=None, variant: str = "fp8") -> dict:
     against the reference's, on the first call of the seed's order and its
     decode ids."""
     import jax
-    import jax.numpy as jnp
     from repro.launch import sharding as SH
     from repro.launch.mesh import make_mesh
 
@@ -241,12 +249,7 @@ def control(config, traffic, seed, memo=None, variant: str = "fp8") -> dict:
     if "mesh" not in memo:
         memo["mesh"] = make_mesh((1, model), ("data", "model"))
     with SH.use_sharding(memo["mesh"]):
-        shapes_tree = jax.eval_shape(
-            lambda: ref.init_params(seed, config, jnp.bfloat16))
-        shardings = jax.tree.map(SH.named_sharding,
-                                 SH.tree_param_specs(shapes_tree))
-        params = ref.init_params(seed, config, jnp.dtype(config["dtype"]),
-                                 shardings)
+        params = weights(seed, config, traffic)
         s = ref.call_order(seed, [tuple(x) for x in traffic["shapes"]])[0]
         toks = np.concatenate([ref.prompts(seed, config, s,
                                            traffic["pool"])[0],

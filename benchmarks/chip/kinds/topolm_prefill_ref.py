@@ -47,7 +47,8 @@ from __future__ import annotations
 import numpy as np
 
 VOCAB_PAD = 256  # the program pads its embedding and head rows to this
-ROUTER_STD = 0.02  # the program's router init (`moe.moe_init`)
+ROUTER_STD = 0.02  # the program's router init (`moe.moe_init`), before
+# `balance_routers`
 
 
 def prng_key(seed: int, stream: int):
@@ -90,7 +91,8 @@ def init_params(seed: int, c: dict, dtype, shardings=None):
     """The weights in the program's layout (one period, each block's leaves
     stacked on a leading axis of 1), made on the device in one jitted call:
     normal / sqrt(fan_in) matrices in `dtype`, the router in float32 at
-    ROUTER_STD-normal, the embedding 0.02-normal, norm gains
+    ROUTER_STD-normal (`balance_routers` then balances it), the embedding
+    0.02-normal, norm gains
     1 + 0.1-normal.
     `shardings`, a matching tree of shardings, places them."""
     import jax
@@ -158,6 +160,34 @@ def next_ids(seed: int, c: dict, call: int, B: int, n: int) -> np.ndarray:
     """The ids fed to the `n` decode steps after call number `call`."""
     rng = np.random.default_rng([seed, 3, call])
     return rng.integers(0, c["vocab_size"], (B, n), dtype=np.int32)
+
+
+def balance_routers(params, c: dict, seed: int, rows: int):
+    """The weights with each layer's router balanced over the experts, as a
+    trained router is (MiniMax-Text-01 trains with a load-balancing loss).
+    A random router is not: the SiLU features give every token's router
+    input h a common part, whose product with each expert's column sends up
+    to 2.5 times the mean load to some experts, and the seed decides which,
+    so that the held experts' work changed from seed to seed. The reference
+    runs `rows` seeded ids layer by layer; at each layer the router's
+    columns lose their component along the mean of h over those ids and are
+    scaled so that each expert's logits spread over the ids as the experts'
+    mean does (the init's scale), and the layer then routes with them."""
+    import jax
+
+    ids = np.random.default_rng([seed, 4]).integers(
+        0, c["vocab_size"], rows, dtype=np.int32)
+    routers: dict = {}
+    with jax.default_matmul_precision("highest"):
+        Reference(c, rows=rows, max_len=rows)._row(params, ids, 1, None,
+                                                   routers)
+    blocks = dict(params["blocks0"])
+    for l, kind in enumerate(sizes(c)["kinds"]):
+        blk = blocks[block_key(l, kind)]
+        router = jax.device_put(routers[l], blk["moe"]["router"].sharding)
+        blocks[block_key(l, kind)] = {**blk, "moe": {**blk["moe"],
+                                                     "router": router}}
+    return {**params, "blocks0": blocks}
 
 
 # ----------------------------------------------------------------------------
@@ -446,7 +476,10 @@ class Reference:
                     ) -> np.ndarray:
         return self.forward(params, tokens, n_last)[0]
 
-    def _row(self, params, ids, n_last, given):
+    def _row(self, params, ids, n_last, given, balanced=None):
+        """One row's forward. `balanced`, a dict, asks for `balance_routers`'
+        routers: each layer routes with its balanced router, stored there
+        under the layer's index."""
         import jax
         import jax.numpy as jnp
 
@@ -463,9 +496,19 @@ class Reference:
             s = slopes(z, l) * (0.0 if self.variant == "no_decay" else 1.0)
             x = fns[kind]({k: blk[k] for k in ("attn", "attn_norm")}, x,
                           jnp.asarray(s), nb)
+            router = blk["moe"]["router"]
             h, logits = fns["router"](
-                {"mlp_norm": blk["mlp_norm"],
-                 "moe": {"router": blk["moe"]["router"]}}, x)
+                {"mlp_norm": blk["mlp_norm"], "moe": {"router": router}}, x)
+            if balanced is not None:
+                m = jnp.mean(h[:Lx], axis=0)
+                w = router[0].astype(jnp.float32)
+                w = w - jnp.outer(m, m @ w) / (m @ m)
+                sd = jnp.std(h[:Lx] @ w, axis=0)
+                w = w * (jnp.mean(sd) / sd)
+                balanced[l] = router = w[None].astype(router.dtype)
+                h, logits = fns["router"](
+                    {"mlp_norm": blk["mlp_norm"], "moe": {"router": router}},
+                    x)
             del x  # (Lp, d) float32 buffers are the reference's largest
             if given is None:
                 top = fns["top"](logits)

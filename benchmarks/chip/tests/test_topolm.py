@@ -54,6 +54,33 @@ def test_route_gap_reads_a_wrong_choice():
     assert gap2 > 0.0
 
 
+@pytest.mark.parametrize("seed", [7, 9])
+def test_balanced_routers_spread_the_load(seed):
+    """`balance_routers` changes the routers alone, and with them no expert
+    takes the load that a random router sends to its favourites: on a
+    seeded prompt the most that one expert takes in a layer stays under 2.5
+    times the mean, where the init's routers pass it."""
+    import jax
+    import jax.numpy as jnp
+
+    c = lm_small.config()
+    cfg = KIND.model_config(c)
+    raw = ref.init_params(seed, c, jnp.bfloat16)
+    bal = ref.balance_routers(raw, c, seed, 256)
+    same = jax.tree.map(lambda a, b: bool((a == b).all()), raw, bal)
+    for key, blk in same["blocks0"].items():
+        assert not blk["moe"].pop("router"), key
+    assert all(jax.tree.leaves(same))
+    toks = jnp.asarray(ref.prompts(seed, c, (1, 512), 1)[0])
+    most = []
+    for params in (raw, bal):
+        _, cache = jax.jit(KIND.prefill(cfg, 512))(params, toks)
+        most.append(max(np.bincount(log[:512].ravel(), minlength=32).max()
+                        for log in KIND.route_log(c, cache)[0]))
+    mean = 512 * 2 / 32
+    assert most[1] < 2.5 * mean < most[0]
+
+
 def test_sound_run_is_correct():
     out = lm_small.run()
     assert out["correct"], out["checks"]
