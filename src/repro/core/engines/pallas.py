@@ -3,11 +3,14 @@ fdist_matvec TPU kernel.
 
 Per-bucket cross jobs (B, U_t) x (B, U_s) are batched straight into
 `fdist_matvec_batched` for the in-kernel f families (poly / exp / expq /
-rational) — each tile of M is built in VMEM and fed to the MXU, never
-materialized in HBM. Engine selection and the executor live in the
-functional core (`plan_api.select_cross` routes these families to the
-kernel whenever backend == "pallas"); this subclass only carries the kernel
-options and keys the shared fastmult memo with them. The kernel consumes
+rational) — each tile of M is built in VMEM, never materialized in HBM,
+and contracted there: on the VPU in f32 for fields of at most
+`kernels.fdist_matvec.kernel.D_VPU` columns, on the MXU for wider ones (the
+`blk_a` / `blk_b` options size the MXU path's tiles only). Engine
+selection and the executor live in the functional core
+(`plan_api.select_cross` routes these families to the kernel whenever
+backend == "pallas"); this subclass only carries the kernel options and
+keys the shared fastmult memo with them. The kernel consumes
 the *params* distance arrays, so it is traceable — and differentiates —
 through `ftfi.reweight`ed distances. General f falls back to the exact
 Hankel/FFT engine on grid-aligned trees, else batched Chebyshev. Off-TPU

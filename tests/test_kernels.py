@@ -4,7 +4,10 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.kernels.fdist_matvec.kernel import fdist_matvec_pallas
+from repro.analysis import trace_guard
+from repro.kernels.fdist_matvec.kernel import (D_VPU,
+                                               fdist_matvec_batched_pallas,
+                                               fdist_matvec_pallas)
 from repro.kernels.fdist_matvec.ref import fdist_matvec_ref
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
@@ -48,6 +51,75 @@ def test_fdist_matvec_dtypes(dtype, rng):
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                 - ref.astype(jnp.float32))))
     assert err < tol * max(float(jnp.max(jnp.abs(ref.astype(jnp.float32)))), 1)
+
+
+FDIST_MODES = [("poly", (0.5, -0.2, 0.1)), ("exp", (-0.7, 1.3)),
+               ("expq", (-0.05, -0.2, 0.1)), ("rational", (0.8,))]
+
+
+def _fdist_batched_f64(x, y, v, cs, mode):
+    """The batched product in float64 from the f32 inputs."""
+    s = np.asarray(x, np.float64)[:, :, None] + np.asarray(
+        y, np.float64)[:, None, :]
+    c = np.asarray(cs, np.float64)
+    if mode == "poly":
+        m = np.polynomial.polynomial.polyval(s, c)
+    elif mode == "exp":
+        m = c[1] * np.exp(c[0] * s)
+    elif mode == "expq":
+        m = np.exp(c[0] * s * s + c[1] * s + c[2])
+    else:
+        m = 1.0 / (1.0 + c[0] * s * s)
+    return np.einsum("nab,nbd->nad", m, np.asarray(v, np.float64))
+
+
+def _path_count():
+    return (trace_guard.compiles("fdist_matvec:vpu"),
+            trace_guard.compiles("fdist_matvec:mxu"))
+
+
+# ragged shapes that fill no slab, lane or sublane multiple, at widths up to
+# D_VPU (5: 16-row partial sums), and one past it (the MXU path)
+BATCHED_CASES = [(B, a, b, d) for B, a, b in ((3, 33, 65), (1, 129, 257),
+                                             (1, 300, 1500))
+                 for d in (1, 3, D_VPU)] + [(1, 129, 257, 5),
+                                            (3, 33, 65, D_VPU + 1)]
+
+
+@pytest.mark.parametrize("B,a,b,d", BATCHED_CASES)
+@pytest.mark.parametrize("mode,coeffs", FDIST_MODES)
+def test_fdist_matvec_batched_paths(B, a, b, d, mode, coeffs, rng):
+    """Each path of the batched kernel (the VPU one up to D_VPU columns, the
+    MXU one past it) against the float64 product; each trace records the
+    path it took."""
+    x = jnp.asarray(rng.uniform(0, 3, (B, a)), jnp.float32)
+    y = jnp.asarray(rng.uniform(0, 3, (B, b)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, b, d)), jnp.float32)
+    cs = jnp.asarray(coeffs, jnp.float32)
+    before = _path_count()
+    got = fdist_matvec_batched_pallas(x, y, v, cs, mode=mode, interpret=True)
+    vpu, mxu = (n - m for n, m in zip(_path_count(), before))
+    assert (vpu, mxu) == ((1, 0) if d <= D_VPU else (0, 1))
+    ref = _fdist_batched_f64(x, y, v, cs, mode)
+    assert got.shape == ref.shape and got.dtype == jnp.float32
+    err = np.max(np.abs(np.asarray(got) - ref)) / np.max(np.abs(ref))
+    assert err < 3e-6
+
+
+def test_fdist_matvec_narrow_sum_keeps_f32_accuracy(rng):
+    """4,096 sources of mixed sign: the narrow path's sum stays within
+    1e-6 of the float64 product, relative to its largest entry (a plain
+    running f32 sum over the sources reads 2.4e-6 here)."""
+    B, a, b, d = 1, 64, 4096, 3
+    x = jnp.asarray(rng.uniform(0, 0.2, (B, a)), jnp.float32)
+    y = jnp.asarray(rng.uniform(0, 0.2, (B, b)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, b, d)), jnp.float32)
+    cs = jnp.asarray((0.8,), jnp.float32)
+    got = fdist_matvec_batched_pallas(x, y, v, cs, mode="rational",
+                                      interpret=True)
+    ref = _fdist_batched_f64(x, y, v, cs, "rational")
+    err = np.max(np.abs(np.asarray(got) - ref)) / np.max(np.abs(ref))
+    assert err <= 1e-6
 
 
 @pytest.mark.parametrize("L,hd,blk", [(128, 32, 32), (256, 64, 64), (64, 16, 16)])

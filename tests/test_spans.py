@@ -74,6 +74,34 @@ def test_executor_phases_named_in_compiled_hlo(case):
                    for n in names)
 
 
+def test_pallas_integrate_counts_the_vpu_path():
+    """A d = 3 pallas integrate keeps the kernel's name inside the cross
+    scope and traces the narrow (VPU) path once per cross bucket shape,
+    never the MXU one."""
+    from repro.kernels.fdist_matvec.kernel import D_VPU
+
+    assert D_VPU >= 3
+    spec, params = ftfi.build(random_tree(309, seed=3091), leaf_size=16)
+    shapes = {(t.shape, s.shape) for t, s in zip(spec.cross_tgt_mask,
+                                                  spec.cross_src_mask)}
+    fn = C.Rational((1.0,), (1.0, 0.0, 0.5))
+    X = jnp.ones((spec.n, 3), jnp.float32)
+    jax.clear_caches()  # the kernel's jit traces each shape once a process
+    before = trace_guard.snapshot()
+    entry = jax.jit(lambda p, x: ftfi.apply(spec, p, fn, x,
+                                            backend="pallas"))
+    names = _op_names(entry.lower(params, X).compile())
+    after = trace_guard.snapshot()
+
+    def grew(key):
+        return after.get(key, 0) - before.get(key, 0)
+
+    assert any("ftfi.cross/jit(fdist_matvec_batched_pallas)" in n
+               for n in names)
+    assert shapes and grew("fdist_matvec:vpu") == len(shapes)
+    assert grew("fdist_matvec:mxu") == 0
+
+
 @pytest.mark.parametrize("remat", [True, False])
 def test_vit_step_parts_named_forward_and_backward(remat):
     """A two-layer TopoViT step (microbatch scan, grad, AdamW) names Alg. 1,

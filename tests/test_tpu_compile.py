@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis import trace_guard
 from repro.kernels.fdist_matvec.kernel import fdist_matvec_batched_pallas
 from repro.kernels.topo_linear_attention.ops import topo_linear_attention
 
@@ -57,6 +58,21 @@ def test_fdist_matvec_compiles_for_v5e(one_chip, mode, ncoef):
     compiled = fn.lower(_f32((B, a), one_chip), _f32((B, b), one_chip),
                         _f32((B, b, d), one_chip),
                         _f32((ncoef,), one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,a,b", [(2, 43316, 43316), (66, 33, 33)],
+                         ids=["largest", "small"])
+def test_fdist_matvec_narrow_compiles_for_v5e(one_chip, B, a, b):
+    """The narrow-field path at the mesh cell's largest cross bucket
+    (icosphere(7), leaf size 64) and at its smallest, d = 3, rational f."""
+    before = trace_guard.compiles("fdist_matvec:vpu")
+    fn = jax.jit(lambda x, y, v, c: fdist_matvec_batched_pallas(
+        x, y, v, c, mode="rational", interpret=False))
+    compiled = fn.lower(_f32((B, a), one_chip), _f32((B, b), one_chip),
+                        _f32((B, b, 3), one_chip),
+                        _f32((1,), one_chip)).compile()
+    assert trace_guard.compiles("fdist_matvec:vpu") == before + 1
     assert "tpu_custom_call" in compiled.as_text()
 
 
