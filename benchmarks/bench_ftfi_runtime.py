@@ -35,7 +35,7 @@ if __package__ in (None, ""):  # `python benchmarks/bench_ftfi_runtime.py`
 
 from benchmarks.common import emit, timeit
 from repro import ftfi
-from repro.core import (BTFI, Exponential, Forest, Integrator, build_flat_it,
+from repro.core import (BTFI, FTFI, Exponential, Forest, build_flat_it,
                         clear_flat_cache, clear_plan_cache)
 from repro.core.itree_flat import build_flat_forest
 from repro.graphs.graph import random_tree, synthetic_graph
@@ -114,17 +114,14 @@ def _run(sizes, mesh_subdiv, repeat, backends, leaf_size):
         emit(f"fig3/{name}/n{n}/btfi_pre", t_pre_btfi)
         emit(f"fig3/{name}/n{n}/btfi_int", t_int_btfi)
         for backend in backends:
-            # fig3 measures the paper's FTFI algorithm: disable the host
-            # backend's ExpMP fast path so exp f doesn't bypass the IT walk
-            opts = {"use_expmp": False} if backend == "host" else {}
-            if backend == "ftfi":
-                # functional plan API row: jitted pure (params, X) -> Y —
-                # params cross the jit boundary explicitly, so this is the
-                # retrace-free serving/vmap/shard path
-                mk_pre = lambda: ftfi.build(tree, leaf_size=leaf_size)
+            # host: the recursive numpy FTFI (the paper's algorithm: the IT
+            # walk, no ExpMP shortcut for exp f); plan / pallas / ftfi: the
+            # plan, jitted pure (params, X) -> Y on that executor backend
+            # ("ftfi" is the plan backend under its API name)
+            if backend == "host":
+                mk_pre = lambda: FTFI(tree, leaf_size=leaf_size)
             else:
-                mk_pre = lambda: Integrator(tree, backend=backend,
-                                            leaf_size=leaf_size, **opts)
+                mk_pre = lambda: ftfi.build(tree, leaf_size=leaf_size)
             # cold IT build, then backend assembly on the now-warm IT cache:
             # the two add up to a full cold preprocessing pass
             t_pre_it = _cold(
@@ -134,17 +131,18 @@ def _run(sizes, mesh_subdiv, repeat, backends, leaf_size):
             t_pre_plan = _cold(mk_pre, rounds=repeat,
                                clear=clear_plan_cache)
             t_pre = t_pre_it + t_pre_plan
-            if backend == "ftfi":
+            if backend == "host":
+                integ = mk_pre()
+                engine = "recursive_ftfi"
+                run_once = lambda: np.asarray(integ.integrate(fn, X))
+            else:
+                plan = mk_pre()
+                be = "pallas" if backend == "pallas" else "plan"
+                engine = ftfi.describe(plan[0], fn, be)["cross_engine"]
                 import jax
 
-                spec, pp = ftfi.build(tree, leaf_size=leaf_size)
-                engine = ftfi.describe(spec, fn)["cross_engine"]
-                fm = jax.jit(ftfi.fastmult(spec, fn))
-                run_once = lambda: np.asarray(fm(pp, X))
-            else:
-                integ = mk_pre()
-                engine = integ.describe(fn)["cross_engine"]
-                run_once = lambda: np.asarray(integ.integrate(fn, X))
+                fm = jax.jit(ftfi.fastmult(plan[0], fn, backend=be))
+                run_once = lambda: np.asarray(fm(plan[1], X))
             # timeit's warmup call absorbs jit compilation before timing
             t_int = timeit(run_once, repeat=repeat, warmup=1)
             got = run_once()
@@ -192,31 +190,40 @@ def _forest_row(rng, fn, num_trees=90, repeat=2):
     forest = Forest(trees)
     n = forest.num_vertices
     X = rng.normal(size=(n, 4))
-    # baseline: per-tree host loop (ExpMP off: measure the IT walk, as above)
-    mk_loop = lambda: Integrator.from_forest(forest, backend="host",
-                                             use_expmp=False)
+    # baseline: per-tree host loop (no ExpMP: measure the IT walk, as above)
+    loops = []
+    mk_loop = lambda: loops.append([FTFI(t, leaf_size=64)
+                                    for t in forest.trees])
     _clear_all()
     t_pre_loop = timeit(mk_loop, repeat=1, warmup=0)
-    loop = mk_loop()
-    t_int_loop = timeit(lambda: np.asarray(loop.integrate(fn, X)),
-                        repeat=repeat)
-    ref = np.asarray(loop.integrate(fn, X))
+    off = forest.offsets
+
+    def loop():
+        return np.concatenate([
+            f.integrate(fn, X[off[i]:off[i + 1]])
+            for i, f in enumerate(loops[-1])], axis=0)
+
+    t_int_loop = timeit(loop, repeat=repeat)
+    ref = loop()
     emit(f"fig3/forest{num_trees}/n{n}/loop_pre", t_pre_loop)
     emit(f"fig3/forest{num_trees}/n{n}/loop_int", t_int_loop)
     # fused forest plan, with the same cold pre_it / pre_plan split as the
     # single-tree rows: forest flat-IT build, then fused-plan assembly on
     # the warm IT cache
-    mk_forest = lambda: Integrator.from_forest(forest, backend="plan")
+    mk_forest = lambda: ftfi.build(forest)
     t_pre_it = _cold(lambda: build_flat_forest(forest.trees, leaf_size=64),
                      rounds=repeat, clear=_clear_all)
     build_flat_forest(forest.trees, leaf_size=64)  # warm the IT cache
     t_pre_plan = _cold(mk_forest, rounds=repeat, clear=clear_plan_cache)
     t_pre = t_pre_it + t_pre_plan
-    integ = mk_forest()
-    engine = integ.describe(fn)["cross_engine"]
-    t_int = timeit(lambda: np.asarray(integ.integrate(fn, X)), repeat=repeat,
-                   warmup=1)
-    got = np.asarray(integ.integrate(fn, X))
+    plan = mk_forest()
+    engine = ftfi.describe(plan[0], fn)["cross_engine"]
+    import jax
+
+    fm = jax.jit(ftfi.fastmult(plan[0], fn))
+    run_once = lambda: np.asarray(fm(plan[1], X))
+    t_int = timeit(run_once, repeat=repeat, warmup=1)
+    got = run_once()
     err = np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-9)
     total_f, total_b = t_pre + t_int, t_pre_loop + t_int_loop
     emit(f"fig3/forest{num_trees}/n{n}/forest_pre", t_pre,

@@ -7,7 +7,7 @@ The tree-kernel features have a --backend axis:
 
   host    per-graph Python loop: MST -> tree_all_pairs -> exp -> eigvalsh
           (the pre-forest baseline every other backend is timed against)
-  plan    per-graph Integrator loop (one jit dispatch PER graph — exists to
+  plan    per-graph plan loop (one jit dispatch PER graph — exists to
           show why the forest path is the right unit of work)
   pallas  same loop on the Pallas backend
   forest  ALL graphs' MSTs packed into ONE Forest: a single fused plan
@@ -31,13 +31,15 @@ import sys
 import time
 from functools import partial
 
+import jax
 import numpy as np
 
 if __package__ in (None, ""):  # `python benchmarks/bench_graph_classification.py`
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 from benchmarks.common import emit, timeit
-from repro.core import Exponential, Forest, Integrator
+from repro import ftfi
+from repro.core import Exponential, Forest
 from repro.graphs.graph import random_graph_family
 from repro.graphs.mst import minimum_spanning_forest, minimum_spanning_tree
 from repro.graphs.traverse import graph_all_pairs, tree_all_pairs
@@ -91,19 +93,26 @@ def features_ftfi(graphs, k=8):
     return np.array(feats)
 
 
+@partial(jax.jit, static_argnames="backend")
+def _kernel_matrix(spec, params, X, backend):
+    """M_f X for f = exp(LAM s) over a plan. The spec is static pytree
+    structure, so jit compiles once per plan and backend and the steady
+    state reuses it."""
+    return ftfi.apply(spec, params, Exponential(LAM), X, backend=backend)
+
+
 def features_integrator(graphs, k=8, backend="plan"):
-    """Per-graph Integrator loop: one plan compile + jit dispatch PER graph.
+    """Per-graph plan loop: one plan compile + jit dispatch PER graph.
 
     Every graph size is a distinct set of bucket shapes, so this pays N
     dispatches (and, cold, N compilations) — the anti-pattern the packed
     forest path exists to remove."""
-    fn = Exponential(LAM)
     feats = []
     for g in graphs:
         mst = minimum_spanning_tree(g)
         n = mst.num_vertices
-        integ = Integrator(mst, backend=backend)
-        M = np.asarray(integ.integrate(fn, np.eye(n, dtype=np.float32)))
+        M = np.asarray(_kernel_matrix(*ftfi.build(mst),
+                                      np.eye(n, dtype=np.float32), backend))
         feats.append(_kernel_spectrum(M, k))
     return np.array(feats)
 
@@ -124,8 +133,8 @@ def features_forest(graphs, k=8, backend="plan"):
     N, nmax = forest.num_vertices, int(sizes.max())
     E = np.zeros((N, nmax), dtype=np.float32)
     E[np.arange(N), np.concatenate([np.arange(s) for s in sizes])] = 1.0
-    integ = Integrator.from_forest(forest, backend=backend)
-    M = np.asarray(integ.integrate(Exponential(LAM), E))  # (N, nmax)
+    M = np.asarray(_kernel_matrix(*ftfi.build(forest), E,
+                                  backend))  # (N, nmax)
     return np.array([
         _kernel_spectrum(M[off[t]:off[t] + s, :s], k)
         for t, s in enumerate(sizes)])

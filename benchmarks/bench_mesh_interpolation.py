@@ -91,14 +91,13 @@ def run(meshes=None, lambdas=(1.0, 4.0, 16.0)):
             # ONE fused forest integration, per-tree outputs averaged.
             # Forest construction is hoisted out of the lambda sweep (it is
             # lambda-independent), mirroring the single-tree row above.
-            from repro.core.engines import Integrator
+            from repro import ftfi
             from repro.graphs.frt import forest_leaf_integrate, frt_forest
 
             k = 4
             t0 = _t.perf_counter()
             forest, leaf = frt_forest(g, k, seed=0, D=Dg)
-            finteg = Integrator.from_forest(forest, backend="plan",
-                                            leaf_size=128)
+            finteg = ftfi.build(forest, leaf_size=128)
             t_forest_pre = _t.perf_counter() - t0
             best = -1.0
             for lam in lambdas:

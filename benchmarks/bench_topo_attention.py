@@ -21,7 +21,7 @@ if __package__ in (None, ""):  # `python benchmarks/bench_topo_attention.py`
 
 from benchmarks.common import emit, timeit
 from repro.core import masks as MK
-from repro.core.engines import Integrator
+from repro import ftfi
 from repro.core.toeplitz import toeplitz_dense
 
 
@@ -124,7 +124,7 @@ def scaling(rng):
 
 
 def tree_attention(rng, backends=("plan",), side=8):
-    """Grid-MST topological masking (the ViT path) per Integrator backend:
+    """Grid-MST topological masking (the ViT path) per executor backend:
     exactness vs the dense mask and per-call latency of Algorithm 1."""
     from repro.graphs.graph import grid_graph
     from repro.graphs.mst import minimum_spanning_tree
@@ -140,14 +140,17 @@ def tree_attention(rng, backends=("plan",), side=8):
     mask = MK.mask_f(g, coeffs, 1.0 / L)(jnp.asarray(D))
     ref = MK.masked_attention_bruteforce(qf, kf, V, mask)
     for backend in backends:
-        integ = Integrator(mst, backend=backend, leaf_size=16)
-        fm = MK.make_tree_fastmult(integ, g, coeffs, 1.0 / L)
+        plan = ftfi.build(mst, leaf_size=16)
+        fm = MK.make_tree_fastmult(plan, g, coeffs, 1.0 / L,
+                                   backend=backend, tree=mst)
         attn = lambda: jax.block_until_ready(
             MK.masked_linear_attention(qf, kf, V, fm))
         got = attn()
         err = float(jnp.max(jnp.abs(got - ref)))
         t = timeit(attn)
-        engine = integ.describe(MK.mask_f(g, coeffs, 1.0 / L))["cross_engine"]
+        engine = ("dense" if MK.tree_fastmult_path(plan) == "dense" else
+                  ftfi.describe(plan[0], MK.mask_f(g, coeffs, 1.0 / L),
+                                backend)["cross_engine"])
         emit(f"tab1/tree/L{L}/{backend}", t,
              f"maxerr={err:.2e} engine={engine}")
 

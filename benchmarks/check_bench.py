@@ -123,7 +123,8 @@ def check_forest(max_rel_err: float) -> list[str]:
     """Forest smoke: the fused forest plan must equal the per-tree host loop
     on a small mixed-size forest, for an exact family AND a general f."""
     import numpy as np
-    from repro.core import AnyFn, Exponential, Forest, Integrator
+    from repro import ftfi
+    from repro.core import FTFI, AnyFn, Exponential, Forest
     from repro.graphs.graph import (caterpillar_tree, path_graph, random_tree,
                                     star_tree)
 
@@ -134,13 +135,14 @@ def check_forest(max_rel_err: float) -> list[str]:
               caterpillar_tree(36, seed=2)]
     forest = Forest(trees)
     X = rng.normal(size=(forest.num_vertices, 3))
-    loop = Integrator.from_forest(forest, backend="host")
+    off = forest.offsets
+    spec, params = ftfi.build(forest, leaf_size=16)
     errors = []
     for fn, label in ((Exponential(-0.6, 1.2), "exp"),
                       (AnyFn(lambda z: 1.0 / (1.0 + z)), "anyfn")):
-        ref = np.asarray(loop.integrate(fn, X))
-        got = np.asarray(Integrator.from_forest(
-            forest, backend="plan", leaf_size=16).integrate(fn, X))
+        ref = np.concatenate([FTFI(t).integrate(fn, X[off[i]:off[i + 1]])
+                              for i, t in enumerate(forest.trees)])
+        got = np.asarray(ftfi.apply(spec, params, fn, X))
         err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
         if err > max_rel_err:
             errors.append(

@@ -53,7 +53,8 @@ def main() -> None:
                     help="smaller sizes for CI-speed runs")
     ap.add_argument("--only", default=None)
     ap.add_argument("--backend", default="host",
-                    help="comma list of Integrator backends for fig3/tab1")
+                    help="comma list of backends (host,plan,pallas,ftfi) for "
+                         "fig3/tab1")
     ap.add_argument("--fig5-backend", default="host,forest",
                     help="comma list of host,plan,pallas,forest for the "
                          "graph-classification suite (plan/pallas are "

@@ -29,8 +29,8 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro import ftfi  # noqa: E402
 from repro.core import masks  # noqa: E402
-from repro.core.engines import Integrator  # noqa: E402
 from repro.graphs.graph import grid_graph  # noqa: E402
 from repro.graphs.mst import minimum_spanning_tree  # noqa: E402
 
@@ -39,7 +39,7 @@ PATHS = {"dense": 1 << 30, "plan": 0}  # the N_DENSE that selects each path
 
 def measure(side: int, width: int, reps: int, seed: int = 0) -> list[dict]:
     mst = minimum_spanning_tree(grid_graph(side, side))
-    integ = Integrator(mst, backend="plan", leaf_size=16)
+    plan = ftfi.build(mst, leaf_size=16)
     n = side * side
     X = jax.random.normal(jax.random.PRNGKey(seed), (n, width), jnp.float32)
     coeffs = jnp.asarray([0.0, -1.0, 0.0], jnp.float32)
@@ -50,7 +50,7 @@ def measure(side: int, width: int, reps: int, seed: int = 0) -> list[dict]:
         for path, cap in PATHS.items():
             masks.N_DENSE = cap
             fm = jax.jit(lambda c, x: masks.make_tree_fastmult(
-                integ, "exp", c, 0.0625)(x))
+                plan, "exp", c, 0.0625, tree=mst)(x))
             t0 = time.perf_counter()
             compiled = fm.lower(coeffs, X).compile()
             compile_s = time.perf_counter() - t0

@@ -216,7 +216,9 @@ def vit_batch(cfg, batch: int, patch_dim: int, num_classes: int, seed: int):
 
 def mask_engine(cfg, integ, params) -> str:
     """What runs layer 0's mask: "dense" (the small-tree product by f(D)),
-    else the grid integrator's cross engine."""
+    else the cross engine of the grid plan `integ` on the config's
+    backend."""
+    from repro import ftfi
     from repro.core.masks import mask_f, tree_fastmult_path
     from repro.models import attention as A
 
@@ -226,7 +228,8 @@ def mask_engine(cfg, integ, params) -> str:
     p_topo = {k: v[0] for k, v in params["blocks"]["topo"].items()}
     coeffs = np.asarray(A.topo_mask_coeffs(cfg, p_topo)[0])
     f = mask_f(cfg.topo_g, coeffs, cfg.topo_dist_scale)
-    return integ.describe(f)["cross_engine"]
+    return ftfi.describe(integ[0], f,
+                         A.resolve_topo_backend(cfg))["cross_engine"]
 
 
 def phase_topovit(cfg, *, train_layers: int | None = None, batch: int = 128,

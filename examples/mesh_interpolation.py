@@ -9,7 +9,7 @@ import time
 
 import numpy as np
 
-from repro.core import Integrator, Rational
+from repro.core import FTFI, Rational
 from repro.graphs.meshes import icosphere, mesh_graph, vertex_normals
 from repro.graphs.mst import minimum_spanning_tree
 
@@ -25,7 +25,7 @@ for subdiv in (3, 4):
     F = np.where(known[:, None], normals, 0.0)
 
     t0 = time.perf_counter()
-    integ = Integrator(mst, backend="host", leaf_size=256)
+    integ = FTFI(mst, leaf_size=256)
     t_pre = time.perf_counter() - t0
 
     best = (-1.0, None)

@@ -6,7 +6,7 @@ import time
 
 import numpy as np
 
-from repro.core import BTFI, Exponential, Integrator, Polynomial, Rational
+from repro.core import BTFI, FTFI, Exponential, Polynomial, Rational
 from repro.graphs.graph import synthetic_graph
 from repro.graphs.mst import minimum_spanning_tree
 
@@ -21,12 +21,10 @@ rng = np.random.default_rng(0)
 X = rng.normal(size=(n, 8))
 
 # 3. Preprocess once (IntegratorTree, O(N log N)), integrate many times.
-#    One API, swappable structured-multiply backends:
-#      host   recursive numpy engines (exact; ExpMP fast path for exp)
-#      plan   jit-able bucketed plan executor (exact LDR + Chebyshev)
-#      pallas plan executor on the fused fdist_matvec TPU kernel
+#    The recursive numpy FTFI is the host reference (exact per-node LDR
+#    engines); step 5 runs the same integrals through the jit-able plan.
 t0 = time.perf_counter()
-integ = Integrator(tree, backend="host", leaf_size=256)
+integ = FTFI(tree, leaf_size=256)
 t_pre = time.perf_counter() - t0
 
 for fn, name in [(Exponential(-0.5), "exp(-0.5 x)"),
@@ -35,9 +33,8 @@ for fn, name in [(Exponential(-0.5), "exp(-0.5 x)"),
     t0 = time.perf_counter()
     out = integ.integrate(fn, X)
     t_fast = time.perf_counter() - t0
-    engine = integ.describe(fn)["cross_engine"]
     print(f"f = {name:20s} integrated {n} vertices x 8 channels "
-          f"in {t_fast*1e3:7.1f} ms  [{engine}]")
+          f"in {t_fast*1e3:7.1f} ms")
 
 # 4. Exactness: identical to brute force (materialized N x N kernel).
 t0 = time.perf_counter()
@@ -50,33 +47,35 @@ t_brute = time.perf_counter() - t0
 got = integ.integrate(fn, X)
 err = np.max(np.abs(got - ref)) / np.max(np.abs(ref))
 print(f"\nexact vs brute force: rel err = {err:.2e}")
-print(f"preprocessing: Integrator {t_pre:.2f}s vs BTFI {t_pre_b:.2f}s "
+print(f"preprocessing: FTFI {t_pre:.2f}s vs BTFI {t_pre_b:.2f}s "
       f"({t_pre_b/max(t_pre, 1e-9):.1f}x)")
 
-# 5. The jit-able backends agree too (compiled once, reused per field).
-sub_n = 1500
-sub = minimum_spanning_tree(synthetic_graph(sub_n, sub_n // 2, seed=1))
-Xs = rng.normal(size=(sub_n, 8))
-ref = BTFI(sub).integrate(fn, Xs)
-for backend in ("plan", "pallas"):
-    ii = Integrator(sub, backend=backend, leaf_size=64)
-    t0 = time.perf_counter()
-    got = np.asarray(ii.integrate(fn, Xs))
-    dt = time.perf_counter() - t0
-    err = np.max(np.abs(got - ref)) / np.max(np.abs(ref))
-    print(f"backend={backend:6s} rel err vs BTFI = {err:.2e}  "
-          f"({dt*1e3:.1f} ms, engine={ii.describe(fn)['cross_engine']})")
-
-# 6. Functional plan API: static PlanSpec (pytree aux) + differentiable
-#    PlanParams (pytree leaves). Pure (params, X) -> Y crosses jit
-#    boundaries explicitly — vmap over batched fields, checkpoint/serve the
-#    plan, and (with reweightable=True) train the tree metric itself.
+# 5. The plan API: static PlanSpec (pytree aux) + differentiable
+#    PlanParams (pytree leaves), one executor on two backends:
+#      plan   jit-able bucketed plan executor (exact LDR + Chebyshev)
+#      pallas plan executor on the fused fdist_matvec TPU kernel
+#    Pure (params, X) -> Y crosses jit boundaries explicitly — vmap over
+#    batched fields, checkpoint/serve the plan, and (with
+#    reweightable=True) train the tree metric itself.
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro import ftfi  # noqa: E402
 
+sub_n = 1500
+sub = minimum_spanning_tree(synthetic_graph(sub_n, sub_n // 2, seed=1))
+Xs = rng.normal(size=(sub_n, 8))
+ref = BTFI(sub).integrate(fn, Xs)
 spec, params = ftfi.build(sub, leaf_size=64)
+for backend in ("plan", "pallas"):
+    t0 = time.perf_counter()
+    got = np.asarray(ftfi.apply(spec, params, fn, Xs, backend=backend))
+    dt = time.perf_counter() - t0
+    err = np.max(np.abs(got - ref)) / np.max(np.abs(ref))
+    engine = ftfi.describe(spec, fn, backend)["cross_engine"]
+    print(f"backend={backend:6s} rel err vs BTFI = {err:.2e}  "
+          f"({dt*1e3:.1f} ms, engine={engine})")
+
 fm = jax.jit(ftfi.fastmult(spec, fn))
 got = np.asarray(fm(params, Xs))
 err = np.max(np.abs(got - ref)) / np.max(np.abs(ref))

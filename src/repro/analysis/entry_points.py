@@ -7,7 +7,7 @@ route, same engines, same shard_map wrapping).  ``python -m repro.analysis
 ``ANALYSIS_BUDGETS.json``; tests iterate the same registry so the budget
 file and the test suite can never drift apart.
 
-Sections: ``core`` (ftfi functional API + backends), ``kernels`` (Pallas
+Sections: ``core`` (ftfi functional API on the plan and pallas backends), ``kernels`` (Pallas
 ops), ``models`` (train steps / forwards), ``serve`` (prefill), ``sharded``
 (shard_map paths — need >= 8 devices, skipped otherwise).
 """
@@ -128,30 +128,17 @@ def _ftfi_reweight_grad():
     return jax.grad(loss), (w0, X)
 
 
-@entry("engines.plan.fastmult", "core",
-       "Integrator facade over PlanBackend (params ride the closure)")
-def _engine_plan():
-    from repro.core.engines.base import Integrator
+@entry("ftfi.fastmult.pallas", "core",
+       "fused plan executor on the fdist_matvec kernel (interpret off-TPU)")
+def _ftfi_fastmult_pallas():
+    import repro.ftfi as ftfi
     from repro.core import cordial as C
     from repro.graphs.graph import random_tree
 
-    integ = Integrator(random_tree(80, seed=3), backend="plan")
-    pf = integ.fastmult(C.Exponential(-0.5))
+    spec, params = ftfi.build(random_tree(80, seed=4))
     X = _rng().standard_normal((80, 2), dtype=np.float32)
-    return (lambda X: pf(X)), (X,)
-
-
-@entry("engines.pallas.fastmult", "core",
-       "Integrator facade over PallasBackend (interpret off-TPU)")
-def _engine_pallas():
-    from repro.core.engines.base import Integrator
-    from repro.core import cordial as C
-    from repro.graphs.graph import random_tree
-
-    integ = Integrator(random_tree(80, seed=4), backend="pallas")
-    pf = integ.fastmult(C.Exponential(-0.5))
-    X = _rng().standard_normal((80, 2), dtype=np.float32)
-    return (lambda X: pf(X)), (X,)
+    return (ftfi.fastmult(spec, C.Exponential(-0.5), backend="pallas"),
+            (params, X))
 
 
 # ---------------------------------------------------------------------------

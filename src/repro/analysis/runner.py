@@ -114,7 +114,6 @@ def run_trace_guard(budgets: dict) -> dict:
     from repro.analysis import trace_guard as tg
     from repro.core import cordial as C
     from repro.core import masks
-    from repro.core.engines.base import Integrator
     from repro.graphs.graph import random_tree
 
     tg.reset()
@@ -125,20 +124,8 @@ def run_trace_guard(budgets: dict) -> dict:
     def stable(*sites, max_compiles=0):
         return tg.expect_stable(*sites, max_compiles=max_compiles)
 
-    # 1. backend fastmult memo (Integrator facade)
+    # 1. functional fastmult under an outer jit
     tree = random_tree(64, seed=0)
-    integ = Integrator(tree, backend="plan")
-    pf = integ.fastmult(C.Exponential(-0.5))
-    pf(X)  # first call compiles
-    try:
-        with stable("engines.plan.fastmult"):
-            pf(X)
-            pf(X)
-            integ.fastmult(C.Exponential(-0.5))(X)  # memo returns same closure
-    except tg.RetraceError as e:
-        issues.append(f"trace_guard[backend-memo]: {e}")
-
-    # 2. functional fastmult under an outer jit
     spec, params = ftfi.build(tree)
     fm = jax.jit(ftfi.fastmult(spec, C.Exponential(-0.5)))
     fm(params, X)
@@ -148,18 +135,20 @@ def run_trace_guard(budgets: dict) -> dict:
     except tg.RetraceError as e:
         issues.append(f"trace_guard[ftfi-fastmult]: {e}")
 
-    # 3. mask-closure LRU (serving / eval rebuild path), on the plan
-    # executor: the 64-vertex tree would take the dense path, which
-    # compiles no executor for the counters to watch
+    # 2. mask-closure LRU (serving / eval rebuild path) over the pair, on
+    # the plan executor, whose compiles count under `masks.tree_fastmult`:
+    # the 64-vertex tree would take the dense path, which compiles no
+    # executor for the counters to watch
     coeffs = np.asarray([1.0, -0.5], np.float32)
     F = jnp.asarray(rng.standard_normal((2, 64, 3)), jnp.float32)
     n_dense, masks.N_DENSE = masks.N_DENSE, 0
     try:
-        mfm = masks.make_tree_fastmult(integ, "exp", coeffs, 1.0)
+        mfm = masks.make_tree_fastmult((spec, params), "exp", coeffs, 1.0)
         mfm(F)  # new f family -> exactly one compile
         hits0 = tg.compiles("masks.tree_fastmult:hit")
-        with stable("engines.plan.fastmult", "ftfi.fastmult"):
-            masks.make_tree_fastmult(integ, "exp", coeffs, 1.0)(F)
+        with stable("masks.tree_fastmult", "ftfi.fastmult"):
+            masks.make_tree_fastmult((spec, params), "exp", coeffs, 1.0)(F)
+            mfm(F)
             mfm(F)
     except tg.RetraceError as e:
         issues.append(f"trace_guard[mask-memo]: {e}")
@@ -169,7 +158,7 @@ def run_trace_guard(budgets: dict) -> dict:
         issues.append("trace_guard[mask-memo]: rebuilding an identical mask "
                       "closure missed the _TREE_FM_CACHE")
 
-    # 4. serve decode / prefill buckets
+    # 3. serve decode / prefill buckets
     try:
         from repro.configs.base import get_smoke_config
         from repro.models import api

@@ -1,8 +1,8 @@
 """Retrace sentinel: compile-count accounting for the shared jit closures.
 
 The repo's hot paths are all served from memoized jit closures — the
-`plan_api.fastmult` memos on the backends, the `masks.make_tree_fastmult`
-LRU, the serve engine's decode/prefill buckets.  A cache-key bug (or an
+jitted `ftfi.fastmult` closures, the `masks.make_tree_fastmult` LRU, the
+serve engine's decode/prefill buckets.  A cache-key bug (or an
 unhashable static arg, or a python scalar that should have been an array)
 turns any of them into a silent retrace-per-call, which never fails a
 correctness test but destroys serving latency.

@@ -35,7 +35,7 @@ class ModelConfig:
     # sequence-mask attention impl: ref (dense O(L^2) oracle) | fft
     # (separable scan / Toeplitz-FFT column chunks) | pallas (fused kernel)
     topo_attn_impl: str = "fft"
-    # tree/grid Integrator backend override for the ViT path (None: follow
+    # tree/grid executor backend override for the ViT path (None: follow
     # topo_attn_impl — pallas -> pallas, else plan)
     topo_backend: Optional[str] = None
     # multi-device: run the topo plan executor under shard_map on the active

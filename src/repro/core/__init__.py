@@ -3,21 +3,15 @@ from repro.core.cordial import (  # noqa: F401
     AnyFn, CordialFn, ExpPoly, ExpQuadratic, ExpRational, Exponential,
     Polynomial, Rational, Trigonometric,
 )
-from repro.core.integrate import (  # noqa: F401
-    BTFI, ExpMP, FTFI, IntegrationPlan, clear_plan_cache,
-    compile_forest_plan, compile_plan,
-)
+from repro.core.integrate import BTFI, ExpMP, FTFI  # noqa: F401
 from repro.core.itree_flat import (  # noqa: F401
     FlatIT, build_flat_forest, build_flat_it, clear_flat_cache, flat_stats,
     tree_fingerprint,
 )
 from repro.graphs.graph import Forest  # noqa: F401
-from repro.core.engines import (  # noqa: F401
-    Integrator, available_backends, chebyshev_batched_matvec, execute_plan,
-    polynomial_batched_matvec, register_backend,
-)
 from repro.core.plan_api import (  # noqa: F401
-    PlanParams, PlanSpec,
+    PlanParams, PlanSpec, chebyshev_batched_matvec, clear_plan_cache,
+    polynomial_batched_matvec,
 )
 from repro.core.plan_guard import (  # noqa: F401
     PlanGuardWarning, PlanValidationError,

@@ -457,3 +457,88 @@ class AnyFn(CordialFn):
         if h is not None:
             return hankel_fft_matvec(self.fn, x, y, V, h, xp=xp)
         return chebyshev_matvec(self.fn, x, y, V, degree=self.degree, xp=xp, tol=self.tol)
+
+
+# ----------------------------------------------------------------------------
+# FamilySpec: one normalized description of f for the plan executor
+# ----------------------------------------------------------------------------
+#
+# The executor dispatches on `mode`, the structured-multiply family of f:
+#
+#   mode        f(s)                          exact engines available
+#   ----------  ----------------------------  --------------------------------
+#   "poly"      sum_t coeffs[t] s^t           polynomial LDR, Pallas in-kernel
+#   "exp"       coeffs[1] * exp(coeffs[0] s)  rank-1, Pallas in-kernel, ExpMP
+#   "expq"      exp(c0 s^2 + c1 s + c2)       Pallas in-kernel, Hankel on grids
+#   "rational"  scale / (1 + c0 s^2)          Pallas in-kernel, Hankel on grids
+#   None        anything                      Hankel on grids, else Chebyshev
+#
+# `coeffs` follows the layout of kernels/fdist_matvec (`_f_tile`); `scale` is
+# a scalar multiplier applied OUTSIDE the kernel families that don't carry
+# one. `fn_eval` is an xp-traceable evaluation of the full f (scale included)
+# used for leaf blocks, pivot corrections and the Chebyshev/Hankel fallbacks.
+
+
+@dataclasses.dataclass(frozen=True)
+class FamilySpec:
+    mode: str | None
+    coeffs: tuple
+    fn_eval: Callable  # traceable full f (jnp in, jnp out)
+    cordial: CordialFn  # host-side strategy object (FTFI path)
+    scale: float = 1.0
+
+
+def _horner(coeffs):
+    def f(z):
+        acc = 0.0
+        for c in reversed(coeffs):
+            acc = acc * z + c
+        return acc
+
+    return f
+
+
+def spec_of(fn) -> FamilySpec:
+    """Classify `fn` (a CordialFn or a plain traceable callable)."""
+    import jax.numpy as jnp
+
+    if isinstance(fn, Polynomial):
+        cs = tuple(float(c) for c in fn.coeffs)
+        return FamilySpec("poly", cs, _horner(cs), fn)
+    if isinstance(fn, Exponential):
+        lam, s = float(fn.lam), float(fn.scale)
+        return FamilySpec("exp", (lam, s), lambda z: s * jnp.exp(lam * z), fn)
+    if isinstance(fn, ExpQuadratic):
+        u, v, w = float(fn.u), float(fn.v), float(fn.w)
+        return FamilySpec(
+            "expq", (u, v, w), lambda z: jnp.exp(u * z * z + v * z + w), fn)
+    if isinstance(fn, Rational):
+        num, den = tuple(map(float, fn.num)), tuple(map(float, fn.den))
+        if (len(num) == 1 and len(den) == 3 and den[0] > 0.0 and den[1] == 0.0
+                and den[2] >= 0.0):
+            # a / (d0 + d2 s^2) = (a/d0) * 1/(1 + (d2/d0) s^2)
+            c0 = den[2] / den[0]
+            scale = num[0] / den[0]
+            return FamilySpec(
+                "rational", (c0,),
+                lambda z: scale / (1.0 + c0 * z * z), fn, scale=scale)
+        pn, pd = _horner(num), _horner(den)
+        return FamilySpec(None, (), lambda z: pn(z) / pd(z), fn)
+    if isinstance(fn, ExpPoly):
+        lam, cs = float(fn.lam), tuple(map(float, fn.coeffs))
+        p = _horner(cs)
+        return FamilySpec(None, (), lambda z: jnp.exp(lam * z) * p(z), fn)
+    if isinstance(fn, Trigonometric):
+        om, ph = float(fn.omega), float(fn.phi)
+        trig = jnp.cos if fn.kind == "cos" else jnp.sin
+        return FamilySpec(None, (), lambda z: trig(om * z + ph), fn)
+    if isinstance(fn, ExpRational):
+        lam, c = float(fn.lam), float(fn.c)
+        return FamilySpec(None, (), lambda z: jnp.exp(lam * z) / (z + c), fn)
+    if isinstance(fn, AnyFn):
+        return FamilySpec(None, (), fn.fn, fn)
+    if isinstance(fn, CordialFn):
+        return FamilySpec(None, (), fn, fn)
+    if callable(fn):  # plain traceable callable: wrap for the host path
+        return FamilySpec(None, (), fn, AnyFn(fn))
+    raise TypeError(f"cannot build a FamilySpec from {type(fn).__name__}")

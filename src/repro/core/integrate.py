@@ -1,6 +1,7 @@
-"""Tree-field integration: BTFI oracle, recursive FTFI, ExpMP, and the plan
-data (compile_plan). The jit plan *executor* lives in repro.core.engines.plan;
-the public entry point is repro.core.engines.Integrator.
+"""Tree-field integration on the host: the BTFI oracle, the recursive FTFI
+and ExpMP (numpy references the tests compare against), and the plan
+assembly that `repro.ftfi.build` (`core/plan_api.build`) turns into a
+`PlanSpec`. The jit plan executor is `plan_api._execute`.
 
 Correctness invariant (proved in comments below, tested in tests/test_core.py):
 the *additive* decomposition counts every ordered pair (v, j) exactly once.
@@ -25,15 +26,12 @@ against the dense BTFI oracle.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Callable
 
 import numpy as np
 
-from repro.analysis import trace_guard
 from repro.core.cordial import CordialFn
 from repro.core.integrator_tree import ITNode, build_integrator_tree
-from repro.core.lru import BoundedLRU
 from repro.graphs.graph import WeightedTree
 from repro.graphs.traverse import tree_all_pairs
 
@@ -153,105 +151,11 @@ class ExpMP:
 
 
 # ----------------------------------------------------------------------------
-# Plan compilation: flatten the IT into padded, bucketed, static arrays plus
-# concatenated gather/segment/scatter index plans for the fused executor
+# Plan assembly: flatten the IT into padded, bucketed, static arrays plus
+# concatenated gather/segment/scatter index plans for the fused executor.
+# The result is the keyword set of `plan_api.PlanSpec` (`plan_api.build`
+# adds the provenance and, for reweightable builds, the reweight tables).
 # ----------------------------------------------------------------------------
-
-
-@dataclasses.dataclass
-class CrossBucket:
-    """Group-distance arrays for one size bucket, padded to the bucket maxima
-    (the cross-engine inputs). The per-vertex gather/scatter plumbing lives in
-    the flat index arrays on `IntegrationPlan`; `src_off`/`tgt_off` locate
-    this bucket's (B*U) group block inside those flat layouts.
-
-    `piv` / `tgt_rep` / `src_rep` record, per job row, the pivot vertex and
-    one representative vertex per distance group (padding repeats the
-    pivot, whose pivot-distance is 0 like the original padding). They let
-    the functional API re-derive every distance from edge weights:
-    d[b, u] = dist(piv[b], rep[b, u])."""
-
-    tgt_d: np.ndarray  # (B, U_t) float
-    tgt_d_mask: np.ndarray  # (B, U_t) bool
-    src_d: np.ndarray  # (B, U_s) float
-    src_d_mask: np.ndarray  # (B, U_s) bool
-    src_off: int = 0  # offset of this bucket's B*U_s groups in the flat X'
-    tgt_off: int = 0  # offset of this bucket's B*U_t groups in the flat cross
-    piv: np.ndarray | None = None  # (B,) pivot vertex per job row
-    tgt_rep: np.ndarray | None = None  # (B, U_t) group representative vertex
-    src_rep: np.ndarray | None = None  # (B, U_s)
-
-
-@dataclasses.dataclass
-class LeafBucket:
-    ids: np.ndarray  # (B, K)
-    mask: np.ndarray  # (B, K)
-    dists: np.ndarray  # (B, K, K)
-
-
-@dataclasses.dataclass
-class IntegrationPlan:
-    """Static integration plan. Beyond the padded per-bucket engine inputs,
-    the whole executor data-flow is precompiled into four flat index arrays:
-
-      X'_flat  = segment_sum(Xpad[src_gather], src_seg)   # one gather+segsum
-      cross    = per-bucket engine on X'_flat slices       # one dispatch each
-      out     += scatter_add at tgt_scatter of cross[tgt_gather]
-
-    so `execute_plan` is a handful of fused array ops, not a Python loop
-    re-wrapping numpy arrays per bucket."""
-
-    n: int
-    cross_buckets: list
-    leaf_buckets: list
-    pivots: np.ndarray  # (P,) vertex ids, one per internal node (with repeats)
-    grid_h: float | None = None  # common distance grid (if any) for hankel engine
-    # fused executor index arrays (real entries only — no padding, no masks)
-    src_gather: np.ndarray | None = None  # (S,) vertex ids into Xpad
-    src_seg: np.ndarray | None = None  # (S,) flat source-group index
-    n_src_groups: int = 0  # sum over buckets of B*U_s
-    tgt_gather: np.ndarray | None = None  # (T,) flat cross-group index
-    tgt_scatter: np.ndarray | None = None  # (T,) vertex ids into out
-    n_tgt_groups: int = 0  # sum over buckets of B*U_t
-    num_cross_jobs: int = 0
-    # provenance (stamped by compile_plan / compile_forest_plan): the
-    # functional PlanSpec carries these across process/device boundaries
-    fingerprint: str = ""
-    leaf_size: int = 0
-    seed: int = 0
-    tree_sizes: tuple = ()
-    reweightable: bool = False
-    rw: dict | None = None  # reweight tables (LCA + root-path CSR)
-    # update tables (stamped by _assemble_plan): IT skeleton + the
-    # (bucket, row) coordinates of every cross job and leaf, so
-    # `ftfi.update_plan` can patch individual slots without re-deriving the
-    # bucketing
-    upd: dict | None = None
-
-    def num_jobs(self):
-        return self.num_cross_jobs
-
-
-_PLAN_CACHE = BoundedLRU(32)
-
-
-def clear_plan_cache() -> None:
-    """Drop cached plans AND the memos that live on them.
-
-    Plans carry their jitted-fastmult memo (`_fm_cache`) and functional
-    (spec, params) pair (`_spec_params`) so construction amortizes across
-    Integrator instances — which also means a live Integrator sharing a
-    cached plan keeps those memos alive. Clearing only the LRU would leave
-    every compiled closure (and the device arrays it pins) reachable
-    through such instances; purge the per-plan memos explicitly so a
-    cleared cache actually frees them."""
-    for _, plan in _PLAN_CACHE.items():
-        fm = getattr(plan, "_fm_cache", None)
-        if fm is not None:
-            fm.clear()
-        if getattr(plan, "_spec_params", None) is not None:
-            plan._spec_params = None
-    _PLAN_CACHE.clear()
 
 
 def _side_job_arrays(side, expand_groups: bool):
@@ -270,10 +174,10 @@ def _side_job_arrays(side, expand_groups: bool):
 
 
 def _upd_tables(flat, job_bucket, job_row, leaf_bucket, leaf_row) -> dict:
-    """Update tables shared by both assembly paths: the IT skeleton
-    (children refs + per-tree roots) and the (bucket, row) coordinate of
-    every cross job / leaf, which is all `ftfi.update_plan` needs to walk a
-    vertex's IT chain and patch the affected slots in place."""
+    """Update tables: the IT skeleton (children refs + per-tree roots) and
+    the (bucket, row) coordinate of every cross job / leaf, which is all
+    `ftfi.update_plan` needs to walk a vertex's IT chain and patch the
+    affected slots in place. `ghosts` starts empty."""
     root_refs = (flat.root_refs if flat.root_refs is not None
                  else np.array([flat.root_ref], np.int64))
     # int32 end-to-end: node refs are bounded by the node count, and every
@@ -284,149 +188,26 @@ def _upd_tables(flat, job_bucket, job_row, leaf_bucket, leaf_row) -> dict:
             "job_bucket": np.asarray(job_bucket, np.int32),
             "job_row": np.asarray(job_row, np.int32),
             "leaf_bucket": np.asarray(leaf_bucket, np.int32),
-            "leaf_row": np.asarray(leaf_row, np.int32)}
-
-
-def _assemble_plan_ref(flat, n: int, detect_grid_spacing: bool,
-                       expand_groups: bool = False) -> IntegrationPlan:
-    """Reference (per-node Python loop) plan assembly. Kept as the oracle
-    the vectorized `_assemble_plan` is tested bitwise-equal against; all
-    production paths go through the vectorized assembly."""
-    # one job per (node, direction): targets/sources both exclude the pivot
-    # (masked-source optimization); distance arrays keep the pivot group 0
-    jobs = []
-    for i in range(flat.num_internal):
-        L, R = flat.left[i], flat.right[i]
-        piv = int(L.ids[0])
-        for t, s in ((L, R), (R, L)):
-            t_ids, t_idd, t_d, t_rep = _side_job_arrays(t, expand_groups)
-            s_ids, s_idd, s_d, s_rep = _side_job_arrays(s, expand_groups)
-            jobs.append((t_ids[1:], t_idd[1:], t_d, s_ids[1:], s_idd[1:],
-                         s_d, t_rep, s_rep, piv))
-
-    # --- bucket cross jobs by ceil(log2(max dim)) => <=2x padding waste
-    def bkey(job):
-        m = max(job[0].size, job[3].size, 2)
-        return int(np.ceil(np.log2(m)))
-
-    buckets: dict[int, list] = {}
-    for ji, job in enumerate(jobs):
-        buckets.setdefault(bkey(job), []).append((ji, job))
-
-    job_bucket = np.zeros(len(jobs), np.int32)
-    job_row = np.zeros(len(jobs), np.int32)
-    cross_buckets = []
-    src_gather_parts, src_seg_parts = [], []
-    tgt_gather_parts, tgt_scatter_parts = [], []
-    src_goff = tgt_goff = 0
-    for bi, key_b in enumerate(sorted(buckets)):
-        bjobs = buckets[key_b]
-        Ut = max(j[2].size for _, j in bjobs)
-        Us = max(j[5].size for _, j in bjobs)
-        B = len(bjobs)
-        cb = CrossBucket(
-            tgt_d=np.zeros((B, Ut), dtype=np.float64),
-            tgt_d_mask=np.zeros((B, Ut), dtype=bool),
-            src_d=np.zeros((B, Us), dtype=np.float64),
-            src_d_mask=np.zeros((B, Us), dtype=bool),
-            src_off=src_goff, tgt_off=tgt_goff,
-        )
-        if expand_groups:  # only reweightable builds consume rep tables
-            cb.piv = np.zeros(B, dtype=np.int32)
-            cb.tgt_rep = np.zeros((B, Ut), dtype=np.int32)
-            cb.src_rep = np.zeros((B, Us), dtype=np.int32)
-        for b, (ji, (t_ids, t_idd, t_d, s_ids, s_idd, s_d, t_rep, s_rep,
-                     piv)) in enumerate(bjobs):
-            job_bucket[ji] = bi
-            job_row[ji] = b
-            cb.tgt_d[b, :t_d.size] = t_d
-            cb.tgt_d_mask[b, :t_d.size] = True
-            cb.src_d[b, :s_d.size] = s_d
-            cb.src_d_mask[b, :s_d.size] = True
-            if expand_groups:
-                cb.piv[b] = piv
-                cb.tgt_rep[b, :] = piv  # padding: dist(piv, piv) == 0
-                cb.tgt_rep[b, :t_rep.size] = t_rep
-                cb.src_rep[b, :] = piv
-                cb.src_rep[b, :s_rep.size] = s_rep
-            src_gather_parts.append(s_ids)
-            src_seg_parts.append(src_goff + b * Us + s_idd)
-            tgt_gather_parts.append(tgt_goff + b * Ut + t_idd)
-            tgt_scatter_parts.append(t_ids)
-        src_goff += B * Us
-        tgt_goff += B * Ut
-        cross_buckets.append(cb)
-
-    def _cat(parts, dtype):
-        return (np.concatenate(parts).astype(dtype) if parts
-                else np.zeros(0, dtype))
-
-    # --- leaf buckets by ceil(log2(k)): a mixed-size forest pads each leaf
-    # to its size class, not to the global maximum (K^2 padding waste would
-    # dominate leaf-heavy forest plans)
-    leaf_groups: dict[int, list] = {}
-    for li, (ids, D) in enumerate(zip(flat.leaf_ids, flat.leaf_dists)):
-        leaf_groups.setdefault(
-            int(np.ceil(np.log2(max(ids.size, 2)))), []).append((li, ids, D))
-    leaf_bucket = np.zeros(len(flat.leaf_ids), np.int32)
-    leaf_row = np.zeros(len(flat.leaf_ids), np.int32)
-    leaf_buckets = []
-    for bi, key_b in enumerate(sorted(leaf_groups)):
-        leaves = leaf_groups[key_b]
-        K = max(ids.size for _, ids, _ in leaves)
-        B = len(leaves)
-        lb = LeafBucket(
-            ids=np.full((B, K), n, dtype=np.int32),
-            mask=np.zeros((B, K), dtype=bool),
-            dists=np.zeros((B, K, K), dtype=np.float64),
-        )
-        for b, (li, ids, D) in enumerate(leaves):
-            leaf_bucket[li] = bi
-            leaf_row[li] = b
-            k = ids.size
-            lb.ids[b, :k] = ids
-            lb.mask[b, :k] = True
-            lb.dists[b, :k, :k] = D
-        leaf_buckets.append(lb)
-
-    h = None
-    if detect_grid_spacing:
-        from repro.core.cordial import detect_grid
-        # one detection over the merged distances reconciles per-tree grids:
-        # the common h of a forest is the gcd of its trees' spacings (None if
-        # any tree is off-grid or the joint span is FFT-impractical)
-        all_d = np.unique(np.concatenate(
-            [s.d for i in range(flat.num_internal)
-             for s in (flat.left[i], flat.right[i])] or [np.zeros(1)]))
-        h = detect_grid(all_d, np.zeros(1))
-    return IntegrationPlan(
-        n=n, cross_buckets=cross_buckets, leaf_buckets=leaf_buckets,
-        pivots=flat.pivots.astype(np.int32), grid_h=h,
-        src_gather=_cat(src_gather_parts, np.int32),
-        src_seg=_cat(src_seg_parts, np.int32),
-        n_src_groups=src_goff,
-        tgt_gather=_cat(tgt_gather_parts, np.int32),
-        tgt_scatter=_cat(tgt_scatter_parts, np.int32),
-        n_tgt_groups=tgt_goff,
-        num_cross_jobs=len(jobs),
-        upd=_upd_tables(flat, job_bucket, job_row, leaf_bucket, leaf_row),
-    )
+            "leaf_row": np.asarray(leaf_row, np.int32),
+            "ghosts": np.zeros(0, np.int32)}
 
 
 def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
-                   expand_groups: bool = False) -> IntegrationPlan:
-    """Flatten a (tree or forest) FlatIT into one IntegrationPlan: cross jobs
-    and leaves from EVERY tree share one global index space and are merged
-    into the same size-class buckets, so the executor's dispatch count is a
-    function of size diversity, not of how many trees the plan covers.
+                   expand_groups: bool = False) -> dict:
+    """Flatten a (tree or forest) FlatIT into the executor's arrays: cross
+    jobs and leaves from EVERY tree share one global index space and are
+    merged into the same size-class buckets, so the executor's dispatch
+    count is a function of size diversity, not of how many trees the plan
+    covers. Returns `PlanSpec` keywords: per-bucket tuples (`cross_*`,
+    `leaf_*`), the four flat executor index arrays, `grid_h` and the
+    update tables; `cross_piv` / `cross_*_rep` only when `expand_groups`.
 
-    Vectorized: the per-internal-node Python loop, per-job tuple appends and
-    dict-of-lists bucketing of `_assemble_plan_ref` are replaced by array
-    ops over the IT's concatenated side CSR (`FlatIT.side_cat` /
-    `leaf_cat`) — one stable argsort groups jobs into size-class buckets,
-    `np.maximum.reduceat` yields the bucket maxima, and every padded bucket
-    array plus all four flat executor index arrays fill through `_ranges`
-    scatters, bitwise-identical to the reference output (tested)."""
+    Vectorized: array ops over the IT's concatenated side CSR
+    (`FlatIT.side_cat` / `leaf_cat`) — one stable argsort groups jobs into
+    size-class buckets, `np.maximum.reduceat` yields the bucket maxima, and
+    every padded bucket array plus all four flat executor index arrays
+    fill through `_ranges` scatters, bitwise-identical to the per-node loop
+    oracle of tests/test_plan_assembly.py."""
     from repro.core.itree_flat import _ranges
 
     num_i = flat.num_internal
@@ -441,7 +222,9 @@ def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
     g = k if expand_groups else u  # distance-group count per side (incl piv)
     mem = k - 1  # member count per side (targets/sources exclude the pivot)
 
-    cross_buckets = []
+    cb = {key: [] for key in ("tgt_d", "tgt_mask", "src_d", "src_mask",
+                              "src_off", "tgt_off", "piv", "tgt_rep",
+                              "src_rep")}
     job_bucket = np.zeros(J, np.int32)
     job_row = np.zeros(J, np.int32)
     src_gather = src_seg = tgt_gather = tgt_scatter = np.zeros(0, np.int64)
@@ -474,36 +257,38 @@ def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
             hi = lo + int(bcounts[bi])
             js, ss = order[lo:hi], sib[lo:hi]
             B, Utb, Usb = hi - lo, int(Ut[bi]), int(Us[bi])
-            cb = CrossBucket(
-                tgt_d=np.zeros((B, Utb), dtype=np.float64),
-                tgt_d_mask=np.zeros((B, Utb), dtype=bool),
-                src_d=np.zeros((B, Usb), dtype=np.float64),
-                src_d_mask=np.zeros((B, Usb), dtype=bool),
-                src_off=int(src_off[bi]), tgt_off=int(tgt_off[bi]),
-            )
+            tgt_d = np.zeros((B, Utb), dtype=np.float64)
+            tgt_mask = np.zeros((B, Utb), dtype=bool)
+            src_d = np.zeros((B, Usb), dtype=np.float64)
+            src_mask = np.zeros((B, Usb), dtype=bool)
             gt, gs = g[js], g[ss]
             rt = np.repeat(np.arange(B), gt)
             ct = _ranges(np.zeros(B, np.int64), gt)
             rs = np.repeat(np.arange(B), gs)
             cs = _ranges(np.zeros(B, np.int64), gs)
             if expand_groups:
-                cb.tgt_d[rt, ct] = dvert[_ranges(kptr[js], gt)]
-                cb.src_d[rs, cs] = dvert[_ranges(kptr[ss], gs)]
+                tgt_d[rt, ct] = dvert[_ranges(kptr[js], gt)]
+                src_d[rs, cs] = dvert[_ranges(kptr[ss], gs)]
             else:
-                cb.tgt_d[rt, ct] = d_c[_ranges(uptr[js], gt)]
-                cb.src_d[rs, cs] = d_c[_ranges(uptr[ss], gs)]
-            cb.tgt_d_mask[rt, ct] = True
-            cb.src_d_mask[rs, cs] = True
+                tgt_d[rt, ct] = d_c[_ranges(uptr[js], gt)]
+                src_d[rs, cs] = d_c[_ranges(uptr[ss], gs)]
+            tgt_mask[rt, ct] = True
+            src_mask[rs, cs] = True
+            cb["tgt_d"].append(tgt_d)
+            cb["tgt_mask"].append(tgt_mask)
+            cb["src_d"].append(src_d)
+            cb["src_mask"].append(src_mask)
+            cb["src_off"].append(int(src_off[bi]))
+            cb["tgt_off"].append(int(tgt_off[bi]))
             if expand_groups:  # rep tables: padding repeats the pivot
                 pj = piv_job[js]
-                cb.piv = pj.astype(np.int32)
-                cb.tgt_rep = np.repeat(pj, Utb).reshape(B, Utb).astype(
-                    np.int32)
-                cb.src_rep = np.repeat(pj, Usb).reshape(B, Usb).astype(
-                    np.int32)
-                cb.tgt_rep[rt, ct] = ids_c[_ranges(kptr[js], gt)]
-                cb.src_rep[rs, cs] = ids_c[_ranges(kptr[ss], gs)]
-            cross_buckets.append(cb)
+                tgt_rep = np.repeat(pj, Utb).reshape(B, Utb).astype(np.int32)
+                src_rep = np.repeat(pj, Usb).reshape(B, Usb).astype(np.int32)
+                tgt_rep[rt, ct] = ids_c[_ranges(kptr[js], gt)]
+                src_rep[rs, cs] = ids_c[_ranges(kptr[ss], gs)]
+                cb["piv"].append(pj.astype(np.int32))
+                cb["tgt_rep"].append(tgt_rep)
+                cb["src_rep"].append(src_rep)
         src_goff, tgt_goff = int(src_off[-1]), int(tgt_off[-1])
 
         # flat executor arrays in (bucket, job) order — one concatenation
@@ -530,7 +315,7 @@ def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
     Lf = lk.size
     leaf_bucket = np.zeros(Lf, np.int32)
     leaf_row = np.zeros(Lf, np.int32)
-    leaf_buckets = []
+    leaf_ids, leaf_mask, leaf_dists = [], [], []
     if Lf:
         lkey = np.ceil(np.log2(np.maximum(lk, 2))).astype(np.int64)
         lorder = np.argsort(lkey, kind="stable")
@@ -542,23 +327,23 @@ def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
             lv = lorder[int(lstarts[bi]):int(lstarts[bi]) + int(lcounts[bi])]
             ks = lk[lv]
             B, K = lv.size, int(ks.max())
-            lb = LeafBucket(
-                ids=np.full((B, K), n, dtype=np.int32),
-                mask=np.zeros((B, K), dtype=bool),
-                dists=np.zeros((B, K, K), dtype=np.float64),
-            )
+            ids = np.full((B, K), n, dtype=np.int32)
+            mask = np.zeros((B, K), dtype=bool)
+            dists = np.zeros((B, K, K), dtype=np.float64)
             r = np.repeat(np.arange(B), ks)
             c = _ranges(np.zeros(B, np.int64), ks)
-            lb.ids[r, c] = lc["ids"][_ranges(lptr[lv], ks)]
-            lb.mask[r, c] = True
+            ids[r, c] = lc["ids"][_ranges(lptr[lv], ks)]
+            mask[r, c] = True
             # raveled (row, col) targets of every k_i x k_i block at once
             pw = _ranges(np.zeros(B, np.int64), ks * ks)
             kk = np.repeat(ks, ks * ks)
             pos = (np.repeat(np.arange(B) * K * K, ks * ks)
                    + (pw // kk) * K + pw % kk)
-            lb.dists.reshape(-1)[pos] = lc["dflat"][_ranges(ldptr[lv],
-                                                            ks * ks)]
-            leaf_buckets.append(lb)
+            dists.reshape(-1)[pos] = lc["dflat"][_ranges(ldptr[lv],
+                                                         ks * ks)]
+            leaf_ids.append(ids)
+            leaf_mask.append(mask)
+            leaf_dists.append(dists)
 
     h = None
     if detect_grid_spacing:
@@ -568,9 +353,20 @@ def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
         # any tree is off-grid or the joint span is FFT-impractical)
         all_d = np.unique(d_c) if d_c.size else np.zeros(1)
         h = detect_grid(all_d, np.zeros(1))
-    return IntegrationPlan(
-        n=n, cross_buckets=cross_buckets, leaf_buckets=leaf_buckets,
-        pivots=flat.pivots.astype(np.int32), grid_h=h,
+    rep = ({"cross_piv": tuple(cb["piv"]),
+            "cross_tgt_rep": tuple(cb["tgt_rep"]),
+            "cross_src_rep": tuple(cb["src_rep"])} if expand_groups else {})
+    return dict(
+        n=n, grid_h=h,
+        cross_tgt_mask=tuple(cb["tgt_mask"]),
+        cross_src_mask=tuple(cb["src_mask"]),
+        cross_src_off=tuple(cb["src_off"]),
+        cross_tgt_off=tuple(cb["tgt_off"]),
+        cross_tgt_d0=tuple(cb["tgt_d"]),
+        cross_src_d0=tuple(cb["src_d"]),
+        leaf_ids=tuple(leaf_ids), leaf_mask=tuple(leaf_mask),
+        leaf_dists0=tuple(leaf_dists),
+        pivots=flat.pivots.astype(np.int32),
         src_gather=src_gather.astype(np.int32),
         src_seg=src_seg.astype(np.int32),
         n_src_groups=src_goff,
@@ -578,153 +374,8 @@ def _assemble_plan(flat, n: int, detect_grid_spacing: bool,
         tgt_scatter=tgt_scatter.astype(np.int32),
         n_tgt_groups=tgt_goff,
         num_cross_jobs=J,
-        upd=_upd_tables(flat, job_bucket, job_row, leaf_bucket, leaf_row),
-    )
-
-
-def _disk_cache_load(key) -> IntegrationPlan | None:
-    """Consult the disk-persistent plan cache (see repro.core.plan_cache):
-    a hit reconstructs the plan via `plan_from_spec` — one file read, zero
-    IT rebuild. Disabled (None) unless a cache directory is configured."""
-    from repro.core import plan_cache
-
-    if not plan_cache.enabled():
-        return None
-    hit = plan_cache.load(plan_cache.key_str(key))
-    if hit is None:
-        return None
-    from repro.core import plan_api
-
-    return plan_api.plan_from_spec(*hit)
-
-
-def _disk_cache_store(key, plan: IntegrationPlan) -> None:
-    from repro.core import plan_cache
-
-    if not plan_cache.enabled():
-        return
-    from repro.core import plan_api
-
-    spec, params = plan_api.specialize(plan)
-    plan_cache.store(plan_cache.key_str(key), spec, params)
-
-
-def _cached_plan(key) -> IntegrationPlan | None:
-    """The plan under `key` from the in-memory LRU, recorded in trace_guard
-    as an `integrate.plan_cache` hit or miss; on a miss, from the disk
-    cache (then kept in memory too)."""
-    hit = _PLAN_CACHE.get(key)
-    if hit is not None:
-        trace_guard.record("integrate.plan_cache", event="hit")
-        return hit
-    trace_guard.record("integrate.plan_cache", event="miss")
-    hit = _disk_cache_load(key)
-    if hit is not None:
-        _PLAN_CACHE.put(key, hit)
-    return hit
-
-
-def compile_plan(tree: WeightedTree, leaf_size: int = 64, seed: int = 0,
-                 detect_grid_spacing: bool = True, use_cache: bool = True,
-                 reweightable: bool = False) -> IntegrationPlan:
-    """Compile (or fetch from the content-hash cache) the integration plan.
-
-    Plans are immutable after construction, so repeated `Integrator`
-    construction over the same topology (serving, benchmarks, ViT mask
-    rebuilds) amortizes to a dict lookup. `seed` is part of the cache key:
-    differently-seeded builds must never alias to the first build.
-
-    Cache hierarchy: in-memory BoundedLRU first, then (when the
-    `FTFI_PLAN_CACHE` directory is configured) the disk-persistent artifact
-    cache — so cold *process* starts over a known topology pay one npz read
-    instead of an O(N log N) decomposition. `use_cache=False` bypasses both.
-
-    `reweightable=True` expands distance groups to per-vertex slots, skips
-    grid detection (an integer grid would not survive weight training) and
-    attaches the LCA / root-path tables `ftfi.reweight` re-derives
-    distances from."""
-    from repro.core.itree_flat import build_flat_it, tree_fingerprint
-
-    if reweightable:
-        detect_grid_spacing = False
-    with trace_guard.span("ftfi.build.fingerprint"):
-        fp = tree_fingerprint(tree)
-    if use_cache:
-        key = (fp, max(int(leaf_size), 6), int(seed), detect_grid_spacing,
-               reweightable)
-        hit = _cached_plan(key)
-        if hit is not None:
-            return hit
-
-    with trace_guard.span("ftfi.build.decompose"):
-        flat = build_flat_it(tree, leaf_size=leaf_size, seed=seed,
-                             use_cache=use_cache)
-    with trace_guard.span("ftfi.build.assemble"):
-        plan = _assemble_plan(flat, tree.num_vertices, detect_grid_spacing,
-                              expand_groups=reweightable)
-    plan.fingerprint = fp
-    plan.leaf_size = max(int(leaf_size), 6)
-    plan.seed = int(seed)
-    plan.tree_sizes = (tree.num_vertices,)
-    plan.reweightable = reweightable
-    if reweightable:
-        _attach_reweight_tables(plan, [tree])
-    if use_cache:
-        _PLAN_CACHE.put(key, plan)
-        _disk_cache_store(key, plan)
-    return plan
-
-
-def compile_forest_plan(forest, leaf_size: int = 64, seed: int = 0,
-                        detect_grid_spacing: bool = True,
-                        use_cache: bool = True,
-                        reweightable: bool = False) -> IntegrationPlan:
-    """Compile a whole `Forest` into ONE IntegrationPlan.
-
-    Per-tree plans are never materialized: the batched flat-IT build decomposes
-    all trees in one level sweep, and `_assemble_plan` concatenates their cross
-    jobs and leaves into a single global index space (shared `src_gather` /
-    `src_seg` / `tgt_gather` / `tgt_scatter`, buckets merged across trees by
-    size class, grid_h reconciled over the merged distances). `execute_plan`
-    then runs the ENTIRE forest as the same handful of fused gather /
-    segment-sum / scatter ops — one jit dispatch for N graphs instead of N.
-
-    The packed field layout is `Forest`'s: vertex v of tree t at row
-    `forest.offsets[t] + v`; the multiply is block-diagonal by construction
-    (no index from one tree ever references another tree's rows)."""
-    import hashlib
-
-    from repro.core.itree_flat import build_flat_forest, tree_fingerprint
-
-    if reweightable:
-        detect_grid_spacing = False
-    with trace_guard.span("ftfi.build.fingerprint"):
-        fps = tuple(tree_fingerprint(t) for t in forest.trees)
-    if use_cache:
-        key = ("forest", fps, max(int(leaf_size), 6), int(seed),
-               detect_grid_spacing, reweightable)
-        hit = _cached_plan(key)
-        if hit is not None:
-            return hit
-
-    with trace_guard.span("ftfi.build.decompose"):
-        flat = build_flat_forest(forest.trees, leaf_size=leaf_size,
-                                 seed=seed, use_cache=use_cache)
-    with trace_guard.span("ftfi.build.assemble"):
-        plan = _assemble_plan(flat, forest.num_vertices, detect_grid_spacing,
-                              expand_groups=reweightable)
-    plan.fingerprint = hashlib.sha1(
-        "".join(fps).encode()).hexdigest()
-    plan.leaf_size = max(int(leaf_size), 6)
-    plan.seed = int(seed)
-    plan.tree_sizes = tuple(int(s) for s in forest.tree_sizes)
-    plan.reweightable = reweightable
-    if reweightable:
-        _attach_reweight_tables(plan, forest.trees)
-    if use_cache:
-        _PLAN_CACHE.put(key, plan)
-        _disk_cache_store(key, plan)
-    return plan
+        **rep,
+        **_upd_tables(flat, job_bucket, job_row, leaf_bucket, leaf_row))
 
 
 # ----------------------------------------------------------------------------
@@ -782,31 +433,34 @@ def _forest_lca_query(lcas, offsets, u, v):
     return out.reshape(shape)
 
 
-def _attach_reweight_tables(plan: IntegrationPlan, trees) -> None:
-    """Stamp the LCA tables (cross + leaf) and root-path CSR onto the plan:
-    with these, every distance slot is depth[u] + depth[v] - 2 depth[lca],
-    a pure (differentiable) function of the edge weights."""
+def _reweight_tables(fields: dict, trees) -> dict:
+    """The LCA tables (cross + leaf) and root-path CSR of an expanded-group
+    assembly (`_assemble_plan(..., expand_groups=True)`), as `PlanSpec`
+    keywords: with these, every distance slot is depth[u] + depth[v] -
+    2 depth[lca], a pure (differentiable) function of the edge weights."""
     from repro.graphs.traverse import TreeLCA
 
     sizes = np.array([t.num_vertices for t in trees], np.int64)
     offsets = np.zeros(sizes.size + 1, np.int64)
     np.cumsum(sizes, out=offsets[1:])
     lcas = [TreeLCA(t) for t in trees]
-    n = plan.n
+    n = fields["n"]
 
     ctl, csl = [], []
-    for cb in plan.cross_buckets:
-        pv_t = np.broadcast_to(cb.piv[:, None], cb.tgt_rep.shape)
-        pv_s = np.broadcast_to(cb.piv[:, None], cb.src_rep.shape)
+    for piv, tgt_rep, src_rep in zip(fields["cross_piv"],
+                                     fields["cross_tgt_rep"],
+                                     fields["cross_src_rep"]):
+        pv_t = np.broadcast_to(piv[:, None], tgt_rep.shape)
+        pv_s = np.broadcast_to(piv[:, None], src_rep.shape)
         ctl.append(_forest_lca_query(lcas, offsets, pv_t,
-                                     cb.tgt_rep).astype(np.int32))
+                                     tgt_rep).astype(np.int32))
         csl.append(_forest_lca_query(lcas, offsets, pv_s,
-                                     cb.src_rep).astype(np.int32))
+                                     src_rep).astype(np.int32))
     ll = []
-    for lb in plan.leaf_buckets:
-        B, K = lb.ids.shape
-        u = np.broadcast_to(lb.ids[:, :, None].astype(np.int64), (B, K, K))
-        v = np.broadcast_to(lb.ids[:, None, :].astype(np.int64), (B, K, K))
+    for ids in fields["leaf_ids"]:
+        B, K = ids.shape
+        u = np.broadcast_to(ids[:, :, None].astype(np.int64), (B, K, K))
+        v = np.broadcast_to(ids[:, None, :].astype(np.int64), (B, K, K))
         valid = (u < n) & (v < n)
         out = np.full((B, K, K), n, np.int64)  # pad -> sentinel depth row
         if valid.any():
@@ -821,17 +475,12 @@ def _attach_reweight_tables(plan: IntegrationPlan, trees) -> None:
         eu_parts.append(t.edges_u.astype(np.int64) + off)
         ev_parts.append(t.edges_v.astype(np.int64) + off)
         ew_parts.append(t.weights.astype(np.float64))
-    plan.rw = {"cross_tgt_lca": ctl, "cross_src_lca": csl, "leaf_lca": ll,
-               "path_rows": rows, "path_edges": edges,
-               "num_edges": int(sum(t.num_edges for t in trees)),
-               "edges_u": (np.concatenate(eu_parts).astype(np.int32)
-                           if eu_parts else np.zeros(0, np.int32)),
-               "edges_v": (np.concatenate(ev_parts).astype(np.int32)
-                           if ev_parts else np.zeros(0, np.int32)),
-               "edge_w0": (np.concatenate(ew_parts)
-                           if ew_parts else np.zeros(0, np.float64))}
-
-
-# The jax plan *executor* lives in repro.core.engines.plan (execute_plan and
-# the batched structured-multiply engines); this module owns only the host-side
-# integrators and the plan *data* (compile_plan).
+    return {"cross_tgt_lca": tuple(ctl), "cross_src_lca": tuple(csl),
+            "leaf_lca": tuple(ll), "path_rows": rows, "path_edges": edges,
+            "num_edges": int(sum(t.num_edges for t in trees)),
+            "edges_u": (np.concatenate(eu_parts).astype(np.int32)
+                        if eu_parts else np.zeros(0, np.int32)),
+            "edges_v": (np.concatenate(ev_parts).astype(np.int32)
+                        if ev_parts else np.zeros(0, np.int32)),
+            "edge_w0": (np.concatenate(ew_parts)
+                        if ew_parts else np.zeros(0, np.float64))}

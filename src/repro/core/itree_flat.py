@@ -31,7 +31,7 @@ forest layout) and ONE frontier loop decomposes all trees' levels together —
 90 small graphs cost the same handful of numpy passes as one graph.
 
 Results are cached per (content hash, leaf_size, seed) in one shared
-BoundedLRU for trees and forests: repeated Integrator construction over the
+BoundedLRU for trees and forests: repeated plan builds over the
 same topology (serving, benchmarks, ViT mask rebuilds) amortizes to a dict
 lookup. `seed` must be part of the key even though the current builder is
 deterministic — a seeded builder variant must never alias differently-seeded

@@ -154,13 +154,12 @@ class ResilientFastMult:
         self._idx = 0
 
     def _jit_ok(self) -> bool:
-        # mirror PlanBackend._jit_ok: only the structured concrete-float
-        # families are safe to jit from here; everything else runs eagerly
-        # (still traceable inline by an enclosing jit)
+        # only the structured concrete-float families are safe to jit from
+        # here; everything else runs eagerly (still traceable inline by an
+        # enclosing jit)
         from repro.core import cordial as C
-        from repro.core.engines.spec import FamilySpec
 
-        if isinstance(self.fn, FamilySpec):
+        if isinstance(self.fn, C.FamilySpec):
             return self.fn.mode is not None
         return (isinstance(self.fn, C.CordialFn)
                 and not isinstance(self.fn, C.AnyFn)

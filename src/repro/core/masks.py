@@ -107,7 +107,7 @@ def chebyshev_separable_tables(g: str, coeffs, L: int, dist_scale: float = 1.0,
 
     Returns (alpha (..., L, rank), beta (..., L, rank))."""
     nodes, Bmat = chebyshev_separable_expansion(g, coeffs, L, dist_scale, rank)
-    from repro.core.engines.plan import _lagrange_batched
+    from repro.core.plan_api import _lagrange_batched
     pos = np.arange(L, dtype=np.float32)
     Lg = _lagrange_batched(pos[None, :], nodes[None, :])[0]  # (L, r)
     Lg = jnp.asarray(Lg, jnp.float32)
@@ -181,6 +181,8 @@ def make_sequence_fastmult(g: str, coeffs, L: int, causal: bool,
 
 
 _TREE_FM_CACHE = BoundedLRU(64)
+# all-pairs distance matrices of the dense path and of the ViT `ref` impl,
+# keyed by tree fingerprint or by (spec digest, params id)
 _TREE_DIST_CACHE = BoundedLRU(8)
 
 # Largest single tree whose mask FastMult is one dense f32 product by
@@ -193,26 +195,13 @@ N_DENSE = 4096
 
 
 def _purge_dead_tree_fm_entries():
-    """Drop entries whose Integrator has been garbage collected: their
-    id-based key can never hit again, and keeping them would pin the plan
-    arrays and compiled closures of dead integrators. Peeks (no recency
-    promotion) so the scan doesn't scramble LRU eviction order."""
+    """Drop entries whose spec has been garbage collected: their id-based
+    key can never hit again, and keeping them would pin the plan arrays and
+    compiled closures of dead plans. Peeks (no recency promotion) so the
+    scan doesn't scramble LRU eviction order."""
     for key, entry in _TREE_FM_CACHE.items():
         if entry[1]() is None:
             _TREE_FM_CACHE.discard(key)
-
-
-def _resolve_plan_handle(integrator):
-    """(impl, spec, params) for an `Integrator` facade, a raw backend, or a
-    functional (spec, params) pair. `impl` is the object whose
-    (non-deprecated, memoizing) `fastmult` the mask closure rides; it is
-    None for the pure-pair form, which executes through `plan_api.fastmult`
-    directly."""
-    if isinstance(integrator, (tuple, list)) and len(integrator) == 2:
-        spec, params = integrator
-        return None, spec, params
-    impl = getattr(integrator, "_impl", integrator)
-    return (impl, getattr(impl, "spec", None), getattr(impl, "params", None))
 
 
 def _has_tracer(tree) -> bool:
@@ -220,67 +209,85 @@ def _has_tracer(tree) -> bool:
                for leaf in jax.tree_util.tree_leaves(tree))
 
 
-def _takes_dense(spec, params, *, use_shard: bool = False,
+def _takes_dense(spec, *, use_shard: bool = False,
                  params_traced: bool = False) -> bool:
     """The dense path's condition: a single tree of at most N_DENSE
     vertices, concrete params, no sharding."""
-    return (not use_shard and not params_traced and spec is not None
-            and params is not None and spec.num_trees == 1
+    return (not use_shard and not params_traced and spec.num_trees == 1
             and spec.n <= N_DENSE)
 
 
-def tree_fastmult_path(integrator) -> str:
-    """"dense" or "plan": the path `make_tree_fastmult` takes over
-    `integrator` (unsharded, with its own concrete params)."""
-    _, spec, params = _resolve_plan_handle(integrator)
-    return "dense" if _takes_dense(spec, params) else "plan"
+def tree_fastmult_path(plan) -> str:
+    """"dense" or "plan": the path `make_tree_fastmult` takes over the
+    `(spec, params)` pair `plan` (unsharded, with concrete params)."""
+    return "dense" if _takes_dense(plan[0]) else "plan"
 
 
-def _tree_distances(impl, spec, params) -> np.ndarray:
-    """(n, n) f32 all-pairs distances of a single-tree plan, computed once
-    per (spec, params) on the host. From the backend's own tree where it
-    has one (`tree_all_pairs`); otherwise (a `(spec, params)` pair, a
-    loaded plan, reweighted params) from the plan itself: the executor with
-    f(s) = s, the exact polynomial engine, applied to the identity field.
-    Per-tree output weights are left out: they scale the output, not D."""
+def tree_distances(tree) -> np.ndarray:
+    """(n, n) f32 exact all-pairs distances of `tree` (`tree_all_pairs`),
+    computed once per tree content on the host."""
+    from repro.core.itree_flat import tree_fingerprint
+
+    key = tree_fingerprint(tree)
+    D = _TREE_DIST_CACHE.get(key)
+    if D is None:
+        from repro.graphs.traverse import tree_all_pairs
+
+        D = np.asarray(tree_all_pairs(tree), np.float32)
+        _TREE_DIST_CACHE.put(key, D)
+    return D
+
+
+def _plan_distances(spec, params) -> np.ndarray:
+    """(n, n) f32 all-pairs distances of a single-tree plan, derived from
+    the plan itself (a loaded plan, reweighted params, no tree at hand):
+    the executor with f(s) = s, the exact polynomial engine, applied to the
+    identity field. Per-tree output weights are left out: they scale the
+    output, not D."""
     key = (spec.digest, id(params))
     hit = _TREE_DIST_CACHE.get(key)
     if hit is not None and hit[1]() is params:
         return hit[0]
-    tree = getattr(impl, "tree", None)
-    if tree is not None:
-        from repro.graphs.traverse import tree_all_pairs
+    from repro.core import cordial, plan_api
 
-        D = tree_all_pairs(tree)
-    else:
-        from repro.core import cordial, plan_api
-
-        unweighted = dataclasses.replace(params, tree_w=None)
-        # may run inside an enclosing trace: evaluate now, stage nothing
-        with jax.ensure_compile_time_eval():
-            D = jax.jit(plan_api.fastmult(spec, cordial.Polynomial(
-                (0.0, 1.0))))(unweighted, jnp.eye(spec.n, dtype=jnp.float32))
+    unweighted = dataclasses.replace(params, tree_w=None)
+    # may run inside an enclosing trace: evaluate now, stage nothing
+    with jax.ensure_compile_time_eval():
+        D = jax.jit(plan_api.fastmult(spec, cordial.Polynomial(
+            (0.0, 1.0))))(unweighted, jnp.eye(spec.n, dtype=jnp.float32))
     D = np.asarray(D, np.float32)
     _TREE_DIST_CACHE.put(key, (D, weakref.ref(params)))
     return D
 
 
-def make_tree_fastmult(integrator, g: str, coeffs,
-                       dist_scale: float = 1.0, *, sharded: bool = False,
-                       mesh=None) -> Callable:
+def _built_from(tree, spec) -> bool:
+    """True when `spec` is the plan of `tree` (same content fingerprint)."""
+    if tree is None:
+        return False
+    from repro.core.itree_flat import tree_fingerprint
+
+    return tree_fingerprint(tree) == spec.fingerprint
+
+
+def make_tree_fastmult(plan, g: str, coeffs, dist_scale: float = 1.0, *,
+                       backend: str = "plan", tree=None,
+                       sharded: bool = False, mesh=None) -> Callable:
     """FastMult_M for M = [f(dist_T(i,j))] over a tree's plan.
 
     Works on fields with arbitrary leading batch/head axes (..., L, c).
-    `integrator` is a repro.core.engines.Integrator (any backend with a
-    jit-able fastmult, i.e. plan or pallas) OR a functional `(spec, params)`
-    pair from `ftfi.build` / `ftfi.load_plan`. One of two paths runs,
-    chosen from what the closure can observe:
+    `plan` is a functional `(spec, params)` pair from `ftfi.build` /
+    `ftfi.load_plan` / `ftfi.reweight`; `backend` is the executor backend
+    the caller resolved ("plan", "pallas" or "auto", as `ftfi.apply`
+    takes it). One of two paths runs, chosen from what the closure can
+    observe:
 
     - dense: a single tree (`spec.num_trees == 1`) of at most `N_DENSE`
       vertices, with concrete params and no sharding. The closure applies
       M = f(D) as one f32 contraction at `HIGHEST` precision, D the tree's
       exact all-pairs distances (computed once per plan on the host, a
-      constant of the compiled program). It is the same product the plan
+      constant of the compiled program): `tree_all_pairs` of `tree` when
+      the caller passes the tree the plan was built from, else derived
+      from the plan (`_plan_distances`). It is the same product the plan
       evaluates, with no term dropped, and exact in f32; on trees this
       small the executor's gathers and scatter-adds cost far more than
       the n x n product. M is built from `coeffs` inside the trace, so
@@ -289,7 +296,10 @@ def make_tree_fastmult(integrator, g: str, coeffs,
       0; params traced under an enclosing jit, e.g. reweighted edge
       weights; the sharded path; larger trees). The mask multiply is
       linear in the field, so every leading axis folds into the trailing
-      field dim of one plan execution.
+      field dim of one plan execution on `backend` (the sharded path
+      always runs "plan"). The mask is a callable, which no backend's
+      fdist_matvec kernel takes: its cross work runs on Hankel/Chebyshev
+      on every backend.
 
     `sharded=True` rides the multi-device shard_map executor
     (`plan_shard.sharded_fastmult`) over `mesh` (default: the active
@@ -299,40 +309,38 @@ def make_tree_fastmult(integrator, g: str, coeffs,
     pass `sharded=cfg.topo_shard_plan` unconditionally.
 
     Each bound closure records `masks.tree_fastmult:dense` or `:plan` in
-    `trace_guard`. For concrete (non-traced) coefficients the closure is
-    memoized per (integrator-or-spec, g, coeffs, dist_scale[, mesh]), so
-    repeated mask rebuilds (serving, eval loops) reuse one compiled
-    executor; traced coeffs (training under jit) bypass the cache and
-    trace inline as before."""
-    impl, p_spec, p_params = _resolve_plan_handle(integrator)
+    `trace_guard`. For concrete (non-traced) coefficients and params the
+    closure is memoized per (spec, params, backend, g, coeffs,
+    dist_scale[, mesh]), so repeated mask rebuilds (serving, eval loops)
+    reuse one compiled executor; traced coeffs or params (training under
+    jit) bypass the cache and trace inline."""
+    spec, params = plan
     if sharded and mesh is None:
         from repro.launch import sharding
 
         mesh = sharding.current_mesh()
     use_shard = (bool(sharded) and mesh is not None
-                 and int(mesh.devices.size) > 1
-                 and p_spec is not None and p_params is not None)
-    ref_target = integrator if impl is not None else p_spec
+                 and int(mesh.devices.size) > 1)
     # reweighted params may themselves be traced (training edge weights
     # under an enclosing jit): never cache a tracer-capturing closure
-    params_traced = (impl is None or use_shard) and _has_tracer(p_params)
+    params_traced = _has_tracer(params)
     traced = params_traced or _has_tracer(coeffs)
-    dense = _takes_dense(p_spec, p_params, use_shard=use_shard,
+    dense = _takes_dense(spec, use_shard=use_shard,
                          params_traced=params_traced)
+    from_tree = dense and _built_from(tree, spec)
     key = None
     if not traced:
         _purge_dead_tree_fm_entries()
         c = np.asarray(coeffs)
-        # the pair path keys on the PARAMS object too: the same spec serves
-        # many PlanParams (ftfi.reweight), and each deserves its own bound
-        # closure — the entry pins `p_params` so its id stays valid for the
+        # keyed on the PARAMS object too: the same spec serves many
+        # PlanParams (ftfi.reweight), and each deserves its own bound
+        # closure — the entry pins `params` so its id stays valid for the
         # entry's lifetime
-        key = (id(ref_target),
-               id(p_params) if (impl is None or use_shard) else None,
-               g, float(dist_scale), c.shape, c.tobytes(),
-               id(mesh) if use_shard else 0, dense)
+        key = (id(spec), id(params), backend, g, float(dist_scale),
+               c.shape, c.tobytes(), id(mesh) if use_shard else 0, dense,
+               from_tree)
         hit = _TREE_FM_CACHE.get(key)
-        if hit is not None and hit[1]() is ref_target:
+        if hit is not None and hit[1]() is spec:
             trace_guard.record("masks.tree_fastmult", event="hit")
             return hit[0]
         trace_guard.record("masks.tree_fastmult", event="miss")
@@ -340,8 +348,9 @@ def make_tree_fastmult(integrator, g: str, coeffs,
                        event="dense" if dense else "plan")
     f_eval = mask_f(g, coeffs, dist_scale)
     if dense:
-        D = _tree_distances(impl, p_spec, p_params)
-        w = p_params.tree_w
+        D = tree_distances(tree) if from_tree else _plan_distances(spec,
+                                                                   params)
+        w = params.tree_w
 
         def fastmult(X):  # X: (..., L, c)
             with jax.named_scope("ftfi.dense"):
@@ -352,53 +361,47 @@ def make_tree_fastmult(integrator, g: str, coeffs,
                                   X.astype(jnp.float32),
                                   precision=jax.lax.Precision.HIGHEST)
     else:
-        fastmult = _plan_fastmult(impl, p_spec, p_params, f_eval, traced,
+        fastmult = _plan_fastmult(spec, params, f_eval, backend, traced,
                                   mesh if use_shard else None)
 
     if key is not None:
-        try:
-            ref = weakref.ref(ref_target)
-        except TypeError:
-            ref = None
-        if ref is not None:
-            # weakly referenced: the purge above drops the entry (and the
-            # plan/closure memory it pins) once the integrator/spec dies.
-            # p_params rides along strongly so the id() in the key cannot
-            # be recycled while the entry lives (None on the impl path).
-            _TREE_FM_CACHE.put(key, (fastmult, ref, p_params))
+        # the spec is weakly referenced: the purge above drops the entry
+        # (and the closure memory it pins) once the spec dies. `params`
+        # rides along strongly so the id() in the key cannot be recycled
+        # while the entry lives.
+        _TREE_FM_CACHE.put(key, (fastmult, weakref.ref(spec), params))
     return fastmult
 
 
-def _plan_fastmult(impl, p_spec, p_params, f_eval, traced: bool,
+def _plan_fastmult(spec, params, f_eval, backend: str, traced: bool,
                    mesh) -> Callable:
     """The plan path of `make_tree_fastmult`: every leading axis of the
     field folded into the trailing field dim of one plan execution, on the
-    sharded executor when `mesh` is given."""
+    sharded executor when `mesh` is given. Each trace of the single-device
+    executor records one `masks.tree_fastmult` compile in `trace_guard`."""
     if mesh is not None:
         # multi-device path: shard_map executor over the mesh; the closure
         # pins `mesh`, so the id() in the memo key stays valid for the
         # entry's lifetime
         from repro.core import plan_shard
 
-        sfm = plan_shard.sharded_fastmult(p_spec, f_eval, mesh=mesh)
-        if traced:
-            base = lambda X: sfm(p_params, X)  # noqa: E731
-        else:
-            jfm = jax.jit(sfm)
-            base = lambda X: jfm(p_params, X)  # noqa: E731
-    elif impl is not None:
-        # backend path: the impl's fastmult memoizes/jits over ITS OWN
-        # (spec, params) through the same pure executor as plan_api.apply
-        base = impl.fastmult(f_eval)
+        fm = plan_shard.sharded_fastmult(spec, f_eval, mesh=mesh)
     else:
         from repro.core import plan_api
 
-        fm = plan_api.fastmult(p_spec, f_eval)
-        if traced:  # inside an enclosing jit: trace inline, never pin
-            base = lambda X: fm(p_params, X)  # noqa: E731
-        else:
-            jfm = jax.jit(fm)
-            base = lambda X: jfm(p_params, X)  # noqa: E731
+        fspec = plan_api._fspec(f_eval)
+        _, cross = plan_api.select_cross(spec, fspec, backend=backend)
+
+        def fm(params, X):
+            if isinstance(X, jax.core.Tracer):  # trace-time only
+                trace_guard.record("masks.tree_fastmult")
+            return plan_api._execute(spec, params, fspec.fn_eval, cross, X)
+
+    if traced:  # inside an enclosing jit: trace inline, never pin
+        base = lambda X: fm(params, X)  # noqa: E731
+    else:
+        jfm = jax.jit(fm)
+        base = lambda X: jfm(params, X)  # noqa: E731
 
     def fastmult(X):  # X: (..., L, c)
         shape = X.shape
@@ -412,23 +415,23 @@ def _plan_fastmult(impl, p_spec, p_params, f_eval, traced: bool,
     return fastmult
 
 
-def make_forest_fastmult(integrator, forest, g: str, coeffs,
-                         dist_scale: float = 1.0,
-                         tree_weights=None) -> Callable:
+def make_forest_fastmult(plan, forest, g: str, coeffs,
+                         dist_scale: float = 1.0, tree_weights=None, *,
+                         backend: str = "plan") -> Callable:
     """Per-graph FastMult over a packed `Forest` field (..., sum_t n_t, c).
 
-    `integrator` is `Integrator.from_forest(forest, ...)`: its plan is
-    block-diagonal across trees, so ONE fused execution applies each graph's
-    own mask M_t = [f(dist_{T_t}(i,j))] to its own rows — per-request
-    topological masks under serving load ride a single jit dispatch instead
-    of a Python loop over requests.
+    `plan` is `ftfi.build(forest)`: its plan is block-diagonal across
+    trees, so ONE fused execution applies each graph's own mask
+    M_t = [f(dist_{T_t}(i,j))] to its own rows — per-request topological
+    masks under serving load ride a single jit dispatch instead of a Python
+    loop over requests.
 
     `tree_weights` (K,) optionally broadcasts a per-tree coefficient onto
     each tree's output block (the multiply is linear, so scaling the output
     rows of tree t equals scaling its mask) — e.g. FRT-forest averaging
     weights or per-request temperature. Shares the concrete-coeff memo with
     `make_tree_fastmult`; traced coeffs bypass caching exactly as there."""
-    base = make_tree_fastmult(integrator, g, coeffs, dist_scale)
+    base = make_tree_fastmult(plan, g, coeffs, dist_scale, backend=backend)
     if tree_weights is None:
         return base
     w = jnp.asarray(forest.broadcast(

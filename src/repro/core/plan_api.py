@@ -1,9 +1,9 @@
 """Functional plan API: static `PlanSpec` + differentiable `PlanParams`.
 
-The legacy `IntegrationPlan` is an opaque Python object whose distances live
-in numpy CrossBuckets and whose compiled closures capture it — invisible to
-`jit`/`grad`/`vmap` and unable to cross process or device boundaries. This
-module factors every plan into
+A plan is one (spec, params) pair, built straight from the integrator
+tree's flat decomposition (`integrate._assemble_plan`), cached as it is and
+passed explicitly to every entry point, so it stays visible to
+`jit`/`grad`/`vmap` and crosses process and device boundaries:
 
   PlanSpec    hashable, static: index arrays, bucket layout, masks, grid
               metadata, provenance (content hash, seed, leaf_size) and —
@@ -47,9 +47,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis import trace_guard
-from repro.core.engines.spec import FamilySpec, spec_of
-from repro.core.integrate import (CrossBucket, IntegrationPlan, LeafBucket,
-                                  compile_forest_plan, compile_plan)
+from repro.core.cordial import FamilySpec, spec_of
+from repro.core.lru import BoundedLRU
 
 KERNEL_MODES = ("poly", "exp", "expq", "rational")
 
@@ -218,84 +217,11 @@ jax.tree_util.register_pytree_node(
     PlanSpec, lambda s: ((), s), lambda s, _: s)
 
 
-# ----------------------------------------------------------------------------
-# specialize: IntegrationPlan -> (PlanSpec, PlanParams), memoized on the plan
-# ----------------------------------------------------------------------------
-
-
-def specialize(plan: IntegrationPlan):
-    """Split a compiled `IntegrationPlan` into its functional (spec, params)
-    pair. Memoized on the plan object, so content-cached plans hand every
-    Integrator the same device arrays (one transfer per topology)."""
-    cached = getattr(plan, "_spec_params", None)
-    if cached is not None:
-        return cached
-    with trace_guard.span("ftfi.build.specialize"):
-        spec, params = _specialize(plan)
-    plan._spec_params = (spec, params)
-    return spec, params
-
-
-def _specialize(plan: IntegrationPlan):
-    rw = getattr(plan, "rw", None) or {}
-    upd = getattr(plan, "upd", None) or {}
-    spec = PlanSpec(
-        n=plan.n,
-        num_trees=max(len(plan.tree_sizes), 1),
-        tree_sizes=tuple(plan.tree_sizes) or (plan.n,),
-        leaf_size=plan.leaf_size,
-        seed=plan.seed,
-        fingerprint=plan.fingerprint,
-        grid_h=plan.grid_h,
-        reweightable=plan.reweightable,
-        cross_tgt_mask=tuple(cb.tgt_d_mask for cb in plan.cross_buckets),
-        cross_src_mask=tuple(cb.src_d_mask for cb in plan.cross_buckets),
-        cross_src_off=tuple(cb.src_off for cb in plan.cross_buckets),
-        cross_tgt_off=tuple(cb.tgt_off for cb in plan.cross_buckets),
-        cross_tgt_d0=tuple(cb.tgt_d for cb in plan.cross_buckets),
-        cross_src_d0=tuple(cb.src_d for cb in plan.cross_buckets),
-        leaf_ids=tuple(lb.ids for lb in plan.leaf_buckets),
-        leaf_mask=tuple(lb.mask for lb in plan.leaf_buckets),
-        leaf_dists0=tuple(lb.dists for lb in plan.leaf_buckets),
-        pivots=plan.pivots,
-        src_gather=plan.src_gather,
-        src_seg=plan.src_seg,
-        n_src_groups=plan.n_src_groups,
-        tgt_gather=plan.tgt_gather,
-        tgt_scatter=plan.tgt_scatter,
-        n_tgt_groups=plan.n_tgt_groups,
-        num_cross_jobs=plan.num_cross_jobs,
-        num_edges=int(rw.get("num_edges", 0)),
-        path_rows=rw.get("path_rows"),
-        path_edges=rw.get("path_edges"),
-        cross_piv=(tuple(cb.piv for cb in plan.cross_buckets)
-                   if rw else None),
-        cross_tgt_rep=(tuple(cb.tgt_rep for cb in plan.cross_buckets)
-                       if rw else None),
-        cross_tgt_lca=tuple(rw["cross_tgt_lca"]) if rw else None,
-        cross_src_rep=(tuple(cb.src_rep for cb in plan.cross_buckets)
-                       if rw else None),
-        cross_src_lca=tuple(rw["cross_src_lca"]) if rw else None,
-        leaf_lca=tuple(rw["leaf_lca"]) if rw else None,
-        children=upd.get("children"),
-        root_refs=upd.get("root_refs"),
-        job_bucket=upd.get("job_bucket"),
-        job_row=upd.get("job_row"),
-        leaf_bucket=upd.get("leaf_bucket"),
-        leaf_row=upd.get("leaf_row"),
-        edges_u=rw.get("edges_u"),
-        edges_v=rw.get("edges_v"),
-        edge_w0=rw.get("edge_w0"),
-        ghosts=np.zeros(0, np.int32) if upd else None,
-    )
-    return spec, _birth_params(spec)
-
-
 def _birth_params(spec: PlanSpec) -> PlanParams:
-    # lazy specialize may first fire INSIDE a jit trace (the engine's spec/
-    # params properties); without this guard the float64->float32
-    # canonicalization becomes a traced op and the memoized params would
-    # leak tracers out of that trace
+    # a build may run INSIDE a jit trace (a model that builds its plan at
+    # trace time); without this guard the float64->float32 canonicalization
+    # becomes a traced op and the cached params would leak tracers out of
+    # that trace
     with jax.ensure_compile_time_eval():
         return PlanParams(
             cross_tgt_d=tuple(jnp.asarray(d) for d in spec.cross_tgt_d0),
@@ -305,54 +231,33 @@ def _birth_params(spec: PlanSpec) -> PlanParams:
         )
 
 
-def plan_from_spec(spec: PlanSpec, params: PlanParams | None = None
-                   ) -> IntegrationPlan:
-    """Reconstruct a legacy `IntegrationPlan` from (spec, params) — the
-    facade path for loaded artifacts: zero IT rebuild by construction."""
-    cbs = []
-    for i in range(len(spec.cross_tgt_d0)):
-        cbs.append(CrossBucket(
-            tgt_d=spec.cross_tgt_d0[i], tgt_d_mask=spec.cross_tgt_mask[i],
-            src_d=spec.cross_src_d0[i], src_d_mask=spec.cross_src_mask[i],
-            src_off=spec.cross_src_off[i], tgt_off=spec.cross_tgt_off[i],
-            piv=spec.cross_piv[i] if spec.cross_piv else None,
-            tgt_rep=spec.cross_tgt_rep[i] if spec.cross_tgt_rep else None,
-            src_rep=spec.cross_src_rep[i] if spec.cross_src_rep else None,
-        ))
-    lbs = [LeafBucket(ids=spec.leaf_ids[i], mask=spec.leaf_mask[i],
-                      dists=spec.leaf_dists0[i])
-           for i in range(len(spec.leaf_ids))]
-    plan = IntegrationPlan(
-        n=spec.n, cross_buckets=cbs, leaf_buckets=lbs, pivots=spec.pivots,
-        grid_h=spec.grid_h, src_gather=spec.src_gather, src_seg=spec.src_seg,
-        n_src_groups=spec.n_src_groups, tgt_gather=spec.tgt_gather,
-        tgt_scatter=spec.tgt_scatter, n_tgt_groups=spec.n_tgt_groups,
-        num_cross_jobs=spec.num_cross_jobs, fingerprint=spec.fingerprint,
-        leaf_size=spec.leaf_size, seed=spec.seed,
-        tree_sizes=spec.tree_sizes, reweightable=spec.reweightable)
-    if spec.path_rows is not None:
-        plan.rw = {"path_rows": spec.path_rows,
-                   "path_edges": spec.path_edges,
-                   "num_edges": spec.num_edges,
-                   "cross_tgt_lca": list(spec.cross_tgt_lca),
-                   "cross_src_lca": list(spec.cross_src_lca),
-                   "leaf_lca": list(spec.leaf_lca)}
-        if spec.edges_u is not None:
-            plan.rw.update(edges_u=spec.edges_u, edges_v=spec.edges_v,
-                           edge_w0=spec.edge_w0)
-    if spec.children is not None:
-        plan.upd = {"children": spec.children, "root_refs": spec.root_refs,
-                    "job_bucket": spec.job_bucket, "job_row": spec.job_row,
-                    "leaf_bucket": spec.leaf_bucket,
-                    "leaf_row": spec.leaf_row}
-    plan._spec_params = (spec, params if params is not None
-                         else _birth_params(spec))
-    return plan
-
-
 # ----------------------------------------------------------------------------
 # build
 # ----------------------------------------------------------------------------
+
+_PLAN_CACHE = BoundedLRU(32)
+
+
+def clear_plan_cache() -> None:
+    """Drop the in-memory plan LRU (a configured disk cache is kept)."""
+    _PLAN_CACHE.clear()
+
+
+def _cached_plan(key):
+    """The (spec, params) pair under `key` from the in-memory LRU, recorded
+    in trace_guard as an `integrate.plan_cache` hit or miss; on a miss,
+    from the disk cache when one is configured (then kept in memory too)."""
+    hit = _PLAN_CACHE.get(key)
+    if hit is not None:
+        trace_guard.record("integrate.plan_cache", event="hit")
+        return hit
+    trace_guard.record("integrate.plan_cache", event="miss")
+    from repro.core import plan_cache
+
+    hit = plan_cache.load(plan_cache.key_str(key))
+    if hit is not None:
+        _PLAN_CACHE.put(key, hit)
+    return hit
 
 
 def build(tree_or_forest, *, leaf_size: int = 64, seed: int = 0,
@@ -360,27 +265,77 @@ def build(tree_or_forest, *, leaf_size: int = 64, seed: int = 0,
           use_cache: bool = True):
     """Compile a tree or `Forest` into a functional (spec, params) pair.
 
+    A `Forest` compiles into ONE plan over the packed vertex space (vertex
+    v of tree t at row `forest.offsets[t] + v`): the batched flat-IT build
+    decomposes all trees in one level sweep and the assembly merges their
+    cross jobs and leaves into shared size-class buckets, so the executor
+    runs N trees in the same handful of fused dispatches as one. The
+    multiply is block-diagonal by construction.
+
+    Cache hierarchy, keyed by content fingerprint(s), leaf_size, seed,
+    grid detection and reweightable: the in-memory BoundedLRU first, then
+    (when `FTFI_PLAN_CACHE` is configured) the disk-persistent artifact
+    cache, so a cold process over a known topology pays one npz read
+    instead of an O(N log N) decomposition. Both hold the pair as built.
+    `use_cache=False` bypasses both.
+
     `reweightable=True` additionally records the (pivot, representative,
     LCA) tables and root-path edge CSR that let `reweight(spec, edge_w)`
     re-derive `params` differentiably from edge weights — at the cost of
     per-vertex (uncollapsed) distance groups and no grid/Hankel engine."""
+    import hashlib
+
+    from repro.core.integrate import _assemble_plan, _reweight_tables
+    from repro.core.itree_flat import (build_flat_forest, build_flat_it,
+                                       tree_fingerprint)
     from repro.graphs.graph import Forest
 
-    if isinstance(tree_or_forest, Forest):
-        plan = compile_forest_plan(
-            tree_or_forest, leaf_size=leaf_size, seed=seed,
-            detect_grid_spacing=detect_grid_spacing, use_cache=use_cache,
-            reweightable=reweightable)
-    else:
-        plan = compile_plan(
-            tree_or_forest, leaf_size=leaf_size, seed=seed,
-            detect_grid_spacing=detect_grid_spacing, use_cache=use_cache,
-            reweightable=reweightable)
-    return specialize(plan)
+    forest = isinstance(tree_or_forest, Forest)
+    trees = tree_or_forest.trees if forest else [tree_or_forest]
+    if reweightable:
+        detect_grid_spacing = False
+    ls = max(int(leaf_size), 6)
+    with trace_guard.span("ftfi.build.fingerprint"):
+        fps = tuple(tree_fingerprint(t) for t in trees)
+    if use_cache:
+        key = ((("forest", fps) if forest else (fps[0],))
+               + (ls, int(seed), detect_grid_spacing, reweightable))
+        hit = _cached_plan(key)
+        if hit is not None:
+            return hit
+
+    with trace_guard.span("ftfi.build.decompose"):
+        if forest:
+            flat = build_flat_forest(trees, leaf_size=leaf_size, seed=seed,
+                                     use_cache=use_cache)
+        else:
+            flat = build_flat_it(tree_or_forest, leaf_size=leaf_size,
+                                 seed=seed, use_cache=use_cache)
+    with trace_guard.span("ftfi.build.assemble"):
+        fields = _assemble_plan(flat, tree_or_forest.num_vertices,
+                                detect_grid_spacing,
+                                expand_groups=reweightable)
+    if reweightable:
+        fields.update(_reweight_tables(fields, trees))
+    sizes = (tuple(int(s) for s in tree_or_forest.tree_sizes) if forest
+             else (tree_or_forest.num_vertices,))
+    spec = PlanSpec(
+        num_trees=max(len(sizes), 1), tree_sizes=sizes or (fields["n"],),
+        leaf_size=ls, seed=int(seed),
+        fingerprint=(hashlib.sha1("".join(fps).encode()).hexdigest()
+                     if forest else fps[0]),
+        reweightable=reweightable, **fields)
+    pair = (spec, _birth_params(spec))
+    if use_cache:
+        from repro.core import plan_cache
+
+        _PLAN_CACHE.put(key, pair)
+        plan_cache.store(plan_cache.key_str(key), *pair)
+    return pair
 
 
 # ----------------------------------------------------------------------------
-# batched cross engines (moved here from engines/plan.py; re-exported there)
+# batched cross engines
 # ----------------------------------------------------------------------------
 
 
@@ -638,7 +593,8 @@ def apply(spec: PlanSpec, params: PlanParams, fn, X, *,
     `backend` picks the cross-engine family: "plan" (exact LDR + Hankel on
     grids + Chebyshev), "pallas" (fused fdist_matvec kernel for the
     in-kernel families), or "auto" (size-resolved through the ladder). The
-    host backend remains facade-only (numpy).
+    numpy references (`integrate.FTFI`, `ExpMP`, `BTFI`) run on the host
+    without a plan.
 
     `mesh` (optionally with `axis`) routes through the multi-device
     shard_map executor — see `plan_shard.apply_sharded`."""
@@ -658,10 +614,9 @@ def fastmult(spec: PlanSpec, fn, *, backend: str = "plan", degree: int = 32,
              pallas_opts: dict | None = None) -> Callable:
     """Jittable (params, X) -> Y closure with the engine choice baked in.
 
-    Unlike the legacy `Integrator.fastmult` (which captured plan state in an
-    opaque closure), the returned function is pure: params cross jit
-    boundaries explicitly, so it vmaps over batched fields, shards, and
-    back-propagates into reweighted distances."""
+    The returned function is pure: params cross jit boundaries explicitly,
+    so it vmaps over batched fields, shards, and back-propagates into
+    reweighted distances."""
     fspec = _fspec(fn)
     _, cross = select_cross(spec, fspec, backend=backend, degree=degree,
                             pallas_opts=pallas_opts)

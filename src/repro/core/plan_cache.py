@@ -1,10 +1,10 @@
 """Disk-persistent LRU plan cache.
 
-`compile_plan` / `compile_forest_plan` consult this cache after the
-in-memory BoundedLRU misses and BEFORE any IT build: a hit is one
-`load_plan` npz read reconstructed through `plan_from_spec` (zero IT
-rebuild), which turns cold *process* starts — serving restarts, benchmark
-reruns, per-request trees that recur across workers — into a file read.
+`ftfi.build` (`plan_api.build`) consults this cache after the in-memory
+BoundedLRU misses and BEFORE any IT build: a hit is one `load_plan` npz
+read of the (spec, params) pair as it was built (zero IT rebuild), which
+turns cold *process* starts — serving restarts, benchmark reruns,
+per-request trees that recur across workers — into a file read.
 
 Configuration (environment, overridable programmatically):
 

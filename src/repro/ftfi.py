@@ -1,10 +1,16 @@
-"""Public functional plan API (see `repro.core.plan_api` for the engine).
+"""The plan API: the one way to build and run a tree-field integrator.
+
+A plan is a (PlanSpec, PlanParams) pair (`repro.core.plan_api`): the spec
+holds the static index arrays, bucket layout and provenance, the params the
+distances and per-tree output weights as jnp arrays.
 
     from repro import ftfi
 
-    spec, params = ftfi.build(tree)                  # static + dynamic halves
+    spec, params = ftfi.build(tree)                  # or ftfi.build(Forest)
     Y = ftfi.apply(spec, params, Exponential(-0.5), X)
+    Y = ftfi.apply(spec, params, fn, X, backend="pallas")  # fdist_matvec
     fm = jax.jit(ftfi.fastmult(spec, fn))            # (params, X) -> Y
+    ftfi.describe(spec, fn, "pallas")["cross_engine"]  # e.g. fdist_matvec:exp
     ftfi.save_plan("plan.npz", spec, params)
     spec, params = ftfi.load_plan("plan.npz")        # zero IT rebuild
 
@@ -20,15 +26,15 @@
     ])
 
     # disk-persistent plan cache: set FTFI_PLAN_CACHE=/path (or call
-    # ftfi.plan_cache.configure(path)) and every build/Integrator over a
-    # known topology becomes one npz read; LRU-evicted past
+    # ftfi.plan_cache.configure(path)) and every build over a known
+    # topology becomes one npz read; LRU-evicted past
     # FTFI_PLAN_CACHE_MAX_MB (default 512)
 
     # robustness layer (see README "Failure modes and the degradation
     # ladder"): artifacts are validated on load/cache-hit/update under the
     # FTFI_PLAN_GUARD policy (strict|warn|off), and the resilient entry
-    # points demote pallas -> plan -> host on kernel failure or non-finite
-    # output instead of crashing
+    # points demote pallas -> plan -> host (the same executor run eagerly)
+    # on kernel failure or non-finite output instead of crashing
     ftfi.validate(spec, params)                      # PlanValidationError
     Y = ftfi.apply_resilient(spec, params, fn, X, backend="pallas")
     fm = ftfi.resilient_fastmult(spec, fn)           # sticky demotions
@@ -41,13 +47,17 @@
         Y = ftfi.apply_sharded(spec, params, fn, X)
         fm = jax.jit(ftfi.sharded_fastmult(spec, fn, mesh=mesh))
     ftfi.shard_stats(spec, num_shards)               # block/halo/work stats
+
+The numpy references the tests hold every path to run without a plan:
+`repro.core.integrate.BTFI` (the dense oracle), `FTFI` (the recursive
+integrator) and `ExpMP` (two-pass message passing for exp f).
 """
 from repro.core import ladder, plan_cache, plan_guard  # noqa: F401
 from repro.core.ladder import (  # noqa: F401
     BackendDemotionWarning, apply_resilient, resilient_fastmult)
 from repro.core.plan_api import (  # noqa: F401
     KERNEL_MODES, PlanParams, PlanSpec, apply, build, describe, fastmult,
-    load_plan, plan_from_spec, reweight, save_plan, specialize, update_plan)
+    load_plan, reweight, save_plan, update_plan)
 from repro.core.plan_guard import PlanValidationError, validate  # noqa: F401
 from repro.core.plan_shard import (  # noqa: F401
     SHARD_LAYOUT_VERSION, apply_sharded, partition_plan, shard_stats,

@@ -107,20 +107,23 @@ def frt_forest(g: Graph, num_trees: int, seed: int = 0,
     return Forest(trees), np.arange(g.num_vertices)
 
 
-def forest_leaf_integrate(forest: Forest, leaf_ids: np.ndarray, integrator,
-                          fn, X: np.ndarray) -> np.ndarray:
+def forest_leaf_integrate(forest: Forest, leaf_ids: np.ndarray, plan,
+                          fn, X: np.ndarray,
+                          backend: str = "plan") -> np.ndarray:
     """One fused integration of a leaf field over every tree of an FRT
     forest, averaged: the field is replicated into each tree's block at
     `offsets[t] + leaf_ids` (zero on internal cluster vertices), one
-    `integrator.integrate` call covers all trees, and the per-tree leaf
-    outputs are meaned. Reused by callers that sweep many f over one
-    prebuilt forest (e.g. the Fig-4 bench)."""
+    `ftfi.apply` over the forest's `(spec, params)` pair `plan` covers all
+    trees, and the per-tree leaf outputs are meaned. Reused by callers that
+    sweep many f over one prebuilt forest (e.g. the Fig-4 bench)."""
+    from repro import ftfi
+
     X = np.asarray(X)
     off = forest.offsets
     Xp = np.zeros((forest.num_vertices,) + X.shape[1:], dtype=X.dtype)
     for t in range(forest.num_trees):
         Xp[off[t] + leaf_ids] = X
-    out = np.asarray(integrator.integrate(fn, Xp))
+    out = np.asarray(ftfi.apply(*plan, fn, Xp, backend=backend))
     return np.mean(np.stack([out[off[t] + leaf_ids]
                              for t in range(forest.num_trees)]), axis=0)
 
@@ -130,9 +133,9 @@ def frt_integrate_forest(g: Graph, fn, X: np.ndarray, num_trees: int = 8,
                          backend: str = "plan"):
     """Averaged f-integration over `num_trees` sampled FRT tree metrics as
     ONE batched forest integration (Fig. 4's expectation estimate)."""
-    from repro.core.engines import Integrator
+    from repro import ftfi
 
     forest, leaf_ids = frt_forest(g, num_trees, seed=seed)
-    integ = Integrator.from_forest(forest, backend=backend,
-                                   leaf_size=leaf_size)
-    return forest_leaf_integrate(forest, leaf_ids, integ, fn, X)
+    plan = ftfi.build(forest, leaf_size=leaf_size)
+    return forest_leaf_integrate(forest, leaf_ids, plan, fn, X,
+                                 backend=backend)

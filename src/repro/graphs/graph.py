@@ -87,9 +87,8 @@ class Forest:
     spaces: vertex v of tree t lives at global row `offsets[t] + v`, so a
     packed field has shape (sum_t n_t, d) and a forest integration is a
     block-diagonal multiply — every tree's M_f applied to its own rows, with
-    zero cross-tree coupling. `compile_forest_plan` (repro.core.integrate)
-    compiles the whole forest into one fused IntegrationPlan;
-    `Integrator.from_forest` is the public entry point.
+    zero cross-tree coupling. `ftfi.build(forest)` compiles the whole
+    forest into one fused (spec, params) plan.
     """
 
     def __init__(self, trees):

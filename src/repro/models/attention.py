@@ -585,7 +585,7 @@ def topo_logit_scale(cfg, p_topo):
 
 
 def resolve_topo_backend(cfg, backend: str | None = None) -> str:
-    """Integrator/plan backend for tree- and grid-based topological masks,
+    """Executor backend for tree- and grid-based topological masks,
     shared by the ViT grid path and plan-serving. Resolution follows the
     topo impl axis: explicit `backend` arg > cfg.topo_backend >
     cfg.topo_attn_impl ("pallas" -> the fused fdist_matvec executor
@@ -766,7 +766,7 @@ def topo_decomposition(cfg, coeffs, L: int, rank: int = 24):
     nodes = jnp.asarray(nodes)
 
     def lagr(pos):  # pos: () -> (rank,)
-        from repro.core.engines.plan import _lagrange_batched
+        from repro.core.plan_api import _lagrange_batched
         pts = jnp.reshape(jnp.asarray(pos, jnp.float32), (1, 1))
         return _lagrange_batched(pts, nodes[None, :])[0, 0]
 

@@ -8,100 +8,86 @@ IT plan). 3 learnable mask scalars per layer (synced).
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.engines import Integrator
-from repro.core.masks import make_tree_fastmult, masked_linear_attention
+from repro.core.lru import BoundedLRU
+from repro.core.masks import (make_tree_fastmult, mask_f,
+                               masked_attention_bruteforce,
+                               masked_linear_attention, tree_distances)
 from repro.graphs.graph import grid_graph
 from repro.graphs.mst import minimum_spanning_tree
 from repro.models import attention as A
 from repro.models.layers import (dense_init, dtype_of, gated_mlp,
                                  gated_mlp_init, maybe_remat, rms_norm)
 
+# side -> (spec, params) of the patch-grid MST plan: built once per side
+# (or installed from an artifact), shared by every backend
+_GRID_PLANS = BoundedLRU(8)
+# sides whose plan already passed the pallas health probe
+_PROBED = set()
 
-from repro.core.lru import BoundedLRU
 
-_GRID_INTEGRATOR_CACHE = BoundedLRU(8)
-_GRID_DIST_CACHE = BoundedLRU(4)
+@functools.lru_cache(maxsize=8)
+def grid_mst(side: int):
+    """The MST of the unit-weight side x side patch grid graph."""
+    return minimum_spanning_tree(grid_graph(side, side))
 
 
-def install_grid_plan(spec, params, backend: str = "plan") -> int:
+def install_grid_plan(spec, params) -> int:
     """Adopt a prebuilt/loaded functional plan (e.g. an `ftfi.load_plan`
-    artifact) as the grid integrator for its side length: subsequent
-    `build_grid_integrator` / `build_grid_plan` calls reuse it with ZERO IT
-    rebuild. Returns the grid side. Serving startup uses this to trade the
-    O(N log N) decomposition for one artifact read."""
+    artifact) as the grid plan for its side length: subsequent
+    `build_grid_integrator` calls reuse it with ZERO IT rebuild. Returns
+    the grid side. Serving startup uses this to trade the O(N log N)
+    decomposition for one artifact read. The pair passes the plan guard
+    first (FTFI_PLAN_GUARD policy): this is a door untrusted artifacts
+    come in by, and the fused executor does no bounds checking of its
+    own."""
+    from repro.core import plan_guard
+
     side = int(round(np.sqrt(spec.n)))
     if side * side != spec.n:
         raise ValueError(
             f"plan covers n={spec.n} vertices: not a square patch grid")
-    _GRID_INTEGRATOR_CACHE.put(
-        (side, backend),
-        Integrator.from_plan(spec, params, backend=backend, leaf_size=16))
+    plan_guard.validate(spec, params, where="vit.install_grid_plan")
+    _GRID_PLANS.put(side, (spec, params))
+    _PROBED.discard(side)
     return side
 
 
-def build_grid_integrator(cfg, backend: str | None = None) -> Integrator:
-    """Integrator over the patch-grid MST (built once per config). The MST of
-    a unit-weight grid graph is grid-aligned (grid_h == 1), so general mask
-    functions ride the exact Hankel/FFT cross engine automatically.
+def build_grid_integrator(cfg):
+    """The (PlanSpec, PlanParams) pair of the patch-grid MST plan, built
+    once per grid side (or served from `install_grid_plan`). The MST of a
+    unit-weight grid graph is grid-aligned (grid_h == 1), so general mask
+    functions ride the exact Hankel/FFT cross engine on the plan path.
 
-    Backend resolution is `attention.resolve_topo_backend` (explicit arg >
-    cfg.topo_backend > cfg.topo_attn_impl). Memoized per (grid side,
-    backend): repeated mask rebuilds return the same Integrator, so its plan
-    and compiled fastmult closures are reused (the underlying IT/plan
-    construction is additionally content-hash cached), and a plan installed
-    via `install_grid_plan` is served from here without any IT build."""
+    Degradation ladder: the first time a side is asked for on the pallas
+    backend (resolved by `attention.resolve_topo_backend(cfg)`, as the
+    masks resolve it: cfg.topo_backend > cfg.topo_attn_impl) its plan is
+    health-probed on that rung BEFORE live traffic sees it — a kernel that
+    fails to launch (or emits non-finite fields) blocks the rung globally,
+    and the masks then resolve to the next one down."""
+    from repro import ftfi
+
     side = int(round(np.sqrt(cfg.num_prefix_embeddings)))
     assert side * side == cfg.num_prefix_embeddings
-    backend = A.resolve_topo_backend(cfg, backend)
-    key = (side, backend)
-    integ = _GRID_INTEGRATOR_CACHE.get(key)
-    if integ is None:
-        mst = minimum_spanning_tree(grid_graph(side, side))
-        integ = Integrator(mst, backend=backend, leaf_size=16)
-        # degradation ladder: health-probe compiled rungs once per (side,
-        # backend) BEFORE live traffic sees them — a kernel that fails to
-        # launch (or emits non-finite fields) blocks that rung globally and
-        # this grid quietly serves from the next one down
-        if backend in ("pallas",):
-            from repro.core import ladder
+    plan = _GRID_PLANS.get(side)
+    if plan is None:
+        plan = ftfi.build(grid_mst(side), leaf_size=16)
+        _GRID_PLANS.put(side, plan)
+    pallas = A.resolve_topo_backend(cfg) == "pallas"
+    if pallas and side not in _PROBED:
+        from repro.core import ladder
 
-            reason = ladder.probe_backend(integ.spec, integ.params, backend)
-            if reason is not None:
-                ladder.block_backend(backend, f"grid {side}x{side} probe: "
-                                     f"{reason}")
-                backend = ladder.effective_backend(backend)
-                key = (side, backend)
-                integ = _GRID_INTEGRATOR_CACHE.get(key)
-                if integ is None:
-                    integ = Integrator(mst, backend=backend, leaf_size=16)
-                    _GRID_INTEGRATOR_CACHE.put(key, integ)
-                return integ
-        _GRID_INTEGRATOR_CACHE.put(key, integ)
-    return integ
-
-
-def build_grid_plan(cfg, backend: str | None = None):
-    """Functional face of the grid integrator: the (PlanSpec, PlanParams)
-    pair of the patch-grid MST plan — what `ftfi.apply`/`ftfi.save_plan`
-    consume. Same memoization as `build_grid_integrator` (the pair is split
-    off the identical content-cached plan)."""
-    integ = build_grid_integrator(cfg, backend)
-    return integ.spec, integ.params
-
-
-def _grid_tree_distances(side: int):
-    """Dense (L, L) MST path-distance matrix for the ref impl (tests/tiny L)."""
-    D = _GRID_DIST_CACHE.get(side)
-    if D is None:
-        from repro.graphs.traverse import tree_all_pairs
-        D = np.asarray(tree_all_pairs(
-            minimum_spanning_tree(grid_graph(side, side))), np.float32)
-        _GRID_DIST_CACHE.put(side, D)
-    return D
+        _PROBED.add(side)
+        reason = ladder.probe_backend(*plan, "pallas")
+        if reason is not None:
+            ladder.block_backend("pallas", f"grid {side}x{side} probe: "
+                                 f"{reason}")
+    return plan
 
 
 def _vit_block_init(key, cfg, dtype):
@@ -138,10 +124,11 @@ def init_params(cfg, key, num_classes: int = 1000, patch_dim: int = 768):
 def topo_vit_attention(cfg, p, p_topo, x, integ):
     """Grid-MST masked linear attention. The cfg.topo_attn_impl axis rides
     through here too: `ref` materializes the dense tree mask (oracle), any
-    other impl runs Algorithm 1 with `make_tree_fastmult` over `integ`: the
-    dense product by f(D) on a grid of at most `masks.N_DENSE` patches,
-    else the IT plan on the executor backend (plan vs fused pallas
-    fdist_matvec) picked when `integ` was built (build_grid_integrator)."""
+    other impl runs Algorithm 1 with `make_tree_fastmult` over the grid
+    plan `integ` and the grid MST: the dense product by f(D) on a grid of
+    at most `masks.N_DENSE` patches, else the IT plan on the executor
+    backend `attention.resolve_topo_backend` picks for `cfg` (plan vs
+    fused pallas fdist_matvec)."""
     B, L, _ = x.shape
     q, k, v = A._project_qkv(cfg, p["attn"], x,
                              jnp.zeros((B, L), jnp.int32), rope=False)
@@ -153,15 +140,16 @@ def topo_vit_attention(cfg, p, p_topo, x, integ):
     qf_ = qf.transpose(0, 2, 1, 3)
     kf_ = kf.transpose(0, 2, 1, 3)
     v_ = v.transpose(0, 2, 1, 3).astype(jnp.float32)
+    mst = grid_mst(int(round(np.sqrt(L))))
     if getattr(cfg, "topo_attn_impl", "fft") == "ref":
-        from repro.core.masks import mask_f, masked_attention_bruteforce
-        D = jnp.asarray(_grid_tree_distances(int(round(np.sqrt(L)))))
+        D = jnp.asarray(tree_distances(mst))
         out = masked_attention_bruteforce(
             qf_, kf_, v_, mask_f(cfg.topo_g, coeffs, cfg.topo_dist_scale)(D))
     else:
         with jax.named_scope("vit.alg1"):
             fastmult = make_tree_fastmult(
                 integ, cfg.topo_g, coeffs, cfg.topo_dist_scale,
+                backend=A.resolve_topo_backend(cfg), tree=mst,
                 sharded=getattr(cfg, "topo_shard_plan", False))
             out = masked_linear_attention(qf_, kf_, v_, fastmult)
     out = out.transpose(0, 2, 1, 3).reshape(B, L, -1).astype(x.dtype)
@@ -170,7 +158,8 @@ def topo_vit_attention(cfg, p, p_topo, x, integ):
 
 def forward(cfg, params, patches, integ):
     """patches: (B, L, patch_dim) -> logits (B, num_classes).
-    `integ` is the grid Integrator from build_grid_integrator.
+    `integ` is the grid plan, the (spec, params) pair from
+    build_grid_integrator.
 
     The step's parts run under `jax.named_scope`s that a device trace sums
     its ops by: `vit.embed`, `vit.layer_params` (the per-layer slice of the

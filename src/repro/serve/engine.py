@@ -88,13 +88,13 @@ class ServeEngine:
         """`plan` optionally preloads a functional integration plan — an
         `ftfi.save_plan` artifact path or a (PlanSpec, PlanParams) pair —
         so topological-mask serving never rebuilds the IT at startup:
-        square (patch-grid) plans are installed as the ViT grid integrator,
+        square (patch-grid) plans are installed as the ViT grid plan,
         and the provenance (content hash, seed, leaf_size) is surfaced in
         `plan_banner()` for the serve log. Either form is validated by the
         plan guard before anything dereferences its index arrays.
 
         Plans compiled on demand (per-request trees packed into the forest
-        mask, `compile_plan` masks) additionally consult the disk-persistent
+        mask, `ftfi.build` masks) additionally consult the disk-persistent
         plan cache when `FTFI_PLAN_CACHE` is configured, so even cold
         engine processes serving recurring topologies skip the IT rebuild;
         `plan_banner()` reports the cache status.
@@ -132,12 +132,10 @@ class ServeEngine:
             if (side * side == self.plan_spec.n
                     and getattr(cfg, "num_prefix_embeddings", None)
                     == self.plan_spec.n):
-                from repro.models import attention as A
                 from repro.models import vit
 
                 self.plan_grid_side = vit.install_grid_plan(
-                    self.plan_spec, self.plan_params,
-                    backend=A.resolve_topo_backend(cfg))
+                    self.plan_spec, self.plan_params)
         self.B = batch_slots
         self.S = max_len
         self.eos = eos_id
@@ -219,11 +217,11 @@ class ServeEngine:
         s = self.plan_spec
         if self.plan_grid_side is not None:
             status = (f"installed as {self.plan_grid_side}x"
-                      f"{self.plan_grid_side} grid integrator — "
+                      f"{self.plan_grid_side} grid plan — "
                       "zero IT rebuild")
         else:
             status = ("loaded, NOT installed: plan does not cover this "
-                      "model's patch grid; consume via Integrator.from_plan")
+                      "model's patch grid; consume via ftfi.apply")
         return (f"plan: sha={s.fingerprint[:12]} seed={s.seed} "
                 f"leaf_size={s.leaf_size} n={s.n} trees={s.num_trees} "
                 f"grid_h={s.grid_h} reweightable={s.reweightable} "

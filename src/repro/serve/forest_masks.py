@@ -3,7 +3,7 @@
 Topological masking as a first-class serving feature. Each request may carry
 its own `WeightedTree` over its prompt tokens; the engine packs the live
 slots' trees into ONE `Forest` and compiles a single block-diagonal
-integration plan (`compile_forest_plan` via `ftfi.build`), so a batched
+integration plan (`ftfi.build` of the packed `Forest`), so a batched
 tree-masked prefill is one fused plan execution instead of per-request
 rebuilds. The forest layout is block-diagonal — zero cross-tree coupling —
 so rows belonging to other slots (or to ghost rows left by incremental

@@ -5,9 +5,10 @@ import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
+from repro import ftfi
 from repro.core import cordial as C
-from repro.core.engines import execute_plan, polynomial_batched_matvec
-from repro.core.integrate import BTFI, FTFI, compile_plan
+from repro.core.integrate import BTFI, FTFI
+from repro.core.plan_api import _execute, polynomial_batched_matvec
 from repro.core.integrator_tree import build_integrator_tree, it_stats
 from repro.core import approx
 from repro.graphs.graph import (caterpillar_tree, grid_graph, path_graph,
@@ -75,20 +76,19 @@ def test_plan_matches_recursive_and_grad(rng):
     X = rng.normal(size=(150, 2))
     fn = C.Polynomial((0.3, -0.1, 0.05))
     ref = BTFI(tree).integrate(fn, X)
-    plan = compile_plan(tree, leaf_size=16)
+    spec, params = ftfi.build(tree, leaf_size=16)
     coeffs = jnp.array([0.3, -0.1, 0.05])
-    bm = lambda *a: polynomial_batched_matvec(coeffs, *a)
+    bm = lambda i, *a: polynomial_batched_matvec(coeffs, *a)
     f_eval = lambda z: coeffs[0] + coeffs[1] * z + coeffs[2] * z * z
-    got = np.asarray(execute_plan(plan, jnp.asarray(X), f_eval,
-                                  batched_matvec=bm))
+    got = np.asarray(_execute(spec, params, f_eval, bm, jnp.asarray(X)))
     assert np.max(np.abs(got - ref)) / np.max(np.abs(ref)) < 1e-5
 
     # gradient wrt coefficients matches finite differences
     def loss(c):
-        bmv = lambda *a: polynomial_batched_matvec(c, *a)
+        bmv = lambda i, *a: polynomial_batched_matvec(c, *a)
         fe = lambda z: c[0] + c[1] * z + c[2] * z * z
-        return jnp.sum(execute_plan(plan, jnp.asarray(X, jnp.float32), fe,
-                                    batched_matvec=bmv) ** 2)
+        return jnp.sum(_execute(spec, params, fe, bmv,
+                                jnp.asarray(X, jnp.float32)) ** 2)
 
     g = jax.grad(loss)(coeffs)
     eps = 1e-3
@@ -103,8 +103,10 @@ def test_chebyshev_engine_spectral(rng):
     f_np = lambda z: np.exp(-0.4 * z) / (1 + 0.3 * z)
     f_j = lambda z: jnp.exp(-0.4 * z) / (1 + 0.3 * z)
     ref = BTFI(tree).integrate(f_np, X)
-    plan = compile_plan(tree, leaf_size=16)
-    got = np.asarray(execute_plan(plan, jnp.asarray(X), f_j, degree=32))
+    spec, params = ftfi.build(tree, leaf_size=16)
+    assert ftfi.describe(spec, f_j)["cross_engine"] == "chebyshev"
+    got = np.asarray(ftfi.apply(spec, params, f_j, jnp.asarray(X),
+                                degree=32))
     assert np.max(np.abs(got - ref)) / np.max(np.abs(ref)) < 1e-5
 
 

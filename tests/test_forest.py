@@ -6,9 +6,12 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
+import jax
+
+from repro import ftfi
 from repro.analysis import trace_guard
 from repro.core import cordial as C
-from repro.core.engines import Integrator
+from repro.core.integrate import FTFI, ExpMP
 from repro.core.itree_flat import build_flat_forest, build_flat_it
 from repro.graphs.graph import (Forest, caterpillar_tree, path_graph,
                                 random_tree, star_tree, synthetic_graph)
@@ -21,6 +24,19 @@ def _mixed_forest(num=55, seed=0, lo=8, hi=60):
     trees += [path_graph(34), star_tree(27, seed=seed + 1),
               caterpillar_tree(41, seed=seed + 2)]
     return Forest(trees)
+
+
+def _host_loop(forest, fn, X):
+    """The per-tree host reference loop over a packed field: ExpMP for exp
+    f, else the recursive FTFI, one tree at a time."""
+    off, outs = forest.offsets, []
+    for i, t in enumerate(forest.trees):
+        Xi = X[off[i]:off[i + 1]]
+        if isinstance(fn, C.Exponential):
+            outs.append(ExpMP(t).integrate(fn.lam, Xi, scale=fn.scale))
+        else:
+            outs.append(FTFI(t).integrate(fn, Xi))
+    return np.concatenate(outs, axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -87,16 +103,16 @@ def test_build_flat_forest_matches_per_tree_builds():
 def test_forest_plan_equals_per_tree_loop(backend, rng):
     forest = _mixed_forest(55)
     X = rng.normal(size=(forest.num_vertices, 3))
-    loop = Integrator.from_forest(forest, backend="host")
+    spec, params = ftfi.build(forest, leaf_size=16)
     for fn in (C.Exponential(-0.7, 1.3), C.Polynomial((0.5, -0.2, 0.1)),
                C.AnyFn(lambda z: (z + 1.0) ** -0.5)):
-        ref = np.asarray(loop.integrate(fn, X))
-        integ = Integrator.from_forest(forest, backend=backend, leaf_size=16)
-        got = np.asarray(integ.integrate(fn, X))
+        ref = _host_loop(forest, fn, X)
+        got = np.asarray(ftfi.apply(spec, params, fn, X, backend=backend))
         scale = max(np.max(np.abs(ref)), 1e-12)
         assert np.max(np.abs(got - ref)) / scale < 1e-5, type(fn).__name__
-    assert loop.num_trees == 55
-    assert Integrator.from_forest(forest, backend=backend).num_trees == 55
+    assert forest.num_trees == 55
+    assert spec.num_trees == 55
+    assert ftfi.describe(spec, C.Exponential(-0.7), backend)["num_trees"] == 55
 
 
 def test_forest_is_block_diagonal(rng):
@@ -106,8 +122,8 @@ def test_forest_is_block_diagonal(rng):
     X = np.zeros((forest.num_vertices, 2))
     t = 3
     X[off[t]:off[t + 1]] = rng.normal(size=(off[t + 1] - off[t], 2))
-    out = np.asarray(Integrator.from_forest(forest, leaf_size=16)
-                     .integrate(C.Exponential(-0.5), X))
+    out = np.asarray(ftfi.apply(*ftfi.build(forest, leaf_size=16),
+                                C.Exponential(-0.5), X))
     mask = np.zeros(forest.num_vertices, bool)
     mask[off[t]:off[t + 1]] = True
     assert np.max(np.abs(out[~mask])) < 1e-6 * max(np.max(np.abs(out)), 1e-9)
@@ -118,33 +134,35 @@ def test_forest_single_fused_dispatch(rng):
     repeated calls, num_trees-independent dispatch structure."""
     forest = _mixed_forest(20, seed=7)
     X = rng.normal(size=(forest.num_vertices, 2))
-    integ = Integrator.from_forest(forest, backend="plan", leaf_size=16)
-    fm = integ.fastmult(C.Exponential(-0.4))
-    site = "engines.plan.fastmult"
+    spec, params = ftfi.build(forest, leaf_size=16)
+    fm = jax.jit(ftfi.fastmult(spec, C.Exponential(-0.4)))
+    site = "ftfi.fastmult"
     n0 = trace_guard.compiles(site)
-    np.asarray(fm(X))
+    np.asarray(fm(params, X))
     assert trace_guard.compiles(site) == n0 + 1
     with trace_guard.expect_stable(site):
-        np.asarray(fm(X))  # same shapes: no retrace
-    plan = integ._impl.plan
+        np.asarray(fm(params, X))  # same shapes: no retrace
     # buckets are merged across trees by size class: far fewer buckets than
     # trees (the whole point of the shared index space)
-    assert len(plan.cross_buckets) + len(plan.leaf_buckets) < 12
+    assert len(spec.cross_tgt_mask) + len(spec.leaf_ids) < 12
 
 
-@pytest.mark.filterwarnings("ignore::DeprecationWarning")  # facade path
 def test_forest_fastmult_shared_across_instances(rng):
-    """Content-cached plans share their compiled fastmult closures: a new
-    Integrator over an identical forest reuses the jitted executor."""
+    """Content-cached plans share their compiled mask closures: a build
+    over an identical forest returns the same pair, so its forest mask
+    reuses the jitted executor."""
+    from repro.core import masks as MK
+
     forest = _mixed_forest(6, seed=11)
-    i1 = Integrator.from_forest(forest, backend="plan", leaf_size=16)
-    fm1 = i1.fastmult(C.Exponential(-0.3, 1.1))
+    p1 = ftfi.build(forest, leaf_size=16)
+    coeffs = np.asarray([0.1, -0.3], np.float32)
+    fm1 = MK.make_forest_fastmult(p1, forest, "exp", coeffs)
     twin = Forest([type(t)(t.num_vertices, t.edges_u.copy(),
                            t.edges_v.copy(), t.weights.copy())
                    for t in forest.trees])
-    i2 = Integrator.from_forest(twin, backend="plan", leaf_size=16)
-    assert i2._impl.plan is i1._impl.plan  # content-hash plan hit
-    assert i2.fastmult(C.Exponential(-0.3, 1.1)) is fm1
+    p2 = ftfi.build(twin, leaf_size=16)
+    assert p2[0] is p1[0] and p2[1] is p1[1]  # content-hash plan hit
+    assert MK.make_forest_fastmult(p2, twin, "exp", coeffs) is fm1
 
 
 def test_forest_grid_h_reconciliation(rng):
@@ -154,16 +172,14 @@ def test_forest_grid_h_reconciliation(rng):
                    path_graph(33)])
     general = C.AnyFn(lambda z: np.sin(z) * np.exp(-0.1 * z) + 1.0)
     X = rng.normal(size=(unit.num_vertices, 2))
-    integ = Integrator.from_forest(unit, backend="plan", leaf_size=8)
-    assert integ.grid_h == pytest.approx(1.0)
-    assert integ.describe(general)["cross_engine"] == "hankel_fft"
-    ref = np.asarray(Integrator.from_forest(unit, backend="host")
-                     .integrate(general, X))
-    got = np.asarray(integ.integrate(general, X))
+    spec, params = ftfi.build(unit, leaf_size=8)
+    assert spec.grid_h == pytest.approx(1.0)
+    assert ftfi.describe(spec, general)["cross_engine"] == "hankel_fft"
+    ref = _host_loop(unit, general, X)
+    got = np.asarray(ftfi.apply(spec, params, general, X))
     assert np.max(np.abs(got - ref)) / np.max(np.abs(ref)) < 1e-5
     mixed = Forest([path_graph(40), random_tree(30, seed=2)])
-    assert Integrator.from_forest(mixed, backend="plan",
-                                  leaf_size=8).grid_h is None
+    assert ftfi.build(mixed, leaf_size=8)[0].grid_h is None
 
 
 # ---------------------------------------------------------------------------
@@ -226,11 +242,11 @@ def test_make_forest_fastmult_block_diag_mask(weights, rng):
     forest = _mixed_forest(5, seed=9, lo=8, hi=24)
     off = forest.offsets
     N = forest.num_vertices
-    integ = Integrator.from_forest(forest, backend="plan", leaf_size=8)
+    plan = ftfi.build(forest, leaf_size=8)
     coeffs = jnp.asarray([0.0, -0.3], jnp.float32)
     tw = (rng.uniform(0.5, 1.5, size=forest.num_trees)
           if weights == "per_tree" else None)
-    fm = MK.make_forest_fastmult(integ, forest, "exp", coeffs,
+    fm = MK.make_forest_fastmult(plan, forest, "exp", coeffs,
                                  dist_scale=1.0, tree_weights=tw)
     X = jnp.asarray(rng.normal(size=(2, N, 4)), jnp.float32)  # batched field
     # dense block-diagonal reference

@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from repro.core import cordial as C
-from repro.core.integrate import (BTFI, FTFI, clear_plan_cache, compile_plan)
+from repro import ftfi
+from repro.core.integrate import BTFI, FTFI
+from repro.core.plan_api import clear_plan_cache
 from repro.core.integrator_tree import build_integrator_tree, it_stats
 from repro.core.itree_flat import (build_flat_it, clear_flat_cache,
                                    flat_stats, tree_fingerprint)
@@ -106,11 +108,11 @@ def test_cache_keys_include_seed():
     tree = random_tree(90, seed=1)
     clear_plan_cache()
     clear_flat_cache()
-    p0 = compile_plan(tree, leaf_size=16, seed=0)
-    p1 = compile_plan(tree, leaf_size=16, seed=1)
+    p0 = ftfi.build(tree, leaf_size=16, seed=0)
+    p1 = ftfi.build(tree, leaf_size=16, seed=1)
     assert p0 is not p1
-    assert compile_plan(tree, leaf_size=16, seed=0) is p0
-    assert compile_plan(tree, leaf_size=16, seed=1) is p1
+    assert ftfi.build(tree, leaf_size=16, seed=0) is p0
+    assert ftfi.build(tree, leaf_size=16, seed=1) is p1
     f0 = build_flat_it(tree, leaf_size=16, seed=0)
     f1 = build_flat_it(tree, leaf_size=16, seed=1)
     assert f0 is not f1
@@ -129,17 +131,17 @@ def test_plan_cache_amortizes_recompilation():
     tree = random_tree(150, seed=4)
     clear_plan_cache()
     clear_flat_cache()
-    p1 = compile_plan(tree, leaf_size=16)
-    p2 = compile_plan(tree, leaf_size=16)
+    p1 = ftfi.build(tree, leaf_size=16)
+    p2 = ftfi.build(tree, leaf_size=16)
     assert p1 is p2
-    assert compile_plan(tree, leaf_size=32) is not p1
+    assert ftfi.build(tree, leaf_size=32) is not p1
     clear_plan_cache()
-    assert compile_plan(tree, leaf_size=16) is not p1
+    assert ftfi.build(tree, leaf_size=16) is not p1
 
 
 def test_plan_flat_index_arrays_consistent():
     tree = random_tree(200, seed=9)
-    plan = compile_plan(tree, leaf_size=16, use_cache=False)
+    plan, _ = ftfi.build(tree, leaf_size=16, use_cache=False)
     n = tree.num_vertices
     # gather/scatter vertex ids are real vertices (padding-free by design)
     assert plan.src_gather.min() >= 0 and plan.src_gather.max() < n
@@ -148,4 +150,4 @@ def test_plan_flat_index_arrays_consistent():
     assert plan.tgt_gather.max() < plan.n_tgt_groups
     # each (node, direction) job contributes its non-pivot targets once:
     # total scatter size == sum over internal nodes of (kL-1) + (kR-1)
-    assert plan.num_jobs() == 2 * plan.pivots.size
+    assert plan.num_cross_jobs == 2 * plan.pivots.size

@@ -1,9 +1,11 @@
 """Topological masking: Algorithm 1, Toeplitz fastmult, cordial decode."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
+from repro import ftfi
 from repro.core import masks as MK
 from repro.core.toeplitz import (causal_toeplitz_matvec,
                                  symmetric_toeplitz_matvec, toeplitz_dense)
@@ -146,10 +148,10 @@ def _path_counts():
 @pytest.mark.parametrize("path", ["dense", "plan"])
 @pytest.mark.parametrize("backend", ["plan", "pallas"])
 def test_grid_mask_fastmult(backend, path, rng, monkeypatch):
-    """ViT grid masks through the Integrator == dense mask multiply, with
+    """ViT grid masks over the grid plan == dense mask multiply, with
     batch/head axes folded by the tree fastmult factory, on both of its
-    paths (the dense product by f(D), and the plan executor)."""
-    from repro.core.engines import Integrator
+    paths (the dense product by f(D), and the plan executor) and both
+    executor backends."""
     from repro.graphs.graph import grid_graph
     from repro.graphs.mst import minimum_spanning_tree
     from repro.graphs.traverse import tree_all_pairs
@@ -157,13 +159,14 @@ def test_grid_mask_fastmult(backend, path, rng, monkeypatch):
     _pin_path(monkeypatch, path)
     g = grid_graph(6, 6)
     mst = minimum_spanning_tree(g)
-    integ = Integrator(mst, backend=backend, leaf_size=8)
+    plan = ftfi.build(mst, leaf_size=8)
     D = tree_all_pairs(mst)
     coeffs = jnp.asarray([0.0, -0.3], jnp.float32)
     X = jnp.asarray(rng.normal(size=(2, 36, 5)), jnp.float32)  # batched field
     ref = np.einsum("lk,bkd->bld", np.exp(-0.3 * D), np.asarray(X))
     n0 = _path_counts()
-    fm = MK.make_tree_fastmult(integ, "exp", coeffs, dist_scale=1.0)
+    fm = MK.make_tree_fastmult(plan, "exp", coeffs, dist_scale=1.0,
+                               backend=backend, tree=mst)
     n1 = _path_counts()
     assert n1[path] == n0[path] + 1
     got = np.asarray(fm(X))
@@ -175,14 +178,12 @@ def test_grid14_dense_and_plan_agree_with_f64_reference(monkeypatch):
     mask: the dense product and the plan executor give the same outputs
     and the same gradients w.r.t. the mask coefficients and the field, and
     both match a float64 dense reference to 1e-5 relative."""
-    import jax
-    from repro.core.engines import Integrator
     from repro.graphs.graph import grid_graph
     from repro.graphs.mst import minimum_spanning_tree
     from repro.graphs.traverse import tree_all_pairs
 
     mst = minimum_spanning_tree(grid_graph(14, 14))
-    integ = Integrator(mst, backend="plan", leaf_size=16)
+    plan = ftfi.build(mst, leaf_size=16)
     scale = 0.0625
     c = np.asarray([0.1, -0.5, -0.2])
     r = np.random.default_rng(14)
@@ -190,7 +191,7 @@ def test_grid14_dense_and_plan_agree_with_f64_reference(monkeypatch):
     G = r.normal(size=(2, 196, 6))
 
     def loss(c, X):
-        fm = MK.make_tree_fastmult(integ, "exp", c, scale)
+        fm = MK.make_tree_fastmult(plan, "exp", c, scale, tree=mst)
         return jnp.sum(fm(X) * G), fm(X)
 
     def run():
@@ -225,19 +226,18 @@ def test_grid14_dense_and_plan_agree_with_f64_reference(monkeypatch):
 
 def _counter_case(case, monkeypatch):
     """(make the closure, the path it must take) for each kind of plan."""
-    import jax
-    import repro.ftfi as ftfi
-    from repro.core.engines import Integrator
     from repro.graphs.graph import Forest, random_tree
 
     coeffs = np.asarray([0.1, -0.4], np.float32)
     if case == "single_tree":
-        integ = Integrator(random_tree(40, seed=2), leaf_size=8)
-        return (lambda: MK.make_tree_fastmult(integ, "exp", coeffs)), "dense"
+        tree = random_tree(40, seed=2)
+        plan = ftfi.build(tree, leaf_size=8)
+        return (lambda: MK.make_tree_fastmult(plan, "exp", coeffs,
+                                              tree=tree)), "dense"
     if case == "forest":
         forest = Forest([random_tree(20 + 3 * i, seed=i) for i in range(3)])
-        integ = Integrator.from_forest(forest, leaf_size=8)
-        return (lambda: MK.make_forest_fastmult(integ, forest, "exp",
+        plan = ftfi.build(forest, leaf_size=8)
+        return (lambda: MK.make_forest_fastmult(plan, forest, "exp",
                                                 coeffs)), "plan"
     if case == "traced_params":
         tree = random_tree(30, seed=1)
@@ -255,8 +255,8 @@ def _counter_case(case, monkeypatch):
         return make, "plan"
     assert case == "above_n_dense"
     monkeypatch.setattr(MK, "N_DENSE", 32)
-    integ = Integrator(random_tree(40, seed=2), leaf_size=8)
-    return (lambda: MK.make_tree_fastmult(integ, "exp", coeffs)), "plan"
+    plan = ftfi.build(random_tree(40, seed=2), leaf_size=8)
+    return (lambda: MK.make_tree_fastmult(plan, "exp", coeffs)), "plan"
 
 
 @pytest.mark.parametrize("case", ["single_tree", "forest", "traced_params",
@@ -276,13 +276,11 @@ def test_tree_fastmult_path_counter(case, monkeypatch):
 
 @pytest.mark.parametrize("source", ["pair", "from_plan", "reweighted",
                                     "tree_weight"])
-def test_dense_distances_from_plan(source, rng):
+def test_dense_distances_from_plan(source, rng, tmp_path):
     """Without a tree to read, the dense path derives D from the plan
     itself (the executor with f(s) = s on the identity field): the closure
     matches the plan executor's own mask multiply, a per-tree output weight
-    included."""
-    import repro.ftfi as ftfi
-    from repro.core.engines import Integrator
+    included. "from_plan" is a pair loaded from a `save_plan` artifact."""
     from repro.graphs.graph import random_tree
 
     tree = random_tree(50, seed=5)
@@ -293,8 +291,10 @@ def test_dense_distances_from_plan(source, rng):
     elif source == "tree_weight":
         params = ftfi.reweight(spec, np.asarray(tree.weights, np.float32),
                                tree_w=np.asarray([1.7], np.float32))
-    handle = ((spec, params) if source != "from_plan"
-              else Integrator.from_plan(spec, params))
+    handle = (spec, params)
+    if source == "from_plan":
+        ftfi.save_plan(tmp_path / "plan.npz", spec, params)
+        handle = spec, params = ftfi.load_plan(tmp_path / "plan.npz")
     coeffs = np.asarray([0.2, -0.3, -0.05], np.float32)
     X = jnp.asarray(rng.normal(size=(3, 50, 4)), jnp.float32)
     n0 = _path_counts()
@@ -304,3 +304,65 @@ def test_dense_distances_from_plan(source, rng):
     want = np.stack([np.asarray(ftfi.apply(spec, params, f, x)) for x in X])
     assert np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)) \
         < 1e-5
+
+
+def test_tree_fastmult_pallas_pair_matches_kernel(rng, monkeypatch):
+    """The fdist_matvec kernel runs the mask's family on a pair: the
+    functional API on "pallas" with the mask as `ExpQuadratic` fires its
+    VPU counter. `make_tree_fastmult` on the same pair with
+    backend="pallas" keeps the callable mask on the plan engines (the
+    kernel's counter stays still) and gives the same product."""
+    from repro.analysis import trace_guard
+    from repro.core.cordial import ExpQuadratic
+    from repro.graphs.graph import random_tree
+
+    monkeypatch.setattr(MK, "N_DENSE", 0)
+    jax.clear_caches()  # the kernel's jit traces a shape once a process
+    spec, params = plan = ftfi.build(random_tree(60, seed=3), leaf_size=8)
+    c0, c1, c2 = 0.1, -0.5, -0.2
+    s = 0.25
+    X = jnp.asarray(rng.normal(size=(60, 6)), jnp.float32)
+    vpu = trace_guard.compiles("fdist_matvec:vpu")
+    want = ftfi.fastmult(spec, ExpQuadratic(c2 * s * s, c1 * s, c0),
+                         backend="pallas")(params, X)
+    assert trace_guard.compiles("fdist_matvec:vpu") > vpu
+    vpu = trace_guard.compiles("fdist_matvec:vpu")
+    coeffs = np.asarray([c0, c1, c2], np.float32)
+    got = MK.make_tree_fastmult(plan, "exp", coeffs, s, backend="pallas")(
+        jnp.moveaxis(X.reshape(60, 3, 2), -1, 0))
+    assert trace_guard.compiles("fdist_matvec:vpu") == vpu
+    got = jnp.moveaxis(got, 0, -1).reshape(60, 6)
+    assert np.max(np.abs(np.asarray(got) - np.asarray(want))) \
+        / np.max(np.abs(np.asarray(want))) < 1e-5
+
+
+def test_grid14_dense_distances_are_tree_all_pairs(rng, monkeypatch):
+    """The TopoViT grid plan with its grid MST: the dense path's D is
+    bitwise `tree_all_pairs` of the 14 x 14 grid MST in f32 (never derived
+    from the plan), and the closure is the product by f(D)."""
+    from repro.configs.base import get_smoke_config
+    from repro.graphs.graph import grid_graph
+    from repro.graphs.mst import minimum_spanning_tree
+    from repro.graphs.traverse import tree_all_pairs
+    from repro.models import vit
+
+    cfg = get_smoke_config("topovit_b16").replace(num_prefix_embeddings=196)
+    plan = vit.build_grid_integrator(cfg)
+    D = np.asarray(tree_all_pairs(minimum_spanning_tree(grid_graph(14, 14))),
+                   np.float32)
+    got_D = MK.tree_distances(vit.grid_mst(14))
+    assert got_D.dtype == np.float32
+    np.testing.assert_array_equal(got_D, D)
+
+    def no_plan_distances(*a):
+        raise AssertionError("the grid D came from the plan, not the MST")
+
+    monkeypatch.setattr(MK, "_plan_distances", no_plan_distances)
+    coeffs = np.asarray([0.1, -0.5, -0.2], np.float32)
+    X = jnp.asarray(rng.normal(size=(2, 196, 4)), jnp.float32)
+    got = MK.make_tree_fastmult(plan, "exp", coeffs, 0.0625,
+                                tree=vit.grid_mst(14))(X)
+    M = MK.mask_f("exp", coeffs, 0.0625)(jnp.asarray(D))
+    want = jnp.einsum("ij,...jc->...ic", M, X,
+                      precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

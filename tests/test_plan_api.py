@@ -1,8 +1,9 @@
-"""Functional plan API: PlanSpec/PlanParams pytree registration, jit/vmap
-parity vs the legacy Integrator facade on trees and forests (all three
-backends), differentiable `ftfi.reweight` (exact under new weights,
-finite-difference gradcheck), save/load round trip with zero IT rebuild,
-the clear_plan_cache fastmult-memo purge, and the facade deprecation."""
+"""Functional plan API: PlanSpec/PlanParams pytree registration, jitted
+`ftfi.fastmult` parity on trees and forests against the eager executor
+(plan / pallas) and the numpy host references, differentiable
+`ftfi.reweight` (exact under new weights, finite-difference gradcheck),
+save/load round trip with zero IT rebuild, and the plan LRU's
+clear/miss accounting."""
 import dataclasses
 import os
 
@@ -12,8 +13,10 @@ import jax
 import jax.numpy as jnp
 
 from repro import ftfi
-from repro.core import Integrator, clear_plan_cache
+from repro.analysis import trace_guard
+from repro.core import clear_plan_cache
 from repro.core import cordial as C
+from repro.core.integrate import FTFI, ExpMP
 from repro.graphs.graph import (Forest, WeightedTree, path_graph, random_tree,
                                 star_tree)
 
@@ -24,6 +27,30 @@ PARITY_FNS = [
     C.Polynomial((0.5, -0.2, 0.1)),
     C.AnyFn(lambda z: 1.0 / (1.0 + z)),
 ]
+
+
+def _host(trees, fn, X, leaf_size):
+    """The numpy host reference over a packed field of `trees`: ExpMP for
+    exp f, else the recursive FTFI, one tree at a time."""
+    outs, off = [], 0
+    for t in trees:
+        Xt = np.asarray(X)[off:off + t.num_vertices]
+        if isinstance(fn, C.Exponential):
+            outs.append(ExpMP(t).integrate(fn.lam, Xt, scale=fn.scale))
+        else:
+            outs.append(FTFI(t, leaf_size=leaf_size).integrate(fn, Xt))
+        off += t.num_vertices
+    return np.concatenate(outs, axis=0)
+
+
+def _reference(tree_or_forest, backend, fn, X, leaf_size):
+    """Eager M_f X on `backend`: the host reference, or the un-jitted
+    executor `ftfi.apply` on the plan / pallas backend."""
+    if backend == "host":
+        trees = getattr(tree_or_forest, "trees", [tree_or_forest])
+        return _host(trees, fn, X, leaf_size)
+    spec, params = ftfi.build(tree_or_forest, leaf_size=leaf_size)
+    return ftfi.apply(spec, params, fn, X, backend=backend)
 
 
 def _rel_err(got, ref):
@@ -63,7 +90,7 @@ def test_params_tree_map():
 
 
 # ----------------------------------------------------------------------------
-# jit / vmap parity vs the facade
+# jit / vmap parity vs the eager paths
 # ----------------------------------------------------------------------------
 
 
@@ -72,7 +99,7 @@ def test_params_tree_map():
 def test_apply_jit_parity_tree(backend, fn, rng):
     tree = random_tree(130, seed=1)
     X = rng.normal(size=(130, 3))
-    ref = Integrator(tree, backend=backend, leaf_size=16).integrate(fn, X)
+    ref = _reference(tree, backend, fn, X, leaf_size=16)
     spec, params = ftfi.build(tree, leaf_size=16)
     engine = "pallas" if backend == "pallas" else "plan"
     fm = jax.jit(ftfi.fastmult(spec, fn, backend=engine))
@@ -87,8 +114,7 @@ def test_apply_jit_parity_forest(backend, rng):
     forest = Forest(trees)
     X = rng.normal(size=(forest.num_vertices, 2))
     fn = C.Exponential(-0.5, 1.2)
-    ref = Integrator.from_forest(forest, backend=backend,
-                                 leaf_size=8).integrate(fn, X)
+    ref = _reference(forest, backend, fn, X, leaf_size=8)
     spec, params = ftfi.build(forest, leaf_size=8)
     assert spec.num_trees == forest.num_trees
     engine = "pallas" if backend == "pallas" else "plan"
@@ -147,7 +173,7 @@ def test_reweight_exact_under_new_weights(rng):
     t1 = WeightedTree(tree.num_vertices, tree.edges_u, tree.edges_v, w1)
     fn = C.Exponential(-0.6, 1.1)
     X = rng.normal(size=(40, 2))
-    ref = Integrator(t1, backend="host", leaf_size=8).integrate(fn, X)
+    ref = _host([t1], fn, X, leaf_size=8)
     got = ftfi.apply(spec, ftfi.reweight(spec, w1), fn, X)
     assert _rel_err(got, ref) < 1e-5
 
@@ -202,8 +228,7 @@ def test_reweight_forest_packed_edges(rng):
         off += t.num_edges
     fn = C.Exponential(-0.5)
     X = rng.normal(size=(forest.num_vertices, 2))
-    ref = Integrator.from_forest(Forest(new_trees), backend="host",
-                                 leaf_size=6).integrate(fn, X)
+    ref = _host(new_trees, fn, X, leaf_size=6)
     got = ftfi.apply(spec, ftfi.reweight(spec, w1), fn, X)
     assert _rel_err(got, ref) < 1e-5
 
@@ -238,9 +263,10 @@ def test_save_load_bitwise_roundtrip(tmp_path, rng, monkeypatch):
     b_jit = np.asarray(jax.jit(ftfi.fastmult(spec2, fn))(params2, X))
     assert np.array_equal(a_jit, b_jit)
     assert spec2 == spec and hash(spec2) == hash(spec)
-    # the facade path over the artifact is also rebuild-free and exact
-    integ = Integrator.from_plan(spec2, params2, backend="plan")
-    c = np.asarray(integ.integrate(fn, X))
+    # the ladder-supervised door over the artifact is also rebuild-free
+    # and exact
+    c = np.asarray(ftfi.apply_resilient(spec2, params2, fn, X,
+                                        backend="plan"))
     assert _rel_err(c, a) < 1e-6
 
 
@@ -258,50 +284,40 @@ def test_save_load_reweightable_keeps_tables(tmp_path, rng):
 
 
 # ----------------------------------------------------------------------------
-# cache semantics + facade deprecation
+# cache semantics + masks over pairs
 # ----------------------------------------------------------------------------
 
 
-def test_clear_plan_cache_drops_fastmult_memos(rng):
-    """Satellite fix: clearing the plan cache must also purge the fastmult
-    memos living ON the cached plan objects — a live Integrator previously
-    kept every compiled closure reachable after a 'clear'."""
-    clear_plan_cache()
+def test_clear_plan_cache_next_build_misses():
+    """The LRU holds the built pair as it is: a repeat build is a hit that
+    returns the same objects; after clear_plan_cache the next build records
+    an `integrate.plan_cache:miss` and compiles a fresh pair."""
     tree = random_tree(30, seed=9)
-    integ = Integrator(tree, backend="plan", leaf_size=8)
-    with pytest.warns(DeprecationWarning):
-        integ.fastmult(C.Exponential(-0.3))
-    plan = integ._impl.plan
-    assert len(plan._fm_cache) == 1
-    assert plan._spec_params is not None
+    pair = ftfi.build(tree, leaf_size=8)
+    hits = trace_guard.compiles("integrate.plan_cache:hit")
+    again = ftfi.build(tree, leaf_size=8)
+    assert again[0] is pair[0] and again[1] is pair[1]
+    assert trace_guard.compiles("integrate.plan_cache:hit") == hits + 1
     clear_plan_cache()
-    assert len(plan._fm_cache) == 0
-    assert plan._spec_params is None
-    # the integrator itself keeps working (it holds spec/params directly)
-    out = integ.integrate(C.Exponential(-0.3), rng.normal(size=(30, 2)))
-    assert np.all(np.isfinite(np.asarray(out)))
-
-
-def test_facade_fastmult_deprecation_warns():
-    integ = Integrator(random_tree(20, seed=0), backend="plan", leaf_size=8)
-    with pytest.warns(DeprecationWarning, match="ftfi.fastmult"):
-        integ.fastmult(C.Exponential(-0.5))
+    misses = trace_guard.compiles("integrate.plan_cache:miss")
+    fresh = ftfi.build(tree, leaf_size=8)
+    assert trace_guard.compiles("integrate.plan_cache:miss") == misses + 1
+    assert fresh[0] is not pair[0] and fresh[0] == pair[0]
 
 
 def test_masks_accept_functional_pair(rng):
-    """make_tree_fastmult rides a raw (spec, params) pair — no Integrator,
-    no deprecated path."""
+    """make_tree_fastmult rides a raw (spec, params) pair on the plan
+    executor, equal to `ftfi.apply` of the same mask f."""
     from repro.core import masks as MK
     from repro.graphs.graph import grid_graph
     from repro.graphs.mst import minimum_spanning_tree
 
     mst = minimum_spanning_tree(grid_graph(5, 5))
     pair = ftfi.build(mst, leaf_size=8)
-    integ = Integrator(mst, backend="plan", leaf_size=8)
     coeffs = np.asarray([0.1, -0.4], np.float32)
     X = jnp.asarray(rng.normal(size=(25, 3)), jnp.float32)
     a = MK.make_tree_fastmult(pair, "exp", coeffs, 0.5)(X)
-    b = MK.make_tree_fastmult(integ, "exp", coeffs, 0.5)(X)
+    b = ftfi.apply(*pair, MK.mask_f("exp", coeffs, 0.5), X)
     assert _rel_err(a, b) < 1e-5
     # memoized per spec for concrete coeffs
     assert (MK.make_tree_fastmult(pair, "exp", coeffs, 0.5)

@@ -134,3 +134,38 @@ def test_save_load_round_trip_update_tables(tmp_path, reweightable):
     else:
         with pytest.raises(ValueError, match="reweightable"):
             ftfi.update_plan(spec2, pp2, [("insert_leaf", 4, 0.9)])
+
+
+def test_disk_round_trip_hands_back_the_loaded_pair(cache_dir, monkeypatch):
+    """build -> save_plan -> load_plan -> apply through the disk cache, with
+    no conversion step: a cold build returns exactly the pair `load_plan`
+    read (same objects), never touches the IT builder, and applies
+    bitwise like the original build."""
+    import repro.core.itree_flat as itree_flat
+
+    tree = random_tree(150, seed=8)
+    spec1, pp1 = ftfi.build(tree, leaf_size=16)
+    clear_flat_cache()
+    clear_plan_cache()
+    loaded = []
+    real_load = plan_cache.load
+
+    def spy(keyhex):
+        hit = real_load(keyhex)
+        loaded.append(hit)
+        return hit
+
+    def boom(*args, **kwargs):
+        raise AssertionError("a disk-cache hit rebuilt the IT")
+
+    monkeypatch.setattr(plan_cache, "load", spy)
+    monkeypatch.setattr(itree_flat, "_build", boom)
+    spec2, pp2 = ftfi.build(tree, leaf_size=16)
+    [(lspec, lparams)] = loaded
+    assert spec2 is lspec and pp2 is lparams
+    assert spec2.digest == spec1.digest
+    X = np.random.default_rng(2).normal(size=(150, 3)).astype(np.float32)
+    fn = C.Rational((1.0,), (1.0, 0.0, 0.5))
+    np.testing.assert_array_equal(
+        np.asarray(ftfi.apply(spec1, pp1, fn, X, backend="pallas")),
+        np.asarray(ftfi.apply(spec2, pp2, fn, X, backend="pallas")))

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from repro import ftfi
-from repro.core import Integrator
 from repro.core import cordial as C
 from repro.graphs.graph import Forest, WeightedTree, random_tree
 
@@ -213,8 +212,8 @@ def test_updated_plan_runs_on_pallas_backend():
     X = rng.normal(size=(s.n, 3)).astype(np.float32)
     fn = C.Exponential(-0.4)
     ref = _apply_rows(s, p, fn, X)
-    got = np.asarray(Integrator.from_plan(s, p, backend="pallas",
-                                          interpret=True).integrate(fn, X))
+    assert ftfi.describe(s, fn, "pallas")["cross_engine"] == "fdist_matvec:exp"
+    got = np.asarray(ftfi.apply(s, p, fn, X, backend="pallas"))
     assert _rel_err(got, ref) < TOL
 
 

@@ -148,14 +148,19 @@ def test_update_plan_output_is_validated():
     assert plan_guard.check_spec(spec2, params2) == []
 
 
-def test_integrator_from_plan_guards_artifact_pairs(plan_pair):
-    from repro.core import Integrator
+def test_install_grid_plan_guards_artifact_pairs():
+    """Both doors a loaded pair comes in by run the plan guard:
+    `ftfi.validate` and the ViT grid's `install_grid_plan`."""
+    from repro.models import vit
 
-    spec, params = plan_pair
-    integ = Integrator.from_plan(spec, params)  # healthy pair passes
-    assert integ.spec is spec
+    spec, params = ftfi.build(vit.grid_mst(8), leaf_size=16)
+    ftfi.validate(spec, params)  # healthy pair passes
+    assert vit.install_grid_plan(spec, params) == 8
+    bad = faults.flip_index(spec)
     with pytest.raises(PlanValidationError):
-        Integrator.from_plan(faults.flip_index(spec), params)
+        ftfi.validate(bad, params)
+    with pytest.raises(PlanValidationError):
+        vit.install_grid_plan(bad, params)
 
 
 def test_disk_cache_hit_validates_and_rejects_corruption(tmp_path):

@@ -226,7 +226,7 @@ def test_auto_backend_size_threshold():
     (pallas loses there: speedup_int 0.88 at n=1000) and pallas above."""
     from repro.core import cordial as C
     from repro.core import ladder
-    from repro.core.engines.spec import spec_of
+    from repro.core.cordial import spec_of
     from repro.core.plan_api import build, select_cross
     from repro.graphs.graph import random_tree
 
